@@ -1,10 +1,10 @@
 """Turn a capture directory into a kernel ranking + dispatch advice.
 
-Usage: python benchmarks/analyze_capture.py TPU_CAPTURE_r2b [...]
+Usage: python benchmarks/analyze_capture.py CAPTURE_DIR [...]
        python benchmarks/analyze_capture.py --emit-thresholds CAPTURE_DIR
 
 Reads each directory's ``device_paths.json`` (written by
-benchmarks/tpu_oneshot.py stage 6 / benchmarks/device_paths.py) and
+benchmarks/device_paths.py run on the chip) and
 prints, per metric count, the measured ranking plus the winner — then
 compares the winners against what ``ops/dispatch.py`` would choose.
 
@@ -12,7 +12,7 @@ compares the winners against what ``ops/dispatch.py`` would choose.
 capture's winners and writes it to
 ``loghisto_tpu/ops/dispatch_thresholds.json``, which ``ops/dispatch.py``
 loads at import — so refreshing the dispatch policy after a hardware
-capture is a committed JSON, not a code edit (VERDICT r2 item 7).
+capture is a committed JSON, not a code edit.
 Pure stdlib; safe to run anywhere (no jax import).
 """
 
@@ -152,7 +152,7 @@ def main() -> int:
     if not dirs:
         print("no TPU_CAPTURE* directories here; pass capture dirs as "
               "arguments (e.g. python benchmarks/analyze_capture.py "
-              "TPU_CAPTURE_r2b)", file=sys.stderr)
+              "CAPTURE_DIR)", file=sys.stderr)
         return 1
     if emit and len(dirs) != 1:
         print("--emit-thresholds takes exactly one capture directory "

@@ -45,7 +45,7 @@ sys.path.insert(0, _REPO)
 
 import numpy as np
 
-from bench import HBM_PEAK_BYTES_PER_S
+from bench import peak_bytes_per_s
 
 # (label, num_metrics, bucket_limit, tiers) — the query-engine grid: the
 # 10k point shrinks buckets/tier depth so the rings fit everywhere; the
@@ -95,7 +95,7 @@ def run(reps: int = 20, configs=None) -> dict:
     from loghisto_tpu.window import TimeWheel
 
     platform = jax.devices()[0].platform
-    cap = HBM_PEAK_BYTES_PER_S.get(platform, 4e12)
+    cap = peak_bytes_per_s(jax.devices()[0].device_kind)
     result = {
         "metric": "drift-engine cost: EWMA ride-along + divergence dispatch",
         "platform": platform,

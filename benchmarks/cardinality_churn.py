@@ -37,7 +37,7 @@ sys.path.insert(0, _REPO)
 
 import numpy as np
 
-from bench import HBM_PEAK_BYTES_PER_S
+from bench import peak_bytes_per_s
 
 # (label, cumulative_names, live_budget_rows, bucket_limit, tiers)
 # The big points shrink buckets and tier depth so the rings fit
@@ -71,7 +71,7 @@ def run(configs=None) -> dict:
     from loghisto_tpu.window import TimeWheel
 
     platform = jax.devices()[0].platform
-    cap = HBM_PEAK_BYTES_PER_S.get(platform, 4e12)
+    cap = peak_bytes_per_s(jax.devices()[0].device_kind)
     wanted = set(configs) if configs else None
     result = {
         "metric": "interval commit + lifecycle cost under name churn",

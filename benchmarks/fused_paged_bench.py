@@ -131,7 +131,7 @@ def run(num_metrics: int = 4_096, bucket_limit: int = 512,
         times.append(time.perf_counter() - t0)
     t_two = float(np.median(times))
 
-    cap = plausibility_cap_samples_per_s(platform, pool_bytes)
+    cap = plausibility_cap_samples_per_s(jax.devices()[0].device_kind, pool_bytes)
     sps = batch / t_fused
     suspect = sps > cap
     if suspect:

@@ -35,7 +35,7 @@ sys.path.insert(0, _REPO)
 
 import numpy as np
 
-from bench import HBM_PEAK_BYTES_PER_S
+from bench import peak_bytes_per_s
 
 # (label, num_metrics, bucket_limit, tiers): the 10k point shrinks the
 # bucket space and tier depth so the rings fit comfortably everywhere —
@@ -90,7 +90,7 @@ def run(reps: int = 30) -> dict:
         "metric": "interval commit latency, fused vs fan-out",
         "platform": platform,
         "reps": reps,
-        "hbm_peak_bytes_per_s": HBM_PEAK_BYTES_PER_S.get(platform, 4e12),
+        "hbm_peak_bytes_per_s": peak_bytes_per_s(jax.devices()[0].device_kind),
         "configs": {},
     }
     for label, num_metrics, bucket_limit, tiers in CONFIGS:
@@ -169,7 +169,7 @@ def run(reps: int = 30) -> dict:
         # plausibility: implied H2D bandwidth for the fused upload must
         # stay under the platform roofline, else the timing is broken
         implied_bw = h2d_per_interval / max(fused_med, 1e-9)
-        cap = HBM_PEAK_BYTES_PER_S.get(platform, 4e12)
+        cap = peak_bytes_per_s(jax.devices()[0].device_kind)
         suspect = implied_bw > cap
         if suspect:
             print(

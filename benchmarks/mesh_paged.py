@@ -71,7 +71,7 @@ def run_shapes(num_metrics: int = 1024, bucket_limit: int = 512,
     at every mesh shape, with pool-decode parity against single."""
     import jax
 
-    from bench import HBM_PEAK_BYTES_PER_S
+    from bench import peak_bytes_per_s
     from mesh_scale import _commit_intervals
     from loghisto_tpu.commit import IntervalCommitter
     from loghisto_tpu.config import MetricConfig
@@ -82,7 +82,7 @@ def run_shapes(num_metrics: int = 1024, bucket_limit: int = 512,
     from loghisto_tpu.window import TimeWheel
 
     platform = jax.devices()[0].platform
-    cap = HBM_PEAK_BYTES_PER_S.get(platform, 4e12)
+    cap = peak_bytes_per_s(jax.devices()[0].device_kind)
     cfg = MetricConfig(bucket_limit=bucket_limit)
     rng = np.random.default_rng(0)
     stream = _commit_intervals(rng, reps + 2, num_metrics, bucket_limit)

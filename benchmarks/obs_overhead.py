@@ -42,7 +42,7 @@ sys.path.insert(0, _REPO)
 
 import numpy as np
 
-from bench import HBM_PEAK_BYTES_PER_S
+from bench import peak_bytes_per_s
 
 NUM_METRICS = 1024
 BATCH = 1 << 16
@@ -107,7 +107,7 @@ def run(reps: int = 4, seconds: float = 1.5) -> dict:
     from loghisto_tpu.obs import SpanRecorder
 
     platform = jax.devices()[0].platform
-    cap = HBM_PEAK_BYTES_PER_S.get(platform, 4e12)
+    cap = peak_bytes_per_s(jax.devices()[0].device_kind)
 
     # alternate the contenders so host-speed drift cancels
     off_rates, on_rates = [], []

@@ -273,7 +273,7 @@ def run(wire_rows=(10_000, 100_000), occupancy_rows: int = 100_000) -> dict:
     suspect = False
     for m in wire_rows:
         cfg_bytes = m * (2 * WIRE_BUCKET_LIMIT + 1) * 4
-        cap = plausibility_cap_samples_per_s(platform, cfg_bytes)
+        cap = plausibility_cap_samples_per_s(jax.devices()[0].device_kind, cfg_bytes)
         line = measure_wire(m, cap)
         line["roofline_cap_samples_per_s"] = cap
         result["configs"][str(m)] = line

@@ -1,5 +1,6 @@
 """Bit-parity validation of the Pallas ingest kernels vs the scatter path
-ON REAL HARDWARE (non-interpret). Run via benchmarks/tpu_capture.sh.
+ON REAL HARDWARE (non-interpret): run it on the chip, alone in its
+process.
 
 Prints PARITY OK / PARITY FAIL lines per kernel; exit code 0 iff all pass.
 """

@@ -42,7 +42,7 @@ sys.path.insert(0, _REPO)
 
 import numpy as np
 
-from bench import HBM_PEAK_BYTES_PER_S
+from bench import peak_bytes_per_s
 
 # (label, num_metrics, bucket_limit, tiers) — same grid as
 # interval_commit.py: the 10k point shrinks buckets and tier depth so
@@ -102,7 +102,7 @@ def run(reps: int = 30) -> dict:
     from loghisto_tpu.window import TimeWheel
 
     platform = jax.devices()[0].platform
-    cap = HBM_PEAK_BYTES_PER_S.get(platform, 4e12)
+    cap = peak_bytes_per_s(jax.devices()[0].device_kind)
     result = {
         "metric": "windowed percentile-query latency, snapshot vs recompute",
         "platform": platform,

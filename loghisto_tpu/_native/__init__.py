@@ -1,6 +1,7 @@
 """ctypes loader and wrapper for the native ingest runtime.
 
-Builds `ingest.cpp` with g++ on first use (cached next to the source);
+Builds `ingest.cpp` with g++ on first use, next to the source under a
+name that hashes the source, the flags and the host CPU;
 every entry point degrades gracefully: `available()` is False when no
 compiler exists, and callers fall back to the pure-NumPy host tier.
 """
@@ -16,15 +17,54 @@ import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "ingest.cpp")
-_LIB_PATH = os.path.join(_HERE, "libloghisto_ingest.so")
 _FASTPATH_SRC = os.path.join(_HERE, "fastpath.cpp")
+# -pthread: the parallel fold/drain entry points spawn std::threads
+_LIB_FLAGS = ["-march=native", "-pthread"]
+
+
+def _host_cpu() -> str:
+    """What ``-march=native`` resolves against: the machine and, on
+    Linux, its CPU model and feature flags."""
+    import platform
+
+    ident = [platform.machine()]
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("model name", "flags")):
+                    ident.append(line.strip())
+                if len(ident) == 3:
+                    break
+    except OSError:
+        pass
+    return "\n".join(ident)
+
+
+def _built_path(stem: str, src: str, flags: list[str], suffix: str) -> str:
+    """Library path named by a hash of its source, its flags and (for
+    ``-march=native``) the host CPU: a library built elsewhere — copied
+    along with the tree — never matches, so it is never loaded in place
+    of one built on this host."""
+    import hashlib
+
+    h = hashlib.sha256()
+    with open(src, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(flags).encode())
+    if "-march=native" in flags:
+        h.update(_host_cpu().encode())
+    return os.path.join(_HERE, f"{stem}-{h.hexdigest()[:16]}{suffix}")
+
+
+_LIB_PATH = _built_path("libloghisto_ingest", _SRC, _LIB_FLAGS, ".so")
 # ABI-tagged filename: a CPython extension built under one interpreter
 # must never be dlopened by another (unlike the ctypes lib above)
 import sysconfig as _sysconfig
 
-_FASTPATH_PATH = os.path.join(
-    _HERE, "loghisto_fastpath" + (_sysconfig.get_config_var("EXT_SUFFIX")
-                                  or ".so")
+_FASTPATH_FLAGS = [f"-I{_sysconfig.get_paths()['include']}"]
+_FASTPATH_PATH = _built_path(
+    "loghisto_fastpath", _FASTPATH_SRC, _FASTPATH_FLAGS,
+    _sysconfig.get_config_var("EXT_SUFFIX") or ".so",
 )
 
 _lib = None
@@ -63,28 +103,13 @@ def _compile(src: str, out_path: str, extra_flags: list[str]) -> str | None:
             pass
 
 
-def _is_stale(lib_path: str, src: str) -> bool:
-    try:
-        return not os.path.exists(lib_path) or (
-            os.path.getmtime(lib_path) < os.path.getmtime(src)
-        )
-    except OSError:
-        # e.g. prebuilt .so shipped without the source: use it as-is
-        return not os.path.exists(lib_path)
-
-
-def _build() -> str | None:
-    # -pthread: the parallel fold/drain entry points spawn std::threads
-    return _compile(_SRC, _LIB_PATH, ["-march=native", "-pthread"])
-
-
 def _load():
     global _lib, _build_error
     with _lib_lock:
         if _lib is not None or _build_error is not None:
             return _lib
-        if _is_stale(_LIB_PATH, _SRC):
-            _build_error = _build()
+        if not os.path.exists(_LIB_PATH):
+            _build_error = _compile(_SRC, _LIB_PATH, _LIB_FLAGS)
             if _build_error is not None:
                 return None
         try:
@@ -175,12 +200,9 @@ def _load_fastpath():
     with _lib_lock:
         if _fastpath is not None or _fastpath_error is not None:
             return _fastpath
-        import sysconfig
-
-        if _is_stale(_FASTPATH_PATH, _FASTPATH_SRC):
-            include = sysconfig.get_paths()["include"]
+        if not os.path.exists(_FASTPATH_PATH):
             _fastpath_error = _compile(
-                _FASTPATH_SRC, _FASTPATH_PATH, [f"-I{include}"]
+                _FASTPATH_SRC, _FASTPATH_PATH, _FASTPATH_FLAGS
             )
             if _fastpath_error is not None:
                 return None

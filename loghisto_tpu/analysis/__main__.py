@@ -32,8 +32,7 @@ def _force_cpu_devices() -> None:
 def _run_jaxpr_pass(programs_file: str | None = None):
     import jax
 
-    # The env var alone is not enough on hosts whose sitecustomize
-    # force-registers an accelerator plugin; the config update is.
+    # the audit traces on the CPU, whatever accelerator the host has
     jax.config.update("jax_platforms", "cpu")
     from loghisto_tpu.analysis import jaxpr_audit
 

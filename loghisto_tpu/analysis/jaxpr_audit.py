@@ -75,6 +75,14 @@ class ProgramSpec:
 # ---------------------------------------------------------------------- #
 
 
+def _user_line(eqn) -> tuple:
+    """(file, line) of the user code that emitted ``eqn``."""
+    from jax._src import source_info_util
+
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    return (frame.file_name, frame.start_line) if frame else (None, id(eqn))
+
+
 def _sub_jaxprs(params):
     """Yield every sub-jaxpr hiding in an eqn's params.  pjit/scan/cond
     carry ClosedJaxpr values (``.jaxpr`` attribute); shard_map and
@@ -202,11 +210,15 @@ def audit_jaxpr(closed, contract: Contract, name: str,
     psums = [e for e in all_eqns if e.primitive.name.startswith("psum")
              and STREAM_AXIS_NAME in tuple(e.params.get("axes", ()))]
     if contract.stream_psums is not None:
-        if len(psums) != contract.stream_psums:
+        # one merge = one ``lax.psum`` call: jax >= 0.7 traces a psum of
+        # a pytree as one ``psum_invariant`` eqn per operand, all
+        # carrying the call's source line
+        merges = len({_user_line(e) for e in psums})
+        if merges != contract.stream_psums:
             out.append(finding(
                 "psum-count",
                 f"contract pins exactly {contract.stream_psums} "
-                f"stream-axis psum(s), trace has {len(psums)}",
+                f"stream-axis psum(s), trace has {merges}",
             ))
         for eqn in psums:
             for var in eqn.outvars:

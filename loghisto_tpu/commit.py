@@ -15,8 +15,8 @@ raw boundary:
   1. the interval's sparse histograms are resolved to ``(ids, codec
      bucket, weight)`` cells ONCE (the aggregator's registry/growth/shed
      policy applies — the wheel shares the registry by construction);
-  2. the cells are staged through a depth-2 double-buffered H2D ring
-     (``ops.commit.CellStagingRing``) so the next chunk/interval's
+  2. the cells are staged through fresh host arrays and an async H2D
+     upload (``ops.commit.CellStagingRing``) so the next chunk/interval's
      transfer overlaps the in-flight commit dispatch;
   3. one jitted donated-carry program (``ops.commit.make_fused_commit_fn``)
      folds the cells into the aggregator accumulator AND every tier's
@@ -75,6 +75,7 @@ from loghisto_tpu.ops.commit import (
 from loghisto_tpu.parallel.mesh import (
     STREAM_AXIS,
     cell_sharding,
+    sharded_zeros,
     triple_sharding,
 )
 from loghisto_tpu.window.snapshot import AccSnapshot
@@ -120,15 +121,13 @@ class IntervalCommitter:
     """One-subscription interval commit for a (TPUAggregator, TimeWheel)
     pair — see the module docstring for the design.  ``chunk`` is the
     fixed commit launch width (tests shrink it to exercise multi-chunk
-    intervals and pad sentinels); ``staging_depth`` sizes the H2D
-    overlap ring."""
+    intervals and pad sentinels)."""
 
     def __init__(
         self,
         aggregator,
         wheel,
         chunk: int = COMMIT_CHUNK,
-        staging_depth: int = 2,
         lifecycle=None,
         anomaly=None,
     ):
@@ -218,11 +217,10 @@ class IntervalCommitter:
                 wheel.config.precision, wheel.merge_path,
                 track_activity=track, track_baseline=track_b,
             )
-        self._staging = CellStagingRing(depth=staging_depth,
-                                        width=self.chunk,
+        self._staging = CellStagingRing(width=self.chunk,
                                         sharding=staging_sharding)
         self._triples = (
-            PagedTripleRing(depth=staging_depth, width=self.chunk,
+            PagedTripleRing(width=self.chunk,
                             sharding=trip_sharding)
             if self.paged is not None else None
         )
@@ -666,14 +664,10 @@ class IntervalCommitter:
         reset = []
         for t in wheel._tiers:
             if getattr(t.ring, "is_deleted", lambda: False)():
-                z = jnp.zeros(
+                t.ring = sharded_zeros(
                     (t.spec.slots, wheel.num_metrics,
                      wheel.config.num_buckets),
-                    dtype=jnp.int32,
-                )
-                t.ring = (
-                    jax.device_put(z, wheel._sharding)
-                    if wheel._sharding is not None else z
+                    wheel._sharding,
                 )
                 t.written[:] = False
                 t.durations[:] = 0.0

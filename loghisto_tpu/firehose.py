@@ -122,7 +122,8 @@ def make_mesh_firehose_interval_step(
 
     from loghisto_tpu.ops.dispatch import ingest_step_fn, resolve_ingest_path
     from loghisto_tpu.ops.ingest import sanitize_ids
-    from loghisto_tpu.parallel.mesh import METRIC_AXIS, STREAM_AXIS, shard_map
+    from jax import shard_map
+    from loghisto_tpu.parallel.mesh import METRIC_AXIS, STREAM_AXIS
 
     n_stream = mesh.shape[STREAM_AXIS]
     n_metric = mesh.shape[METRIC_AXIS]

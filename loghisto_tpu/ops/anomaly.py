@@ -103,12 +103,20 @@ def ewma_bank_update(banks, ihist, bank, decay, min_count):
 # ---------------------------------------------------------------------- #
 
 
-def _row_divergence(cdf, counts, prof, w):
+def _baseline(prof, w):
+    """Bias-corrected baseline pmf and its CDF, f32 [R, B] each.  w == 0
+    rows are masked by the caller; the epsilon only keeps the division
+    finite for them."""
+    base_pmf = prof / jnp.maximum(w, jnp.float32(1e-30))[:, None]
+    return base_pmf, jnp.cumsum(base_pmf, axis=1)
+
+
+def _row_scores(cdf, counts, base_pmf, base_cdf):
     """Raw per-row divergence scores (no floor mask): cdf int32 [R, B],
-    counts int32 [R], prof f32 [R, B], w f32 [R] -> (ks, jsd, emd), each
-    f32 [R].  Row-independent elementwise math + axis-1 reductions ONLY
-    — this is what makes the jnp and Pallas tiers bit-identical (the
-    Pallas kernel applies the same function per 8-row tile)."""
+    counts int32 [R], base_pmf/base_cdf f32 [R, B] -> (ks, jsd, emd),
+    each f32 [R].  Row-independent elementwise math + axis-1 reductions
+    ONLY — this is what makes the jnp and Pallas tiers bit-identical
+    (the Pallas kernel applies the same function per 8-row tile)."""
     total = jnp.maximum(counts, 1).astype(jnp.float32)[:, None]
     live_cdf = cdf.astype(jnp.float32) / total
     # exact integer bin counts first, divide after — differencing the
@@ -117,10 +125,6 @@ def _row_divergence(cdf, counts, prof, w):
         [jnp.zeros_like(cdf[:, :1]), cdf[:, :-1]], axis=1
     )
     live_pmf = bins.astype(jnp.float32) / total
-    # bias-corrected baseline pmf; w == 0 rows are masked by the caller,
-    # the epsilon only keeps the division finite for them
-    base_pmf = prof / jnp.maximum(w, jnp.float32(1e-30))[:, None]
-    base_cdf = jnp.cumsum(base_pmf, axis=1)
     diff = jnp.abs(live_cdf - base_cdf)
     ks = jnp.max(diff, axis=1)
     emd = jnp.sum(diff, axis=1)
@@ -138,23 +142,24 @@ def _row_divergence(cdf, counts, prof, w):
     return ks, jsd, emd
 
 
-def _div_kernel(cdf_ref, cnt_ref, prof_ref, w_ref,
+def _div_kernel(cdf_ref, cnt_ref, pmf_ref, bcdf_ref,
                 ks_ref, jsd_ref, emd_ref):
-    ks, jsd, emd = _row_divergence(
-        cdf_ref[...], cnt_ref[...][:, 0], prof_ref[...], w_ref[...][:, 0]
+    ks, jsd, emd = _row_scores(
+        cdf_ref[...], cnt_ref[...][:, 0], pmf_ref[...], bcdf_ref[...]
     )
     ks_ref[...] = ks[:, None]
     jsd_ref[...] = jsd[:, None]
     emd_ref[...] = emd[:, None]
 
 
-def divergence_pallas(cdf, counts, prof, w, interpret=None):
-    """Pallas tier of the raw divergence: grid over metric tiles, each
-    [ROWS_TILE, B] live/baseline block resident in VMEM while its three
-    scores reduce — HBM traffic is the two operand tensors in + 3 floats
-    per row out, the bandwidth floor.  Row padding is score-neutral
-    (padded rows are sliced off) and the per-row math is the SAME
-    function the jnp tier runs, so results are bit-identical."""
+def divergence_pallas(cdf, counts, base_pmf, base_cdf, interpret=None):
+    """Pallas tier of the raw divergence scores: grid over metric tiles,
+    each [ROWS_TILE, B] live/baseline block resident in VMEM while its
+    three scores reduce.  The baseline's prefix sum is not in the kernel
+    (Pallas has no TPU lowering of cumsum): the caller computes it with
+    the same ``_baseline`` the jnp tier runs.  Row padding is
+    score-neutral (padded rows are sliced off) and the per-row math is
+    the SAME function the jnp tier runs, so results are bit-identical."""
     if interpret is None:
         interpret = default_interpret()
     m, b = cdf.shape
@@ -163,28 +168,27 @@ def divergence_pallas(cdf, counts, prof, w, interpret=None):
         gap = m_pad - m
         cdf = jnp.pad(cdf, ((0, gap), (0, 0)))
         counts = jnp.pad(counts, (0, gap))
-        prof = jnp.pad(prof, ((0, gap), (0, 0)))
-        w = jnp.pad(w, (0, gap))
+        base_pmf = jnp.pad(base_pmf, ((0, gap), (0, 0)))
+        base_cdf = jnp.pad(base_cdf, ((0, gap), (0, 0)))
     grid = (m_pad // ROWS_TILE,)
     row_spec = pl.BlockSpec((ROWS_TILE, b), lambda i: (i, 0))
     col_spec = pl.BlockSpec((ROWS_TILE, 1), lambda i: (i, 0))
     out = pl.pallas_call(
         _div_kernel,
         grid=grid,
-        in_specs=[row_spec, col_spec, row_spec, col_spec],
+        in_specs=[row_spec, col_spec, row_spec, row_spec],
         out_specs=(col_spec, col_spec, col_spec),
         out_shape=tuple(
             jax.ShapeDtypeStruct((m_pad, 1), jnp.float32) for _ in range(3)
         ),
         interpret=interpret,
-    )(cdf, counts[:, None], prof, w[:, None])
+    )(cdf, counts[:, None], base_pmf, base_cdf)
     return tuple(o[:m, 0] for o in out)
 
 
 def resolve_divergence_path(path: str, platform: str, mesh: bool) -> str:
-    """Dispatch policy for the divergence tier, mirroring
-    resolve_merge_path: "auto" picks Pallas only single-device on real
-    TPU (Pallas under shard_map is off the table; interpret mode off-TPU
+    """Dispatch policy for the divergence tier, like the ingest
+    dispatch: "auto" picks Pallas only single-device on real TPU (Pallas under shard_map is off the table; interpret mode off-TPU
     is strictly slower than the jnp form)."""
     if path not in ("auto", "jnp", "pallas"):
         raise ValueError(
@@ -227,10 +231,14 @@ def divergence_scores(cdf, counts, prof, wsum, bank, min_samples,
     else:
         bprof = bprof[:m]
         bw = bw[:m]
+    # materialized for both tiers (the Pallas tier has to take them as
+    # operands), so XLA cannot fuse them into the jnp tier's scores
+    # differently from what the kernel sees
+    base_pmf, base_cdf = jax.lax.optimization_barrier(_baseline(bprof, bw))
     if path == "pallas":
-        ks, jsd, emd = divergence_pallas(cdf, counts, bprof, bw)
+        ks, jsd, emd = divergence_pallas(cdf, counts, base_pmf, base_cdf)
     else:
-        ks, jsd, emd = _row_divergence(cdf, counts, bprof, bw)
+        ks, jsd, emd = _row_scores(cdf, counts, base_pmf, base_cdf)
     valid = (counts >= min_samples) & (bw > 0)
     zero = jnp.float32(0.0)
     return {

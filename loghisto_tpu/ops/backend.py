@@ -2,50 +2,21 @@
 
 Every Pallas entry point in this repo picks compiled-vs-interpret mode
 from the same question — "is the default JAX backend a real TPU?" — and
-until r13 each module (ops/pallas_kernels.py, ops/sparse_ingest.py, and
-the dispatch-adjacent callers) carried its own copy-pasted probe.  One
-probe lives here now, with an env override so CI can pin the answer:
-
-  LOGHISTO_FORCE_INTERPRET=1   every kernel runs in Pallas interpret
-                               mode regardless of the detected platform
-                               — deterministic CPU CI, and a TPU
-                               debugging escape hatch.
-
-The probe is intentionally exception-swallowing: ``jax.devices()`` can
-raise during interpreter teardown or before a distributed runtime is
-initialized, and "couldn't probe" must degrade to the safe answer
-(interpret mode) rather than crash an import chain.
+one probe answers it here.  On a TPU backend every kernel compiles for
+the chip; interpret mode is only for backends with no Pallas TPU
+lowering (the CPU the tests run on).  The probe raises whatever the
+backend raises: a backend that cannot be queried is an error, never a
+reason to run a kernel interpreted.
 """
 
 from __future__ import annotations
 
-import os
-
 import jax
-
-ENV_FORCE_INTERPRET = "LOGHISTO_FORCE_INTERPRET"
-_TRUTHY = ("1", "true", "yes", "on")
-
-
-def force_interpret() -> bool:
-    """True when the env override pins interpret mode on."""
-    raw = os.environ.get(ENV_FORCE_INTERPRET)
-    return raw is not None and raw.strip().lower() in _TRUTHY
 
 
 def on_tpu() -> bool:
-    """True when kernels should compile for a real TPU.
-
-    False on every other platform AND whenever LOGHISTO_FORCE_INTERPRET
-    is set truthy — callers use ``interpret = not on_tpu()`` so the
-    override flips every kernel to interpret mode in one place.
-    """
-    if force_interpret():
-        return False
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    """True when kernels compile for a real TPU."""
+    return jax.default_backend() == "tpu"
 
 
 def default_interpret() -> bool:

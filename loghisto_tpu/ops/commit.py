@@ -19,14 +19,12 @@ program over a donated carry pytree ``(aggregator_acc, ring_0..N)``:
     rotation across intervals never recompiles — one executable serves
     every interval for the lifetime of the shapes.
 
-``CellStagingRing`` is the async H2D front end: a depth-2
-double-buffered set of pinned host pad arrays whose ``stage()`` issues
-``jax.device_put`` and returns immediately, so interval N+1's cell
-transfer overlaps interval N's commit dispatch (the same super-chunk
-overlap design as the aggregator's raw flush path, extended to the
-bridge).  Depth 2 gives exactly one in-flight commit of slack: a slot's
-host buffers are rewritten only after the commit dispatched against the
-OTHER slot has been enqueued, which is the contract the overlap needs.
+``CellStagingRing`` is the async H2D front end: its ``stage()`` pads a
+chunk into fresh host arrays, issues ``jax.device_put`` and returns
+immediately, so interval N+1's cell transfer overlaps interval N's
+commit dispatch (the same overlap design as the aggregator's raw flush
+path, extended to the bridge).  A host buffer handed to ``device_put``
+is never written again: the upload may alias it or read it late.
 
 The orchestration (locks, spill policy, tier metadata) lives in
 ``loghisto_tpu.commit.IntervalCommitter``; this module stays pure
@@ -39,6 +37,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
@@ -47,7 +46,7 @@ from loghisto_tpu.ops.ingest import sanitize_ids
 from loghisto_tpu.ops.paged_store import paged_scatter_batch
 from loghisto_tpu.ops.stats import dense_cdf
 from loghisto_tpu.ops.window import window_snapshot
-from loghisto_tpu.parallel.mesh import METRIC_AXIS, STREAM_AXIS, shard_map
+from loghisto_tpu.parallel.mesh import METRIC_AXIS, STREAM_AXIS
 
 # Fixed commit launch width, matching the aggregator bridge's merge
 # chunk: one compiled executable serves every interval; a typical
@@ -58,6 +57,30 @@ COMMIT_CHUNK = 1 << 16
 # each scatter's mode="drop" sheds it — same design as sanitize_ids and
 # the wheel's _DROP_ID.
 DROP_ID = np.int32(2**30)
+
+
+def _open_slot_slab(ring, slot, keep):
+    """The open slot's [M, B] slab, times its keep factor (0 clears it
+    on ring wrap)."""
+    return jax.lax.dynamic_index_in_dim(ring, slot, keepdims=False) * keep
+
+
+def _fold_open_slot(ring, slot, keep, ids, idx, weights):
+    """``ring[slot] = ring[slot] * keep`` then ``ring[slot, ids, idx] +=
+    weights``, on the one [M, B] slab.  A scatter straight into the
+    [S, M, B] ring makes XLA's TPU backend copy the whole ring to a
+    linear layout (S x 328 MB of temporary HBM at 10k x 8193); a slab
+    costs one slab."""
+    slab = _open_slot_slab(ring, slot, keep)
+    slab = slab.at[ids, idx].add(weights, mode="drop")
+    return jax.lax.dynamic_update_index_in_dim(ring, slab, slot, 0)
+
+
+def _add_open_slot(ring, slot, keep, delta):
+    """``ring[slot] = ring[slot] * keep + delta`` for a dense [M, B]
+    delta (the sharded programs' merged interval cells)."""
+    slab = _open_slot_slab(ring, slot, keep) + delta
+    return jax.lax.dynamic_update_index_in_dim(ring, slab, slot, 0)
 
 
 @functools.lru_cache(maxsize=None)
@@ -141,8 +164,8 @@ def make_fused_commit_fn(
         new_rings = []
         for t in range(num_tiers):
             ring = rings[t]
-            ring = ring.at[slots[t]].multiply(keeps[t], mode="drop")
-            ring = ring.at[slots[t], ids, idx].add(weights, mode="drop")
+            ring = _fold_open_slot(ring, slots[t], keeps[t], ids, idx,
+                                   weights)
             new_rings.append(ring)
         out = [acc, tuple(new_rings)]
         if track_activity:
@@ -236,8 +259,8 @@ def make_fused_commit_snapshot_fn(
         payloads = []
         for t in range(num_tiers):
             ring = rings[t]
-            ring = ring.at[slots[t]].multiply(keeps[t], mode="drop")
-            ring = ring.at[slots[t], ids, idx].add(weights, mode="drop")
+            ring = _fold_open_slot(ring, slots[t], keeps[t], ids, idx,
+                                   weights)
             new_rings.append(ring)
             payloads.append(
                 window_snapshot(ring, masks[t], bucket_limit, precision,
@@ -370,8 +393,7 @@ def make_sharded_fused_commit_fn(
         for t in range(num_tiers):
             ring = rings[t]
             rd = ring_deltas.get(ring.shape[1], delta)
-            ring = ring.at[slots[t]].multiply(keeps[t], mode="drop")
-            ring = ring.at[slots[t]].add(rd, mode="drop")
+            ring = _add_open_slot(ring, slots[t], keeps[t], rd)
             new_rings.append(ring)
         out = [acc, tuple(new_rings)]
         if track_activity:
@@ -452,8 +474,7 @@ def make_sharded_fused_commit_snapshot_fn(
         for t in range(num_tiers):
             ring = rings[t]
             rd = ring_deltas.get(ring.shape[1], delta)
-            ring = ring.at[slots[t]].multiply(keeps[t], mode="drop")
-            ring = ring.at[slots[t]].add(rd, mode="drop")
+            ring = _add_open_slot(ring, slots[t], keeps[t], rd)
             new_rings.append(ring)
             payloads.append(
                 window_snapshot(ring, masks[t], bucket_limit, precision,
@@ -544,8 +565,8 @@ def make_paged_fused_commit_fn(num_tiers: int, track_activity: bool = False):
         new_rings = []
         for t in range(num_tiers):
             ring = rings[t]
-            ring = ring.at[slots[t]].multiply(keeps[t], mode="drop")
-            ring = ring.at[slots[t], ids, idx].add(weights, mode="drop")
+            ring = _fold_open_slot(ring, slots[t], keeps[t], ids, idx,
+                                   weights)
             new_rings.append(ring)
         out = [pool, tuple(new_rings)]
         if track_activity:
@@ -593,8 +614,8 @@ def make_paged_fused_commit_snapshot_fn(
         payloads = []
         for t in range(num_tiers):
             ring = rings[t]
-            ring = ring.at[slots[t]].multiply(keeps[t], mode="drop")
-            ring = ring.at[slots[t], ids, idx].add(weights, mode="drop")
+            ring = _fold_open_slot(ring, slots[t], keeps[t], ids, idx,
+                                   weights)
             new_rings.append(ring)
             payloads.append(
                 window_snapshot(ring, masks[t], bucket_limit, precision,
@@ -687,8 +708,7 @@ def make_sharded_paged_fused_commit_fn(
         for t in range(num_tiers):
             ring = rings[t]
             rd = parts[f"ring{ring.shape[1]}"]
-            ring = ring.at[slots[t]].multiply(keeps[t], mode="drop")
-            ring = ring.at[slots[t]].add(rd, mode="drop")
+            ring = _add_open_slot(ring, slots[t], keeps[t], rd)
             new_rings.append(ring)
         out = [pool, tuple(new_rings)]
         if track_activity:
@@ -752,8 +772,7 @@ def make_sharded_paged_fused_commit_snapshot_fn(
         for t in range(num_tiers):
             ring = rings[t]
             rd = parts[f"ring{ring.shape[1]}"]
-            ring = ring.at[slots[t]].multiply(keeps[t], mode="drop")
-            ring = ring.at[slots[t]].add(rd, mode="drop")
+            ring = _add_open_slot(ring, slots[t], keeps[t], rd)
             new_rings.append(ring)
             payloads.append(
                 window_snapshot(ring, masks[t], bucket_limit, precision,
@@ -791,67 +810,55 @@ def make_sharded_paged_fused_commit_snapshot_fn(
     )
 
 
-class CellStagingRing:
-    """Depth-D double-buffered H2D staging for interval cell arrays.
+def _fresh(width: int, n: int, head: np.ndarray, pad, dtype=np.int32):
+    """A new host array of ``width`` rows: ``head`` then ``pad``.  Staged
+    uploads never reuse a host buffer: ``jax.device_put`` may alias one
+    (the CPU backend does, ``may_alias=False`` or not) or still be
+    reading it after it returns (an async H2D copy), so a buffer handed
+    to it is never written again."""
+    out = np.empty((width,) + head.shape[1:], dtype=dtype)
+    out[:n] = head
+    out[n:] = pad
+    return out
 
-    Each slot owns reusable pinned host pad arrays ``(ids, idx,
-    weights)`` of the fixed commit width; ``stage()`` writes one chunk
-    into the next slot, pads the tail with drop sentinels, and issues an
-    async ``jax.device_put`` — the transfer of the NEXT chunk/interval
+
+class CellStagingRing:
+    """H2D staging for interval cell arrays.
+
+    ``stage()`` pads one chunk to the fixed commit width with drop
+    sentinels in freshly allocated host arrays and issues an async
+    ``jax.device_put`` — the transfer of the NEXT chunk/interval
     overlaps the commit dispatch of the previous one, because
     ``device_put`` and the jitted commit both return before the device
-    work completes.
-
-    Depth 2 (the default) is the minimum that makes the overlap safe:
-    slot k's host buffers are only rewritten once a commit has been
-    dispatched against slot k^1, so the copy engine is never racing the
-    host writes of the transfer it is consuming.  Upload accounting
-    (``uploads``, ``bytes_uploaded``) feeds the committer's
-    H2D-bytes-per-interval gauge.
+    work completes.  Upload accounting (``uploads``, ``bytes_uploaded``)
+    feeds the committer's H2D-bytes-per-interval gauge.
     """
 
-    def __init__(self, depth: int = 2, width: int = COMMIT_CHUNK,
-                 sharding=None):
-        if depth < 2:
-            raise ValueError("staging ring depth must be >= 2 (the "
-                             "overlap contract needs one slot of slack)")
-        self.depth = depth
+    def __init__(self, width: int = COMMIT_CHUNK, sharding=None):
         self.width = width
         # under a mesh the cell chunk uploads stream-sharded (each
         # device receives its slice of the staged pad arrays); the
         # sharded commit programs consume it as P(STREAM_AXIS) operands
         self.sharding = sharding
-        self._slots = [
-            (
-                np.empty(width, dtype=np.int32),
-                np.empty(width, dtype=np.int32),
-                np.empty(width, dtype=np.int32),
-            )
-            for _ in range(depth)
-        ]
-        self._next = 0
         self.uploads = 0          # lifetime stage() calls
         self.bytes_uploaded = 0   # lifetime H2D bytes issued
 
     def stage(self, ids: np.ndarray, idx: np.ndarray, weights: np.ndarray):
-        """Pad one cell chunk (len <= width) into the next host slot and
-        start its async upload; returns the device arrays."""
+        """Pad one cell chunk (len <= width) and start its async upload;
+        returns the device arrays."""
         n = len(ids)
         if n > self.width:
             raise ValueError(f"chunk of {n} cells exceeds staging width "
                              f"{self.width}")
-        hid, hidx, hw = self._slots[self._next]
-        self._next = (self._next + 1) % self.depth
-        hid[:n] = ids
-        hid[n:] = DROP_ID
-        hidx[:n] = idx
-        hidx[n:] = 0
-        hw[:n] = weights
-        hw[n:] = 0
+        host = (
+            _fresh(self.width, n, ids, DROP_ID),
+            _fresh(self.width, n, idx, 0),
+            _fresh(self.width, n, weights, 0),
+        )
         dev = (
-            jax.device_put((hid, hidx, hw), self.sharding)
+            jax.device_put(host, self.sharding)
             if self.sharding is not None
-            else jax.device_put((hid, hidx, hw))
+            else jax.device_put(host)
         )
         self.uploads += 1
         self.bytes_uploaded += 3 * self.width * 4
@@ -860,41 +867,27 @@ class CellStagingRing:
 
 class PagedTripleRing:
     """``CellStagingRing``'s twin for the paged committer's translated
-    ``(slot, offset, count)`` triples: same depth/overlap contract,
-    same fixed width (the commit chunk, so one executable serves every
-    interval), pad sentinel slot -1 (``paged_scatter_batch`` drops it).
-    Under a mesh the upload splits over the stream axis
+    ``(slot, offset, count)`` triples: same fresh-buffer contract, same
+    fixed width (the commit chunk, so one executable serves every
+    interval), pad row ``(-1, 0, 0)`` (``paged_scatter_batch`` drops
+    slot -1).  Under a mesh the upload splits over the stream axis
     (``triple_sharding``), matching the sharded paged commit's
     ``P(STREAM_AXIS, None)`` operand spec."""
 
-    def __init__(self, depth: int = 2, width: int = COMMIT_CHUNK,
-                 sharding=None):
-        if depth < 2:
-            raise ValueError("staging ring depth must be >= 2 (the "
-                             "overlap contract needs one slot of slack)")
-        self.depth = depth
+    def __init__(self, width: int = COMMIT_CHUNK, sharding=None):
         self.width = width
         self.sharding = sharding
-        self._slots = [
-            np.empty((width, 3), dtype=np.int32) for _ in range(depth)
-        ]
-        self._next = 0
         self.uploads = 0
         self.bytes_uploaded = 0
 
     def stage(self, triples: np.ndarray):
-        """Pad one translated triple chunk (len <= width) into the next
-        host slot and start its async upload; returns the device array."""
+        """Pad one translated triple chunk (len <= width) and start its
+        async upload; returns the device array."""
         n = len(triples)
         if n > self.width:
             raise ValueError(f"chunk of {n} triples exceeds staging "
                              f"width {self.width}")
-        buf = self._slots[self._next]
-        self._next = (self._next + 1) % self.depth
-        buf[:n] = triples
-        buf[n:, 0] = -1
-        buf[n:, 1] = 0
-        buf[n:, 2] = 0
+        buf = _fresh(self.width, n, triples, np.array([-1, 0, 0]))
         if self.sharding is not None:
             # collective-free across real jax.distributed processes
             # (every process stages the identical translated chunk)

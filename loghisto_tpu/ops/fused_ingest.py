@@ -445,7 +445,9 @@ def make_sharded_fused_paged_ingest_fn(
     """
     from jax.sharding import PartitionSpec as P
 
-    from loghisto_tpu.parallel.mesh import METRIC_AXIS, STREAM_AXIS, shard_map
+    from jax import shard_map
+
+    from loghisto_tpu.parallel.mesh import METRIC_AXIS, STREAM_AXIS
 
     def _local(pool_local, ids, values, row_codec_local, enc_luts, tbl_local):
         shard = jax.lax.axis_index(METRIC_AXIS)

@@ -1,8 +1,8 @@
 """Hybrid hot-row histogram accumulation: MXU matmul for the hot head,
 scatter for the cold tail.
 
-Honest device-path measurements (TPU_CAPTURE_r2e, value-verified in
-r2f) show the two regimes:
+The two regimes it splits (device rates not measured on this chip
+yet):
 
   * one-hot matmul (ops/matmul_hist.py) sustains hundreds of
     M samples/s but its MAC cost grows linearly with the covered row

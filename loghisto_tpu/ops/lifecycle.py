@@ -163,11 +163,26 @@ def compact_rows(arr: jnp.ndarray, perm: jnp.ndarray) -> jnp.ndarray:
     )
 
 
-def _compact_kernel(perm_ref, in_ref, out_ref):
-    i = pl.program_id(0)
+# Output rows per compaction block: the int32 sublane tile.
+COMPACT_ROWS = 8
 
-    # the index_map clamped an empty row's source to 0; zero it here
-    out_ref[:] = jnp.where(perm_ref[i] >= 0, in_ref[:], 0)
+
+def _compact_kernel(perm_ref, in_ref, out_ref):
+    """Grid step (i, r): copy source row perm[8i + r] into row r of the
+    resident output block i.  The input block is the (8, B) tile that
+    holds the source row (a one-row block of a tiled array is not a
+    legal block), and the row is picked and placed with sublane masks."""
+    i = pl.program_id(0)
+    r = pl.program_id(1)
+    src = perm_ref[i * COMPACT_ROWS + r]
+    sub = jax.lax.broadcasted_iota(jnp.int32, in_ref.shape, 0)
+    row = jnp.sum(
+        jnp.where(sub == src % COMPACT_ROWS, in_ref[...], 0),
+        axis=0, keepdims=True,
+    )
+    # an empty row (negative perm) fetched block 0; it writes zeros
+    row = jnp.where(src >= 0, row, 0)
+    out_ref[...] = jnp.where(sub == r, row, out_ref[...])
 
 
 def compact_rows_pallas(
@@ -176,39 +191,44 @@ def compact_rows_pallas(
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Pallas tier: the survivor permutation rides scalar prefetch and
-    drives the input BlockSpec's index_map directly, so the repack reads
-    each survivor row from HBM once and writes each output row once —
-    the same bandwidth-floor structure as window_merge_pallas, with the
-    gather hidden in block indexing instead of a device-side take.
-    Empty rows (negative / DROP sentinel) clamp to row 0 for the fetch
-    and are zeroed in the kernel."""
+    drives the input BlockSpec's index_map, so the repack is a gather
+    hidden in block indexing.  The grid sweeps the 8 rows of each
+    output block innermost; the output block stays resident across
+    them, and consecutive rows from the same source tile (an
+    order-keeping compaction) fetch that tile once.  Empty rows
+    (negative / DROP sentinel) are zero."""
     if interpret is None:
         interpret = default_interpret()
     m, b = arr.shape
     n = perm.shape[0]
+    n_pad = (n + COMPACT_ROWS - 1) // COMPACT_ROWS * COMPACT_ROWS
     # sanitize the sentinel into -1 so the kernel's sign test works for
     # both DROP_ID pads and explicit -1 holes
     perm32 = jnp.where(
         (perm >= 0) & (perm < m), perm.astype(jnp.int32), -1
     )
+    perm32 = jnp.pad(perm32, (0, n_pad - n), constant_values=-1)
+
+    def src_block(i, r, pr):
+        return (jnp.maximum(pr[i * COMPACT_ROWS + r], 0) // COMPACT_ROWS, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, b), lambda i, pr: (jnp.maximum(pr[i], 0), 0)),
-        ],
-        out_specs=pl.BlockSpec((1, b), lambda i, pr: (i, 0)),
+        grid=(n_pad // COMPACT_ROWS, COMPACT_ROWS),
+        in_specs=[pl.BlockSpec((COMPACT_ROWS, b), src_block)],
+        out_specs=pl.BlockSpec((COMPACT_ROWS, b), lambda i, r, pr: (i, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _compact_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, b), arr.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_pad, b), arr.dtype),
         interpret=interpret,
     )(perm32, arr)
+    return out if n_pad == n else out[:n]
 
 
 def resolve_compact_path(path: str, platform: str, mesh: bool) -> str:
-    """Dispatch policy for the repack, mirroring resolve_merge_path:
+    """Dispatch policy for the repack, like the ingest dispatch:
     "auto" picks the Pallas tier only single-device on real TPU (Pallas
     under shard_map is off the table; interpret mode off-TPU is strictly
     slower than the jnp gather)."""

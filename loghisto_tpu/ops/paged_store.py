@@ -28,12 +28,12 @@ pure weighted scatter into the pool: O(occupied cells) H2D, O(mapped
 pages) HBM, no codec work, no dense row materialization.
 
 Two commit tiers, bit-identical by construction (the Pallas tier reuses
-the sparse-ingest per-cell DMA row round-trip with pool pages as the
-rows — a [1, page_size] row DMA is lane-aligned at the default 256,
-unlike the 8193-wide dense rows):
+the sparse-ingest per-cell (8, 128)-tile DMA round-trip with pool pages
+as the rows — lane-aligned at the default 256, so no cell needs the
+ragged-edge XLA scatter the 8193-wide dense rows do):
 
   * "jnp"    — XLA weighted scatter-add over the flat pool view;
-  * "pallas" — per-cell DMA page round-trip through a VMEM scratch
+  * "pallas" — per-cell DMA tile round-trip through a VMEM scratch
     (ops/sparse_ingest.py's kernel, parameterized by the pool shape).
 
 Query serving gathers only a row's mapped pages and expands them
@@ -51,7 +51,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from loghisto_tpu.ops.backend import default_interpret
 
 # Buckets per page.  256 int32 = 1 KiB per page, two full TPU vector
 # lanes rows — page DMAs are lane-aligned, and at B=8193 a dense row is
@@ -118,58 +117,24 @@ def pallas_paged_scatter(
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Pallas tier: same contract as paged_scatter_batch, executed as
-    the sparse-ingest per-cell DMA round-trip with pool pages as the
-    rows (one [1, page_size] VMEM scratch, serial grid => exact integer
-    accumulation for duplicate cells)."""
-    from loghisto_tpu.ops.sparse_ingest import TRIPLE_TILE, _pallas_kernel
+    the sparse-ingest per-cell tile DMA round-trip with pool pages as
+    the rows (serial grid => exact integer accumulation for duplicate
+    cells)."""
+    from loghisto_tpu.ops.sparse_ingest import pallas_cell_scatter
 
-    if interpret is None:
-        interpret = default_interpret()
     if packed.ndim != 2 or packed.shape[1] != 3:
         raise ValueError(
             f"packed must be [n, 3] (slot, offset, count); got {packed.shape}"
         )
-    pages, page_size = pool.shape
-    n = packed.shape[0]
-    g = max(1, (n + TRIPLE_TILE - 1) // TRIPLE_TILE)
-    padded = g * TRIPLE_TILE
-    if padded != n:
-        pad = jnp.zeros((padded - n, 3), dtype=jnp.int32)
-        pad = pad.at[:, 0].set(-1)
-        packed = jnp.concatenate([packed, pad])
+    page_size = pool.shape[1]
     slots = packed[:, 0]
-    # the kernel bounds-guards ids to [0, rows); shift the zero page out
-    # of range too so nothing can ever write it
+    # the kernel bounds-guards rows to [0, pages); shift the zero page
+    # out of range too so nothing can ever write it
     slots = jnp.where(slots <= ZERO_SLOT, jnp.int32(-1), slots)
-    ids = slots.reshape(g, TRIPLE_TILE)
-    offs = jnp.clip(packed[:, 1], 0, page_size - 1).reshape(g, TRIPLE_TILE)
-    weights = packed[:, 2].reshape(g, TRIPLE_TILE)
-
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    smem_spec = pl.BlockSpec(
-        (1, TRIPLE_TILE), lambda i: (i, 0), memory_space=pltpu.SMEM
+    offs = jnp.clip(packed[:, 1], 0, page_size - 1)
+    return pallas_cell_scatter(
+        pool, slots, offs, packed[:, 2], interpret=interpret
     )
-    return pl.pallas_call(
-        functools.partial(_pallas_kernel, num_metrics=pages),
-        grid=(g,),
-        in_specs=[
-            smem_spec,
-            smem_spec,
-            smem_spec,
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((1, page_size), jnp.int32),
-            pltpu.SemaphoreType.DMA(()),
-            pltpu.SemaphoreType.DMA(()),
-        ],
-        input_output_aliases={3: 0},
-        interpret=interpret,
-    )(ids, offs, weights, pool)
 
 
 def make_paged_commit_fn(kernel: str = "jnp"):
@@ -213,7 +178,9 @@ def make_sharded_paged_commit_fn(mesh, shard_pages: int):
     """
     from jax.sharding import PartitionSpec as P
 
-    from loghisto_tpu.parallel.mesh import METRIC_AXIS, STREAM_AXIS, shard_map
+    from jax import shard_map
+
+    from loghisto_tpu.parallel.mesh import METRIC_AXIS, STREAM_AXIS
 
     def _local(pool_local, packed):
         shard = jax.lax.axis_index(METRIC_AXIS)
@@ -276,10 +243,12 @@ def make_paged_query_fn(bucket_limit: int, precision: int):
         native = native.at[:, dec_lut].add(storage)
         cdf = jnp.cumsum(native, axis=1, dtype=jnp.int32)
         counts = cdf[:, -1]
-        from loghisto_tpu.ops.stats import bucket_representatives
+        from loghisto_tpu.ops.stats import (
+            bucket_representatives, weighted_sums,
+        )
 
         reps = bucket_representatives(bucket_limit, precision)
-        sums = native.astype(jnp.float32) @ reps
+        sums = weighted_sums(native.astype(jnp.float32), reps)
         return snapshot_row_stats(
             cdf, counts, sums, ps, bucket_limit, precision
         )

@@ -15,8 +15,7 @@ benchmark (readme.md:27: ~20M samples/s/process in Go; the MXU sustains
 ~2 samples/cycle at 8k buckets).  The multi-metric scatter path stays on
 XLA (see ops/ingest.py); per-metric-tile generalization is future work.
 
-Falls back to interpret mode automatically off-TPU so CI exercises the
-same code path.
+Runs in interpret mode off-TPU so CI exercises the same code path.
 """
 
 from __future__ import annotations
@@ -29,11 +28,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from loghisto_tpu.config import PRECISION
-# shared backend probe (ops/backend.py); every kernel module now calls
-# backend.default_interpret() directly (r14 probe dedup) — the `_on_tpu`
-# alias stays importable for external callers only
 from loghisto_tpu.ops.backend import default_interpret
-from loghisto_tpu.ops.backend import on_tpu as _on_tpu  # noqa: F401
 from loghisto_tpu.ops.ingest import bucket_indices
 
 LANES = 128
@@ -168,15 +163,15 @@ def _hist_kernel_masked(values_ref, mask_ref, acc_ref, out_ref, scratch_ref,
         scratch_ref[:] = jnp.zeros_like(scratch_ref)
 
     v = values_ref[0, :]
-    m = mask_ref[0, :] != 0  # [T] bool
     bucket = bucket_indices(v, bucket_limit, precision)
-    hi = bucket // LANES
+    # a masked sample's hi index is h, which matches no iota column, so
+    # its one-hot row is zero (Mosaic cannot reshape a [T] bool vector
+    # to [T, 1], so the mask is folded into the int32 index instead)
+    hi = jnp.where(mask_ref[0, :] != 0, bucket // LANES, h)
     lo = bucket % LANES
     hi_iota = jax.lax.broadcasted_iota(jnp.int32, (v.shape[0], h), 1)
     lo_iota = jax.lax.broadcasted_iota(jnp.int32, (v.shape[0], LANES), 1)
-    onehot_hi = (
-        (hi[:, None] == hi_iota) & m[:, None]
-    ).astype(jnp.bfloat16)
+    onehot_hi = (hi[:, None] == hi_iota).astype(jnp.bfloat16)
     onehot_lo = (lo[:, None] == lo_iota).astype(jnp.bfloat16)
     partial = jax.lax.dot_general(
         onehot_hi, onehot_lo,

@@ -194,6 +194,28 @@ def dense_stats_np(
     return {"counts": counts, "sums": sums, "percentiles": pct}
 
 
+def weighted_sums(counts_f, reps):
+    """Per-row sum of bucket representatives weighted by counts, a
+    matvec on the MXU.  At full float32 precision: the TPU's default
+    multiplies float32 operands in bfloat16, whose 8-bit mantissa
+    cannot hold a bucket count above 256."""
+    return jnp.matmul(counts_f, reps, precision=jax.lax.Precision.HIGHEST)
+
+
+def _rank_threshold(k0, ps, total_f):
+    """The smallest integer count k (as float32) with k / total >= p,
+    searched in the +/-1 window around ``k0 = ceil(p * total)``.  The
+    test is ``k >= p * total``: float32 division is not correctly
+    rounded on a TPU (PR 21's chip run: 368,732 of 2^20 quotients
+    differed from IEEE), and a quotient one ulp short at an exact tie
+    moved the rank one sample up; a float32 product is exact-rounded,
+    and at a tie it rounds to the integer itself."""
+    cands = k0[:, :, None] + jnp.arange(-1.0, 2.0)  # [M, P, 3]
+    ok = (cands >= ps[None, :, None] * total_f[:, :, None]) & (cands >= 1.0)
+    best = jnp.min(jnp.where(ok, cands, jnp.inf), axis=2)
+    return jnp.where(jnp.isfinite(best), best, k0)
+
+
 def dense_stats(
     acc: jnp.ndarray,
     ps: jnp.ndarray,
@@ -215,7 +237,7 @@ def dense_stats(
     num_buckets = acc.shape[1]
     acc_f = acc.astype(jnp.float32)
     reps = bucket_representatives(bucket_limit, precision)
-    sums = acc_f @ reps  # matvec on the MXU
+    sums = weighted_sums(acc_f, reps)
     # Hierarchical CDF: a full [M, B] cumsum lowers as ~log2(B) whole-
     # array passes (measured 0.9s of a 1.1s CPU stats call at 10k x 8193);
     # instead reduce to per-block sums in ONE pass (LANE-sized blocks — a
@@ -234,7 +256,7 @@ def dense_stats(
 
     ps = jnp.asarray(ps, dtype=jnp.float32)
 
-    # Selection rule: first bucket with f32(cdf)/f32(total) >= p.  Instead
+    # Selection rule: first bucket with cdf/total >= p.  Instead
     # of materializing the [M, B] float CDF (a full extra array + division
     # per cell), derive the integer rank threshold k*[m, p] = the smallest
     # integer count satisfying the float division — an [M, P] computation —
@@ -249,10 +271,7 @@ def dense_stats(
     # backend-defined.
     total_f = jnp.maximum(counts, 1).astype(jnp.float32)[:, None]  # [M,1]
     k0 = jnp.ceil(ps[None, :] * total_f)  # [M, P] first candidate
-    cands = k0[:, :, None] + jnp.arange(-1.0, 2.0)  # [M, P, 3]
-    ok = (cands / total_f[:, :, None] >= ps[None, :, None]) & (cands >= 1.0)
-    best = jnp.min(jnp.where(ok, cands, jnp.inf), axis=2)
-    k_star_f = jnp.where(jnp.isfinite(best), best, k0)
+    k_star_f = _rank_threshold(k0, ps, total_f)
     # int32-representable float clamp BEFORE the cast (f32(2^31) itself
     # casts implementation-defined), then the exact integer clamp
     k_star_f = jnp.clip(k_star_f, 1.0, jnp.float32(2**31 - 256))
@@ -365,7 +384,7 @@ def dense_cdf(
     return {
         "cdf": cdf,
         "counts": cdf[:, -1],
-        "sums": acc.astype(jnp.float32) @ reps,
+        "sums": weighted_sums(acc.astype(jnp.float32), reps),
     }
 
 
@@ -387,10 +406,7 @@ def snapshot_row_stats(
     ps = jnp.asarray(ps, dtype=jnp.float32)
     total_f = jnp.maximum(counts, 1).astype(jnp.float32)[:, None]  # [n,1]
     k0 = jnp.ceil(ps[None, :] * total_f)  # [n, P]
-    cands = k0[:, :, None] + jnp.arange(-1.0, 2.0)  # [n, P, 3]
-    ok = (cands / total_f[:, :, None] >= ps[None, :, None]) & (cands >= 1.0)
-    best = jnp.min(jnp.where(ok, cands, jnp.inf), axis=2)
-    k_star_f = jnp.where(jnp.isfinite(best), best, k0)
+    k_star_f = _rank_threshold(k0, ps, total_f)
     k_star_f = jnp.clip(k_star_f, 1.0, jnp.float32(2**31 - 256))
     total_i = jnp.maximum(counts, 1)[:, None]
     k_star = jnp.minimum(k_star_f.astype(jnp.int32), total_i)

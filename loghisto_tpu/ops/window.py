@@ -107,17 +107,21 @@ def window_merge_pallas(
 
 
 def resolve_merge_path(path: str, platform: str, mesh: bool) -> str:
-    """Shared dispatch policy for the window merge: "auto" picks the
-    Pallas tier only single-device on real TPU hardware (the same
-    constraint as ingest dispatch — Pallas inside shard_map is off the
-    table, and interpret mode off-TPU is strictly slower than the jnp
-    reduction)."""
+    """Shared dispatch policy for the window merge: "auto" is the jnp
+    reduction everywhere.  The Pallas tier stays for explicit selection,
+    single-device only (Pallas inside shard_map is off the table).  On
+    a v5e it cannot serve the fused commit at 10k x 8193: the TPU's
+    default layout of an int32 [S, M, B] ring puts the bucket axis
+    major, the kernel needs it minor, and the layout-conversion copy of
+    every ring it reads took 7.75 GB of HBM next to 7.9 GB of rings
+    (PR 21's chip run: RESOURCE_EXHAUSTED loading the commit program)."""
+    del platform
     if path not in ("auto", "jnp", "pallas"):
         raise ValueError(
             f"merge_path={path!r}: expected 'auto', 'jnp', or 'pallas'"
         )
     if path == "auto":
-        return "pallas" if (platform == "tpu" and not mesh) else "jnp"
+        return "jnp"
     if path == "pallas" and mesh:
         raise ValueError("merge_path='pallas' is single-device; use jnp "
                          "with a mesh")

@@ -31,6 +31,7 @@ from typing import Dict, Mapping, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -45,7 +46,7 @@ from loghisto_tpu.ops.ingest import (
 )
 from loghisto_tpu.ops.dispatch import resolve_ingest_path
 from loghisto_tpu.ops.stats import dense_stats, dense_stats_np
-from loghisto_tpu.parallel.mesh import METRIC_AXIS, STREAM_AXIS, shard_map
+from loghisto_tpu.parallel.mesh import METRIC_AXIS, STREAM_AXIS
 from loghisto_tpu.registry import MetricRegistry, RegistryFullError
 
 # Default registry-growth headroom: max_metrics = num_metrics * this when
@@ -66,20 +67,20 @@ _PROBE_SAMPLES = 1 << 16
 
 
 class IngestStagingRing:
-    """Depth-K reusable host staging slots for the transfer worker — the
-    CellStagingRing idea (ops/commit.py) generalized to the raw
+    """Bounded async H2D staging for the transfer worker — the
+    CellStagingRing idea (ops/commit.py) applied to the raw
     (ids, values) wire.
 
-    ``stage()`` copies a chunk into the next slot, pads the tail with id
-    -1 (every ingest kernel drops it), and issues the async
+    ``stage()`` copies a chunk into fresh host arrays, pads the tail
+    with id -1 (every ingest kernel drops it), and issues the async
     ``device_put`` — which returns before the H2D copy completes, so the
-    upload of slot i overlaps the donated ingest dispatches still
-    consuming slot i-1.  Before a slot is REUSED (depth stages later)
-    its previous device arrays are ``block_until_ready``'d: a ready
-    device array means its H2D copy has finished reading the host
-    buffer, so overwriting the slot can never corrupt an in-flight
-    transfer.  Depth 2 is the minimum for overlap; 3 keeps one slot
-    filling, one in flight, one being consumed."""
+    upload of chunk i overlaps the donated ingest dispatches still
+    consuming chunk i-1.  A host buffer handed to ``device_put`` is
+    never written again (the upload may alias it or read it late).
+    ``depth`` bounds the uploads in flight: before the (depth+1)-th is
+    issued, the oldest is ``block_until_ready``'d.  Depth 2 is the
+    minimum for overlap; 3 keeps one filling, one in flight, one being
+    consumed."""
 
     def __init__(self, slot_samples: int, depth: int = 3,
                  chunk_samples: Optional[int] = None):
@@ -88,7 +89,7 @@ class IngestStagingRing:
         if slot_samples < 1:
             raise ValueError(f"slot_samples must be >= 1, got {slot_samples}")
         self.slot_samples = int(slot_samples)
-        # upload quantum: a partially-filled slot uploads only its prefix
+        # upload quantum: a partial chunk uploads only its prefix
         # rounded up to this (the dispatch loop consumes chunk_samples
         # slices) — a 1-batch item must not pay the full 8-batch slot on
         # the wire.  Default = whole slot.
@@ -99,22 +100,15 @@ class IngestStagingRing:
                 f"got {self.chunk_samples}"
             )
         self.depth = int(depth)
-        self._ids = [
-            np.full(self.slot_samples, -1, dtype=np.int32)
-            for _ in range(depth)
-        ]
-        self._values = [
-            np.zeros(self.slot_samples, dtype=np.float32)
-            for _ in range(depth)
-        ]
         self._inflight: list[Optional[tuple]] = [None] * depth
         self._next = 0
         self.uploads = 0
         self.bytes_uploaded = 0
 
     def stage(self, ids: np.ndarray, values: np.ndarray):
-        """Copy one chunk (<= slot_samples) into the next slot and start
-        its async upload; returns the (ids, values) device arrays."""
+        """Copy one chunk (<= slot_samples) into fresh host arrays and
+        start its async upload; returns the (ids, values) device
+        arrays."""
         n = len(ids)
         if n > self.slot_samples:
             raise ValueError(f"chunk of {n} exceeds slot {self.slot_samples}")
@@ -130,33 +124,28 @@ class IngestStagingRing:
                     # the old transfer errored — its batch was already
                     # requeued/shed by the failure path; the slot is free
                     pass
-        slot_ids, slot_values = self._ids[i], self._values[i]
-        slot_ids[:n] = ids
-        slot_values[:n] = values
         chunk = self.chunk_samples
         padded = min(self.slot_samples, -(-n // chunk) * chunk)
-        if n < padded:
-            slot_ids[n:padded] = -1
-            slot_values[n:padded] = 0.0
-        # contiguous prefix view: only the chunk-rounded fill crosses the
-        # wire, not the whole slot
-        ids_dev = jax.device_put(slot_ids[:padded])
-        values_dev = jax.device_put(slot_values[:padded])
+        host_ids = np.full(padded, -1, dtype=np.int32)
+        host_ids[:n] = ids
+        host_values = np.zeros(padded, dtype=np.float32)
+        host_values[:n] = values
+        ids_dev = jax.device_put(host_ids)
+        values_dev = jax.device_put(host_values)
         self._inflight[i] = (ids_dev, values_dev)
         self.uploads += 1
         self.bytes_uploaded += padded * (
-            slot_ids.itemsize + slot_values.itemsize
+            host_ids.itemsize + host_values.itemsize
         )
         return ids_dev, values_dev
 
     def drain(self) -> None:
         """Block until EVERY in-flight async upload has completed (or
-        surfaced its failure), then release the slots.  ``stage()`` only
-        waits for the slot it is about to reuse, so with the r13
-        double-buffered dispatch loop up to ``depth`` uploads can still
-        be in flight when the pipeline goes quiet — ``close()`` must
-        drain them all before the final interval commits, or a host
-        buffer could be torn down under a H2D copy still reading it.
+        surfaced its failure).  ``stage()`` only waits for the oldest
+        upload, so with the r13 double-buffered dispatch loop up to
+        ``depth`` uploads can still be in flight when the pipeline goes
+        quiet — ``close()`` drains them all before the final interval
+        commits.
         Failed transfers are swallowed like in ``stage()``: their batch
         was already requeued/shed by the failure path."""
         for i, prev in enumerate(self._inflight):

@@ -26,14 +26,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 STREAM_AXIS = "stream"
 METRIC_AXIS = "metric"
 
-# jax moved shard_map out of jax.experimental at 0.6; every call site in
-# the package routes through this name so both spellings work.
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # jax < 0.6
-    from jax.experimental.shard_map import shard_map
-
-
 # -- canonical carry shardings ---------------------------------------------- #
 # Every device carry in the sharded commit pipeline uses one of these
 # four layouts; the committer, the lifecycle/anomaly managers, and the
@@ -78,6 +70,20 @@ def triple_sharding(mesh: Mesh) -> NamedSharding:
     like cell chunks — each device scatters its slice into a local pool
     delta and ONE psum merges them (int32 ⇒ order-independent)."""
     return NamedSharding(mesh, PartitionSpec(STREAM_AXIS, None))
+
+
+def sharded_zeros(shape, sharding=None) -> jax.Array:
+    """int32 zeros created in place under ``sharding`` (each device
+    makes its own shards).  ``jax.device_put`` of a ``jnp.zeros`` would
+    first build the whole array on one device — at 10k x 8193 a
+    16-slot ring is 5.2 GB there before it is split."""
+    import jax.numpy as jnp
+
+    if sharding is None:
+        return jnp.zeros(shape, dtype=jnp.int32)
+    return jax.jit(
+        lambda: jnp.zeros(shape, dtype=jnp.int32), out_shardings=sharding
+    )()
 
 
 def make_mesh(

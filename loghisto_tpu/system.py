@@ -551,7 +551,6 @@ class TPUMetricSystem(MetricSystem):
                 "intervals_committed": self.committer.intervals_committed,
                 "fused_intervals": self.committer.fused_intervals,
                 "fanout_intervals": self.committer.fanout_intervals,
-                "staging_depth": self.committer._staging.depth,
             }
         dump["obs"] = {
             "enabled": self.obs is not None,

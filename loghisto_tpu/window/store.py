@@ -58,6 +58,7 @@ from loghisto_tpu.ops.window import (
     make_window_stats_fn,
     resolve_merge_path,
 )
+from loghisto_tpu.parallel.mesh import sharded_zeros
 from loghisto_tpu.registry import MetricRegistry, RegistryFullError
 from loghisto_tpu.window.snapshot import (
     QueryPlanCache,
@@ -121,9 +122,9 @@ class _Tier:
     def __init__(self, spec: TierSpec, num_metrics: int, num_buckets: int,
                  sharding=None):
         self.spec = spec
-        z = jnp.zeros((spec.slots, num_metrics, num_buckets),
-                      dtype=jnp.int32)
-        self.ring = jax.device_put(z, sharding) if sharding is not None else z
+        self.ring = sharded_zeros(
+            (spec.slots, num_metrics, num_buckets), sharding
+        )
         self.slot = 0            # open slot index
         self.in_slot = 0         # intervals landed in the open slot
         self.written = np.zeros(spec.slots, dtype=bool)

@@ -263,10 +263,8 @@ def test_giant_cell_weight_routes_interval_to_fanout():
 # staging ring + fused program contracts
 # ---------------------------------------------------------------------- #
 
-def test_staging_ring_depth_and_width_contracts():
-    with pytest.raises(ValueError):
-        CellStagingRing(depth=1)
-    ring = CellStagingRing(depth=2, width=8)
+def test_staging_ring_width_contract():
+    ring = CellStagingRing(width=8)
     with pytest.raises(ValueError):
         ring.stage(np.zeros(9, np.int32), np.zeros(9, np.int32),
                    np.zeros(9, np.int32))
@@ -278,6 +276,35 @@ def test_staging_ring_depth_and_width_contracts():
     assert (np.asarray(dev_w)[2:] == 0).all()
     assert ring.uploads == 1
     assert ring.bytes_uploaded == 3 * 8 * 4
+
+
+@pytest.mark.parametrize("ring_kind", ["cells", "triples", "ingest"])
+def test_staged_upload_survives_later_stages(ring_kind):
+    """A staged upload keeps its contents however many chunks are staged
+    after it: no host buffer handed to device_put is refilled while the
+    device array can still read it (jax 0.9's CPU device_put aliases
+    the host buffer; on a TPU the H2D copy is asynchronous)."""
+    from loghisto_tpu.ops.commit import PagedTripleRing
+    from loghisto_tpu.parallel.aggregator import IngestStagingRing
+
+    width = 1 << 12  # large enough for an aligned, aliasable buffer
+
+    def chunk(k):
+        return np.arange(3, dtype=np.int32) + 10 * k
+
+    if ring_kind == "cells":
+        ring = CellStagingRing(width=width)
+        stage = lambda k: ring.stage(chunk(k), chunk(k), chunk(k))[0]
+    elif ring_kind == "triples":
+        ring = PagedTripleRing(width=width)
+        stage = lambda k: ring.stage(np.stack([chunk(k)] * 3, axis=1))[:, 0]
+    else:
+        ring = IngestStagingRing(slot_samples=width, depth=2)
+        stage = lambda k: ring.stage(chunk(k), chunk(k).astype(np.float32))[0]
+    first = stage(0)
+    for k in range(1, 6):
+        stage(k)
+    np.testing.assert_array_equal(np.asarray(first)[:3], chunk(0))
 
 
 def test_warmup_is_a_numerical_noop():

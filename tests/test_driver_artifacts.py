@@ -54,11 +54,14 @@ def test_plausibility_cap_scales_with_accumulator():
     import bench
 
     vmem = 128 * 1024 * 1024
-    assert bench.plausibility_cap_samples_per_s("tpu", vmem) == 4e12 / 8
-    assert bench.plausibility_cap_samples_per_s("tpu", vmem + 1) == 4e12 / 16
+    v5e = 819e9  # Google Cloud, "TPU v5e"
+    assert bench.plausibility_cap_samples_per_s("TPU v5 lite", vmem) == v5e / 8
+    assert bench.plausibility_cap_samples_per_s(
+        "TPU v5 lite", vmem + 1) == v5e / 16
     assert bench.plausibility_cap_samples_per_s("cpu", 1 << 30) == 4e11 / 16
-    # unknown platforms get the accelerator ceiling, not a free pass
-    assert bench.plausibility_cap_samples_per_s("rocm", 1 << 10) == 4e12 / 8
+    # a device kind with no peak on record is an error, not a default
+    with pytest.raises(ValueError, match="no peak memory bandwidth"):
+        bench.plausibility_cap_samples_per_s("TPU v6 lite", 1 << 10)
 
 
 def test_graft_entry_compiles_and_runs():

@@ -47,9 +47,8 @@ def baked_thresholds():
 
 
 def test_choose_ingest_path_table(baked_thresholds):
-    # thresholds refreshed from the r2 hardware table
-    # (TPU_CAPTURE_r2/device_paths.json): scatter dominates the low/mid
-    # range, sort-dedup wins back high metric cardinality on TPU
+    # the baked thresholds: scatter through the low/mid range,
+    # sort-dedup at high metric cardinality on TPU
     assert choose_ingest_path(1, 8193, "tpu") == "pallas"
     assert choose_ingest_path(128, 8193, "tpu") == "scatter"
     # r13: the fused sample->scatter kernel is the high-cardinality pick

@@ -434,7 +434,8 @@ def test_hll_merges_over_mesh_with_pmax():
     from jax.sharding import PartitionSpec as P
 
     from loghisto_tpu.models import hll
-    from loghisto_tpu.parallel.mesh import STREAM_AXIS, make_mesh, shard_map
+    from jax import shard_map
+    from loghisto_tpu.parallel.mesh import STREAM_AXIS, make_mesh
 
     mesh = make_mesh(stream=8, metric=1)
     rng = np.random.default_rng(6)

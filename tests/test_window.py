@@ -264,7 +264,9 @@ def test_pallas_merge_matches_jnp():
 
 def test_resolve_merge_path_policy():
     assert resolve_merge_path("auto", "cpu", mesh=False) == "jnp"
-    assert resolve_merge_path("auto", "tpu", mesh=False) == "pallas"
+    # the Pallas tier's ring layout conversion does not fit a v5e at 10k
+    assert resolve_merge_path("auto", "tpu", mesh=False) == "jnp"
+    assert resolve_merge_path("pallas", "tpu", mesh=False) == "pallas"
     assert resolve_merge_path("auto", "tpu", mesh=True) == "jnp"
     assert resolve_merge_path("jnp", "tpu", mesh=False) == "jnp"
     with pytest.raises(ValueError):
