@@ -20,8 +20,9 @@ Three acts:
                  report clears.  The whole run is then exported as a
                  Chrome/Perfetto ``trace_events`` JSON (one track per
                  thread, interval seqs as flow ids): load it at
-                 https://ui.perfetto.dev, and set LOGHISTO_TRACE_DIR to
-                 capture correlating jax.profiler device traces.
+                 https://ui.perfetto.dev.  Its clock is perf_counter_ns;
+                 ``ms.obs.clock_mark()`` at both ends of a jax.profiler
+                 capture puts these spans on the device trace's clock.
 
 Runs anywhere (CPU backend)."""
 
@@ -130,6 +131,5 @@ n = dump_perfetto(ms.obs, path)
 print(f"\nperfetto: {n} events -> {path}")
 print("open at https://ui.perfetto.dev; interval seqs are flow ids, "
       "one track per pipeline thread")
-if os.environ.get("LOGHISTO_TRACE_DIR"):
-    print(f"jax.profiler captures correlate under "
-          f"{os.environ['LOGHISTO_TRACE_DIR']}")
+print("to line it up with a jax.profiler capture, call ms.obs.clock_mark() "
+      "right after start_trace and right before stop_trace")

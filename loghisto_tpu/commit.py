@@ -400,12 +400,15 @@ class IntervalCommitter:
         # rebuild, so stop attempting until the open window passes and a
         # half-open trial succeeds (resilience/recovery.py)
         pinned = self.breaker is not None and self.breaker.is_open()
+        rec = self.obs_recorder
+        t_wait = time.perf_counter_ns()
         with agg._dev_lock:
             if (
                 pinned
                 or agg._interval_ingested + total >= agg.spill_threshold
                 or int(w64.max()) >= 1 << 30
             ):
+                rec.record("commit.lock_wait", t_wait, time.perf_counter_ns())
                 # int32-overflow envelope exceeded: the aggregator side
                 # takes its exact host-spill machinery; the tiers take
                 # the fan-out scatter below (their own int32 clip
@@ -420,6 +423,9 @@ class IntervalCommitter:
                 fused = False
             else:
                 with wheel._lock:
+                    rec.record(
+                        "commit.lock_wait", t_wait, time.perf_counter_ns()
+                    )
                     dispatches = self._fused_dispatch_locked(
                         cells, raw, dur
                     )

@@ -1133,7 +1133,12 @@ class MetricSystem:
                     break  # workers are wedged; they are daemons anyway
 
     def _tick(self, process_queue: "queue.Queue") -> None:
+        t0 = time.perf_counter_ns()
         raw = self.collect_raw_metrics()
+        # recorded after the fact: the interval's seq is minted inside
+        self.obs_recorder.record(
+            "reaper.collect", t0, time.perf_counter_ns(), raw.seq
+        )
         self._update_subscribers()
 
         with self.obs_recorder.span("obs.broadcast", raw.seq):

@@ -22,8 +22,10 @@ interval pipeline PRs 1-8 built:
     machine-readable ``HealthReport`` exported as ``health.*`` gauges
     and a ``/healthz`` JSON payload;
   * ``perfetto`` — dumps the span ring as Chrome ``trace_events`` JSON
-    that opens in Perfetto and correlates with ``LOGHISTO_TRACE_DIR``
-    jax.profiler captures (interval seq as flow ids).
+    that opens in Perfetto (interval seq as flow ids).  The ring's
+    clock is ``perf_counter_ns``; ``SpanRecorder.clock_mark()`` at both
+    ends of a ``jax.profiler`` capture gives the offset that puts its
+    spans on the device trace's clock.
 
 Wired via ``TPUMetricSystem(observability=ObsConfig(...))``.
 """

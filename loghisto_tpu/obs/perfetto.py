@@ -15,10 +15,13 @@ https://ui.perfetto.dev opens directly:
     draws arrows through every stage that interval touched, across
     threads.
 
-The µs timestamps share the clock used by ``utils/trace.py``'s
-jax.profiler regions, so a ``LOGHISTO_TRACE_DIR`` capture of the same
-run lines up with this dump: the ``commit.e2e`` span here brackets the
-``fused_commit`` TraceAnnotation there.
+The µs timestamps are ``perf_counter_ns``, not a ``jax.profiler``
+trace's session-relative clock.  To line a profiler capture up with
+this dump, call ``SpanRecorder.clock_mark()`` at the start and the end
+of the capture: each mark is a span here and a ``TraceAnnotation`` of
+the same name there, and the difference of their start times is the
+offset between the two clocks (``benchmark/spanclock.py`` computes it
+and maps the spans onto the trace).
 
 Fleet extension: spans carrying a cross-process flow id
 (``Span.flow``, minted by ``wire.fed_flow_id``) additionally emit

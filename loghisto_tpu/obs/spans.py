@@ -183,6 +183,24 @@ class SpanRecorder:
             return _NULL_HANDLE
         return _SpanHandle(self, stage, seq, flow)
 
+    def clock_mark(self, name: str = "obs.clock") -> None:
+        """Record an empty span ``name`` inside a
+        ``jax.profiler.TraceAnnotation`` of the same name.  The ring's
+        spans are on ``perf_counter_ns``, a profiler trace on its own
+        session-relative clock; the offset between the two is constant,
+        so one mark at each end of a traced window puts every span of
+        the window on the device trace's clock (the trace's annotation
+        event and this span bracket the same instant)."""
+        if not self.enabled:
+            return
+        # imported here, not at module top: federation emitters load
+        # this module without jax
+        import jax.profiler
+
+        with jax.profiler.TraceAnnotation(name):
+            with self.span(name):
+                pass
+
     # -- readers (best-effort, rendezvous-free) ------------------------- #
 
     @property
@@ -247,6 +265,9 @@ class _NullRecorder:
     def span(self, stage: str, seq: Optional[int] = None,
              flow: Optional[int] = None):
         return _NULL_HANDLE
+
+    def clock_mark(self, name: str = "obs.clock") -> None:
+        pass
 
     def spans(self) -> Tuple[Span, ...]:
         return ()

@@ -146,7 +146,7 @@ def make_fused_commit_fn(
     donate = tuple(range(2 + int(track_activity) + int(track_baseline)))
 
     @functools.partial(jax.jit, donate_argnums=donate)
-    def commit(*args):
+    def fused_commit(*args):
         it = iter(args)
         acc = next(it)
         rings = next(it)
@@ -175,7 +175,7 @@ def make_fused_commit_fn(
             out.append(ihist.at[ids, idx].add(weights, mode="drop"))
         return tuple(out)
 
-    return commit
+    return fused_commit
 
 
 @functools.lru_cache(maxsize=None)
@@ -234,7 +234,7 @@ def make_fused_commit_snapshot_fn(
     donate = tuple(range(2 + int(track_activity) + 2 * int(track_baseline)))
 
     @functools.partial(jax.jit, donate_argnums=donate)
-    def commit(*args):
+    def fused_commit_snapshot(*args):
         it = iter(args)
         acc = next(it)
         rings = next(it)
@@ -279,7 +279,7 @@ def make_fused_commit_snapshot_fn(
         out.extend((tuple(payloads), acc_payload))
         return tuple(out)
 
-    return commit
+    return fused_commit_snapshot
 
 
 def _shard_local_deltas(acc, rings, ids, idx, weights, track_activity):
@@ -371,7 +371,7 @@ def make_sharded_fused_commit_fn(
     shapes), so registry growth never needs a new cache entry."""
     donate = tuple(range(2 + int(track_activity) + int(track_baseline)))
 
-    def commit(*args):
+    def sharded_fused_commit(*args):
         it = iter(args)
         acc = next(it)
         rings = next(it)
@@ -414,7 +414,7 @@ def make_sharded_fused_commit_fn(
         in_specs += (P(),)      # ifirst
     return jax.jit(
         shard_map(
-            commit, mesh=mesh,
+            sharded_fused_commit, mesh=mesh,
             in_specs=in_specs, out_specs=tuple(carry_specs),
         ),
         donate_argnums=donate,
@@ -445,7 +445,7 @@ def make_sharded_fused_commit_snapshot_fn(
         from loghisto_tpu.ops.anomaly import ewma_bank_update
     donate = tuple(range(2 + int(track_activity) + 2 * int(track_baseline)))
 
-    def commit(*args):
+    def sharded_fused_commit_snapshot(*args):
         it = iter(args)
         acc = next(it)
         rings = next(it)
@@ -524,7 +524,8 @@ def make_sharded_fused_commit_snapshot_fn(
     )
     return jax.jit(
         shard_map(
-            commit, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+            sharded_fused_commit_snapshot, mesh=mesh,
+            in_specs=in_specs, out_specs=out_specs,
         ),
         donate_argnums=donate,
     )
@@ -548,7 +549,7 @@ def make_paged_fused_commit_fn(num_tiers: int, track_activity: bool = False):
     donate = tuple(range(2 + int(track_activity)))
 
     @functools.partial(jax.jit, donate_argnums=donate)
-    def commit(*args):
+    def paged_fused_commit(*args):
         it = iter(args)
         pool = next(it)
         rings = next(it)
@@ -573,7 +574,7 @@ def make_paged_fused_commit_fn(num_tiers: int, track_activity: bool = False):
             out.append(last_active.at[ids].max(epoch, mode="drop"))
         return tuple(out)
 
-    return commit
+    return paged_fused_commit
 
 
 @functools.lru_cache(maxsize=None)
@@ -595,7 +596,7 @@ def make_paged_fused_commit_snapshot_fn(
     donate = tuple(range(2 + int(track_activity)))
 
     @functools.partial(jax.jit, donate_argnums=donate)
-    def commit(*args):
+    def paged_fused_commit_snapshot(*args):
         it = iter(args)
         pool = next(it)
         rings = next(it)
@@ -627,7 +628,7 @@ def make_paged_fused_commit_snapshot_fn(
         out.append(tuple(payloads))
         return tuple(out)
 
-    return commit
+    return paged_fused_commit_snapshot
 
 
 def _paged_shard_local_deltas(
@@ -686,7 +687,7 @@ def make_sharded_paged_fused_commit_fn(
     shard-local — one collective, one dispatch per chunk."""
     donate = tuple(range(2 + int(track_activity)))
 
-    def commit(*args):
+    def sharded_paged_fused_commit(*args):
         it = iter(args)
         pool = next(it)
         rings = next(it)
@@ -726,7 +727,7 @@ def make_sharded_paged_fused_commit_fn(
         in_specs += (P(),)      # epoch
     return jax.jit(
         shard_map(
-            commit, mesh=mesh,
+            sharded_paged_fused_commit, mesh=mesh,
             in_specs=in_specs, out_specs=tuple(carry_specs),
         ),
         donate_argnums=donate,
@@ -748,7 +749,7 @@ def make_sharded_paged_fused_commit_snapshot_fn(
     outputs metric-row-sharded like the dense sharded variant."""
     donate = tuple(range(2 + int(track_activity)))
 
-    def commit(*args):
+    def sharded_paged_fused_commit_snapshot(*args):
         it = iter(args)
         pool = next(it)
         rings = next(it)
@@ -804,7 +805,8 @@ def make_sharded_paged_fused_commit_snapshot_fn(
     )
     return jax.jit(
         shard_map(
-            commit, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+            sharded_paged_fused_commit_snapshot, mesh=mesh,
+            in_specs=in_specs, out_specs=out_specs,
         ),
         donate_argnums=donate,
     )

@@ -73,10 +73,10 @@ def make_ingest_fn(bucket_limit: int, precision: int = PRECISION):
     """
 
     @functools.partial(jax.jit, donate_argnums=0)
-    def ingest(acc, ids, values):
+    def scatter_ingest(acc, ids, values):
         return ingest_batch(acc, ids, values, bucket_limit, precision)
 
-    return ingest
+    return scatter_ingest
 
 
 def make_weighted_ingest_fn(bucket_limit: int):
@@ -86,11 +86,11 @@ def make_weighted_ingest_fn(bucket_limit: int):
     Bucket indices are clipped to the dense range inside the kernel."""
 
     @functools.partial(jax.jit, donate_argnums=0)
-    def ingest(acc, ids, buckets, weights):
+    def weighted_ingest(acc, ids, buckets, weights):
         idx = jnp.clip(buckets, -bucket_limit, bucket_limit) + bucket_limit
         return acc.at[sanitize_ids(ids), idx].add(weights, mode="drop")
 
-    return ingest
+    return weighted_ingest
 
 
 def make_packed_ingest_fn(bucket_limit: int):
@@ -108,7 +108,7 @@ def make_packed_ingest_fn(bucket_limit: int):
     so the int32 count column cannot overflow."""
 
     @functools.partial(jax.jit, donate_argnums=0)
-    def ingest(acc, packed):
+    def packed_ingest(acc, packed):
         # Trace-time contract check: shapes are static under jit, and a
         # 2-column array would NOT fail the [:, 2] read below (static
         # OOB gathers clamp) — it would silently misread columns.
@@ -121,7 +121,7 @@ def make_packed_ingest_fn(bucket_limit: int):
         idx = jnp.clip(packed[:, 1], -bucket_limit, bucket_limit) + bucket_limit
         return acc.at[sanitize_ids(ids), idx].add(packed[:, 2], mode="drop")
 
-    return ingest
+    return packed_ingest
 
 
 @functools.partial(jax.jit, donate_argnums=0)

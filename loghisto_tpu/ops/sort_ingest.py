@@ -168,13 +168,13 @@ def make_sortscan_ingest_fn(bucket_limit: int, precision: int = PRECISION):
     f(acc, ids, values) -> new_acc contract."""
 
     @functools.partial(jax.jit, donate_argnums=0)
-    def ingest(acc, ids, values):
+    def sortscan_ingest(acc, ids, values):
         validate_flat_cell_shape(acc.shape[0], acc.shape[1], "sortscan")
         return sortscan_ingest_batch(
             acc, ids, values, bucket_limit, precision
         )
 
-    return ingest
+    return sortscan_ingest
 
 
 def make_sort_ingest_fn(bucket_limit: int, precision: int = PRECISION):
@@ -182,8 +182,8 @@ def make_sort_ingest_fn(bucket_limit: int, precision: int = PRECISION):
     f(acc, ids, values) -> new_acc contract as make_ingest_fn."""
 
     @functools.partial(jax.jit, donate_argnums=0)
-    def ingest(acc, ids, values):
+    def sort_ingest(acc, ids, values):
         validate_flat_cell_shape(acc.shape[0], acc.shape[1], "sort")
         return sort_ingest_batch(acc, ids, values, bucket_limit, precision)
 
-    return ingest
+    return sort_ingest

@@ -218,7 +218,7 @@ def make_sparse_ingest_fn(bucket_limit: int, kernel: str = "auto"):
     )
 
     @functools.partial(jax.jit, donate_argnums=0)
-    def ingest(acc, packed):
+    def sparse_ingest(acc, packed):
         return step(acc, packed, bucket_limit)
 
-    return ingest
+    return sparse_ingest

@@ -33,8 +33,6 @@ payloads with zero collectives beyond the one cell-delta psum.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -153,14 +151,13 @@ def make_window_stats_fn(
 ):
     """Jitted f(ring, mask, ps) -> stats, one executable per ring shape
     (one tier = one shape, so a wheel compiles one program per tier)."""
-    return jax.jit(
-        functools.partial(
-            window_stats,
-            bucket_limit=bucket_limit,
-            precision=precision,
-            merge_path=merge_path,
-        )
-    )
+    # a named function, not a functools.partial: a partial's program is
+    # called "_unknown" in a profiler trace
+    def masked_window_stats(ring, mask, ps):
+        return window_stats(ring, mask, ps, bucket_limit, precision,
+                            merge_path)
+
+    return jax.jit(masked_window_stats)
 
 
 def window_snapshot(
@@ -203,11 +200,8 @@ def make_window_snapshot_fn(
     """Jitted f(ring, masks) -> snapshot payload dict.  One executable
     per (ring shape, view count); view counts only change when a new
     window is pinned, so steady state never retraces."""
-    return jax.jit(
-        functools.partial(
-            window_snapshot,
-            bucket_limit=bucket_limit,
-            precision=precision,
-            merge_path=merge_path,
-        )
-    )
+    def masked_window_snapshot(ring, masks):
+        return window_snapshot(ring, masks, bucket_limit, precision,
+                               merge_path)
+
+    return jax.jit(masked_window_snapshot)

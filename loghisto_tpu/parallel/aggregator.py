@@ -622,6 +622,9 @@ class TPUAggregator:
         self._xfer_uploads = 0
         self._xfer_bytes = 0
         self._xfer_samples_shipped = 0
+        # flushes that left samples buffered on host because the
+        # transfer queue was full (guarded by _lock)
+        self._backpressure_flushes = 0
         # host-side retry buffer bound when the device is unreachable
         self.max_pending_samples = 32 * batch_size
         self.retry_cooldown = 1.0  # seconds between device retry attempts
@@ -931,13 +934,14 @@ class TPUAggregator:
         from loghisto_tpu.ops.sparse_ingest import make_sparse_ingest_fn
 
         self._packed_ingest = make_sparse_ingest_fn(config.bucket_limit)
-        self._stats_fn = jax.jit(
-            functools.partial(
-                dense_stats,
-                bucket_limit=config.bucket_limit,
-                precision=config.precision,
-            )
-        )
+        bl, prec = config.bucket_limit, config.precision
+
+        # a named function, not a functools.partial: a partial's program
+        # is called "_unknown" in a profiler trace
+        def collect_stats(acc, ps):
+            return dense_stats(acc, ps, bucket_limit=bl, precision=prec)
+
+        self._stats_fn = jax.jit(collect_stats)
         # lifetime aggregates on host: name id -> [sum, count]
         self._agg_lock = threading.Lock()
         self._agg: Dict[int, list] = {}
@@ -1000,10 +1004,10 @@ class TPUAggregator:
         bl, prec = self.config.bucket_limit, self.config.precision
 
         @functools.partial(jax.jit, donate_argnums=0)
-        def ingest(acc, ids, values):
+        def dense_ingest(acc, ids, values):
             return step(acc, ids, values, bl, prec)
 
-        return ingest
+        return dense_ingest
 
     def _grow_row_unit(self) -> int:
         """Row-count granularity growth must preserve: the mesh metric
@@ -1285,6 +1289,7 @@ class TPUAggregator:
                 # leave samples in the bounded host buffer, where the
                 # oldest-first shed policy applies, instead of growing
                 # the queue without bound
+                self._backpressure_flushes += 1
                 return
             else:
                 # requeue first: strictly older than anything in _pending
@@ -1550,11 +1555,12 @@ class TPUAggregator:
         from loghisto_tpu import _native
 
         try:
-            packed = _native.fold_packed(
-                ids, values,
-                bucket_limit=self.config.bucket_limit,
-                precision=self.config.precision,
-            )
+            with self.obs_recorder.span("ingest.fold"):
+                packed = _native.fold_packed(
+                    ids, values,
+                    bucket_limit=self.config.bucket_limit,
+                    precision=self.config.precision,
+                )
         except MemoryError:
             # can't build the fold table: ship the batch raw instead of
             # losing it (same wire contract, just more bytes)
@@ -1677,7 +1683,11 @@ class TPUAggregator:
             )
         super_bs = ring.slot_samples
         retry_off = None
+        t_wait = time.perf_counter_ns()
         with self._dev_lock:
+            self.obs_recorder.record(
+                "ingest.lock_wait", t_wait, time.perf_counter_ns()
+            )
             pending: Optional[tuple] = None  # staged, not yet dispatched
             for soff in range(0, n, super_bs):
                 send = min(soff + super_bs, n)
@@ -1730,6 +1740,7 @@ class TPUAggregator:
             "bytes_uploaded": self._xfer_bytes
             + (ring.bytes_uploaded if ring else 0),
             "samples_shipped": self._xfer_samples_shipped,
+            "backpressure_flushes": self._backpressure_flushes,
         }
 
     def _preagg_record(self, ids: np.ndarray, values: np.ndarray) -> None:
@@ -1783,9 +1794,13 @@ class TPUAggregator:
                 f"packed cell array must be int32 (no-x64 JAX would "
                 f"silently truncate int64); got {packed.dtype}"
             )
+        rec = self.obs_recorder
+        t_wait = time.perf_counter_ns()
         with self._dev_lock:
+            rec.record("ingest.lock_wait", t_wait, time.perf_counter_ns())
             try:
-                self._merge_packed_locked(packed)
+                with rec.span("ingest.merge"):
+                    self._merge_packed_locked(packed)
             except Exception:
                 # chunk-dispatch failures are handled (and partially
                 # spilled) inside _merge_packed_locked; reaching here
@@ -2183,8 +2198,6 @@ class TPUAggregator:
                 else:
                     acc = acc + 0  # defensive copy; donation-safe snapshot
                     spill = None if spill is None else spill.copy()
-            from loghisto_tpu.utils.trace import maybe_capture
-
             if spill is not None:
                 # overflow-spill interval: counts exceed int32 on device,
                 # so the whole extraction runs in exact int64 on host
@@ -2198,11 +2211,10 @@ class TPUAggregator:
                     self.config.precision,
                 )
             else:
-                with maybe_capture("loghisto_collect"):
-                    stats = self._stats_fn(
-                        self._finalize_acc(acc),
-                        np.asarray(ps, dtype=np.float32),
-                    )
+                stats = self._stats_fn(
+                    self._finalize_acc(acc),
+                    np.asarray(ps, dtype=np.float32),
+                )
         counts = np.asarray(stats["counts"])
         sums = np.asarray(stats["sums"])
         pcts = np.asarray(stats["percentiles"])

@@ -1,1 +1,2 @@
-"""Auxiliary subsystems: process/TPU gauges, checkpointing, tracing."""
+"""Auxiliary subsystems: process/TPU gauges, checkpointing, the journal
+and the compilation cache."""

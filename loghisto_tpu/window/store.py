@@ -748,14 +748,15 @@ class TimeWheel:
             )
             padded, nb = QueryPlanCache.pad_ids(ids_np)
             self.plan_cache.note(ti, nb, len(ps))
-            out = self._query_fn(
-                view.cdf, view.counts, view.sums, padded,
-                np.asarray(ps, dtype=np.float32),
-            )
+            with self.obs_recorder.span("query.gather"):
+                out = self._query_fn(
+                    view.cdf, view.counts, view.sums, padded,
+                    np.asarray(ps, dtype=np.float32),
+                )
+                counts = np.asarray(out["counts"])
+                sums = np.asarray(out["sums"])
+                pcts = np.asarray(out["percentiles"])
             self.query_rows_fetched += nb
-            counts = np.asarray(out["counts"])
-            sums = np.asarray(out["sums"])
-            pcts = np.asarray(out["percentiles"])
             for i, (mid, name) in enumerate(matches):
                 count = int(counts[i])
                 if count == 0:
@@ -950,14 +951,15 @@ class TimeWheel:
             gids_pad[: len(gids)] = gids
             all_ps = np.asarray(ps + eps, dtype=np.float32)
             self.plan_cache.note((ti, "group", ng), nb, len(all_ps))
-            out = self._group_fn(
-                cdf, counts, sums, padded, gids_pad, all_ps,
-                num_groups=ng,
-            )
+            with self.obs_recorder.span("query.gather"):
+                out = self._group_fn(
+                    cdf, counts, sums, padded, gids_pad, all_ps,
+                    num_groups=ng,
+                )
+                gcounts = np.asarray(out["counts"])
+                gsums = np.asarray(out["sums"])
+                gpcts = np.asarray(out["percentiles"])
             self.query_rows_fetched += nb
-            gcounts = np.asarray(out["counts"])
-            gsums = np.asarray(out["sums"])
-            gpcts = np.asarray(out["percentiles"])
             gsizes = np.bincount(
                 np.asarray(gids, dtype=np.int64), minlength=ng_real
             )

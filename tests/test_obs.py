@@ -476,3 +476,261 @@ def test_debug_dump_without_observability():
         assert dump["health"] is None
     finally:
         ms.stop()
+
+
+# -- spans on the device trace's clock, and the spans inside the stages --- #
+
+
+def _nests(child, parent):
+    return (child.thread == parent.thread
+            and parent.start_ns <= child.start_ns
+            and child.end_ns <= parent.end_ns)
+
+
+def test_clock_mark_maps_spans_onto_the_profiler_clock(tmp_path):
+    """Two clock marks around a CPU profiler session put a span recorded
+    inside a known TraceAnnotation within 100 us of that annotation on
+    the trace's clock, and the two marks' readings agree as closely."""
+    import sys
+
+    import jax
+
+    from benchmark import spanclock, xplane
+
+    rec = SpanRecorder(capacity=64)
+    switch = sys.getswitchinterval()
+    # no thread switch between an annotation's start and its span's
+    sys.setswitchinterval(1.0)
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            rec.clock_mark()
+            with jax.profiler.TraceAnnotation("known"):
+                with rec.span("known"):
+                    time.sleep(0.02)
+            rec.clock_mark()
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        sys.setswitchinterval(switch)
+    events = xplane.load_events(xplane.find_trace(str(tmp_path)))
+    offset, readings = spanclock.span_offset_ns(events, rec.spans())
+    assert len(readings) == 2
+    assert abs(readings[1] - readings[0]) < 100_000
+    (known,) = [e for e in events if e.name == "known"]
+    (span,) = spanclock.on_trace_clock(
+        [s for s in rec.spans() if s.stage == "known"], offset)
+    assert abs(span.start_ns - known.start_ns) < 100_000
+    assert abs(span.end_ns - known.end_ns) < 100_000
+
+
+@pytest.mark.parametrize("recorder", ["disabled", "null"])
+def test_disabled_recorder_marks_nothing(monkeypatch, recorder):
+    import jax
+
+    started = []
+
+    class Annotation:
+        def __init__(self, name):
+            started.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            pass
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    rec = NULL_RECORDER
+    if recorder == "disabled":
+        rec = SpanRecorder(capacity=8)
+        rec.enabled = False
+    rec.clock_mark()
+    assert started == [] and rec.spans() == ()
+    on = SpanRecorder(capacity=8)
+    on.clock_mark()
+    assert started == ["obs.clock"]
+    assert [s.stage for s in on.spans()] == ["obs.clock"]
+
+
+def _transport_spans(transport):
+    import numpy as np
+
+    from loghisto_tpu.config import MetricConfig
+    from loghisto_tpu.parallel.aggregator import TPUAggregator
+
+    agg = TPUAggregator(num_metrics=16, config=MetricConfig(bucket_limit=256),
+                        transport=transport, batch_size=1024)
+    agg.obs_recorder = SpanRecorder(capacity=256)
+    rng = np.random.default_rng(3)
+    for _ in range(3):
+        agg.record_batch(rng.integers(0, 16, 5000).astype(np.int32),
+                         rng.lognormal(4, 1, 5000).astype(np.float32))
+        agg.flush(force=True)
+    agg.close()
+    return agg.obs_recorder.spans()
+
+
+def test_sparse_drain_decomposes_into_fold_lock_wait_and_merge():
+    spans = _transport_spans("sparse")
+    drains = [s for s in spans if s.stage == "ingest.drain"]
+    assert len(drains) == 3
+    for d in drains:
+        inner = [s for s in spans if s.stage in (
+            "ingest.fold", "ingest.lock_wait", "ingest.merge")
+            and _nests(s, d)]
+        # one of each per item, one after the other
+        ordered = sorted(inner, key=lambda s: s.start_ns)
+        assert [s.stage for s in ordered] == [
+            "ingest.fold", "ingest.lock_wait", "ingest.merge"]
+        assert ordered[0].end_ns <= ordered[1].start_ns
+        assert ordered[1].end_ns <= ordered[2].start_ns
+
+
+def test_raw_drain_waits_for_the_lock_before_its_dispatches():
+    spans = _transport_spans("raw")
+    drains = [s for s in spans if s.stage == "ingest.drain"]
+    assert len(drains) == 3
+    for d in drains:
+        (wait,) = [s for s in spans
+                   if s.stage == "ingest.lock_wait" and _nests(s, d)]
+        dispatches = [s for s in spans
+                      if s.stage == "ingest.dispatch" and _nests(s, d)]
+        assert dispatches
+        assert all(wait.end_ns <= s.start_ns for s in dispatches)
+    assert not [s for s in spans if s.stage in ("ingest.fold",
+                                                "ingest.merge")]
+
+
+@pytest.fixture(scope="module")
+def served_spans():
+    """A traced system that committed intervals and then answered a
+    window query and a group-by, its spans taken after the stop."""
+    ms = _system()
+    try:
+        ms.start()
+        for _ in range(5):
+            ms.histogram("http.lat", 5.0, labels={"route": "/a"})
+            ms.histogram("http.lat", 9.0, labels={"route": "/b"})
+        _drain(ms, minimum=3)
+        ms.query_window("lat", window=None)
+        ms.query_group_by("http.lat{}", by=["route"], window=None)
+    finally:
+        ms.stop()
+    return ms.obs.spans()
+
+
+def test_commit_lock_wait_nests_in_commit_e2e(served_spans):
+    e2e = {s.seq: s for s in served_spans if s.stage == "commit.e2e"}
+    waits = [s for s in served_spans if s.stage == "commit.lock_wait"]
+    assert waits
+    for w in waits:
+        assert _nests(w, e2e[w.seq])
+        # the wait ends before the interval's first upload starts
+        ups = [s.start_ns for s in served_spans
+               if s.stage == "commit.upload" and s.seq == w.seq]
+        assert all(w.end_ns <= u for u in ups)
+
+
+def test_reaper_collect_carries_the_interval_seq(served_spans):
+    collected = {s.seq: s for s in served_spans
+                 if s.stage == "reaper.collect"}
+    e2e = [s for s in served_spans if s.stage == "commit.e2e"]
+    assert e2e
+    for s in e2e:
+        c = collected[s.seq]
+        assert c.thread != s.thread
+        assert c.start_ns <= c.end_ns <= s.start_ns
+
+
+def test_query_gather_nests_in_query_serve(served_spans):
+    serves = [s for s in served_spans if s.stage == "query.serve"]
+    gathers = [s for s in served_spans if s.stage == "query.gather"]
+    # the window query's and the group-by's
+    assert len(gathers) >= 2
+    for g in gathers:
+        assert any(_nests(g, s) for s in serves)
+
+
+def test_backpressure_flushes_count_flushes_a_full_queue_refused():
+    from loghisto_tpu.config import MetricConfig
+    from loghisto_tpu.parallel.aggregator import TPUAggregator
+
+    agg = TPUAggregator(num_metrics=16, config=MetricConfig(bucket_limit=64),
+                        transport="raw", batch_size=256)
+    release = threading.Event()
+    agg._process_xfer_item = lambda item: release.wait(10.0)
+    agg.max_pending_samples = 64
+    try:
+        for _ in range(100):
+            agg.record("m", 1.0)
+        agg.flush()  # enqueued: the worker takes it and wedges
+        assert agg.transport_stats()["backpressure_flushes"] == 0
+        for _ in range(10):
+            agg.record("m", 1.0)
+        # the queue still counts the wedged item's 100 samples, at least
+        # max_pending_samples: the flush leaves these 10 on the host
+        agg.flush()
+        assert agg.transport_stats()["backpressure_flushes"] == 1
+        assert agg._buffered_samples() == 10
+    finally:
+        release.set()
+        agg.wait_transfers(timeout=10.0)
+
+
+def test_trace_capture_module_is_gone_and_collect_answers(tmp_path):
+    """collect() runs no profiler session of its own: it answers inside
+    one that is already running."""
+    import importlib.util
+
+    import jax
+
+    from loghisto_tpu.config import MetricConfig
+    from loghisto_tpu.parallel.aggregator import TPUAggregator
+
+    assert importlib.util.find_spec("loghisto_tpu.utils.trace") is None
+    agg = TPUAggregator(num_metrics=8, config=MetricConfig(bucket_limit=64))
+    for v in (33.0, 59.0, 330000.0):
+        agg.record("histogram1", v)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        got = agg.collect().metrics
+    finally:
+        jax.profiler.stop_trace()
+    agg.close()
+    assert got["histogram1_count"] == 3.0
+
+
+def test_device_programs_have_stable_names_in_their_layers():
+    """Each jitted program on the main path has a name of its own, and
+    the benchmark's programs.json still puts it in the same layer."""
+    import os
+    import re
+
+    ms = _system()
+    try:
+        agg, wheel, com = ms.aggregator, ms.retention, ms.committer
+        names = {
+            agg._packed_ingest: "ingest",
+            agg._ingest: "ingest",
+            com._fused: "commit",
+            com._fused_snap: "commit",
+            wheel._query_fn: "query",
+            agg._stats_fn: "other",
+            wheel._stats_fn: "other",
+            wheel._snapshot_fn: "other",
+        }
+    finally:
+        ms.stop()
+    path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                        "benchmark", "metrics", "programs.json")
+    with open(path) as f:
+        layers = json.load(f)["layers"]
+    seen = set()
+    for fn, layer in names.items():
+        name = fn.__name__
+        got = next((k for k, pats in layers.items()
+                    if any(re.search(p, name) for p in pats)), "other")
+        assert got == layer, name
+        seen.add(name)
+    assert len(seen) == len(names)
