@@ -558,6 +558,19 @@ def _build_sparse_ingest(kernel):
     return fn, (_z((M, B)), _z((N, 3)))
 
 
+def _build_flat_sparse_ingest():
+    from loghisto_tpu.ops.sparse_ingest import make_flat_sparse_ingest_fn
+
+    fn = make_flat_sparse_ingest_fn(BL, B)
+    return fn, (_z((M * B,)), _z((N, 3)))
+
+
+def _build_settle_ingest_delta():
+    from loghisto_tpu.ops.sparse_ingest import settle_ingest_delta
+
+    return settle_ingest_delta, (_z((M, B)), _z((M * B,)))
+
+
 def _build_paged_commit(kernel):
     from loghisto_tpu.ops.paged_store import make_paged_commit_fn
 
@@ -730,6 +743,17 @@ PROGRAMS: tuple[ProgramSpec, ...] = (
           functools.partial(_build_sparse_ingest, "pallas"),
           donated=1, pallas_calls=1,
           description="packed [n,3] sparse merge, per-cell DMA kernel"),
+    _spec("sparse_ingest_flat",
+          "ops.sparse_ingest.make_flat_sparse_ingest_fn",
+          _build_flat_sparse_ingest,
+          donated=1, int32_scatter_shapes=((M * B,),),
+          forbidden_shapes=((M, B), (B, M)),
+          description="packed [n,3] merge into the flat delta; no [M, B] "
+                      "operand, so no relayout of it"),
+    _spec("settle_ingest_delta", "ops.sparse_ingest.settle_ingest_delta",
+          _build_settle_ingest_delta, donated=2,
+          description="flat delta added into the accumulator, returned "
+                      "zeroed"),
     # -- paged storage ------------------------------------------------ #
     _spec("paged_commit_jnp", "ops.paged_store.make_paged_commit_fn",
           functools.partial(_build_paged_commit, "jnp"),

@@ -547,7 +547,8 @@ class IntervalCommitter:
                 # scalars ([epoch], [masks], [ifirst, bank, decay,
                 # min_count])
                 args = [
-                    paged._pool if paged is not None else agg._acc,
+                    paged._pool if paged is not None
+                    else agg._dense_acc_locked(),
                     tuple(t.ring for t in tiers),
                 ]
                 if lc is not None:
@@ -608,7 +609,8 @@ class IntervalCommitter:
                 # next (a device failure here takes the normal recovery)
                 with rec.span("commit.device_sync"):
                     jax.block_until_ready(
-                        paged._pool if paged is not None else agg._acc
+                        paged._pool if paged is not None
+                        else agg._dense_acc_locked()
                     )
             if self.breaker is not None:
                 # closes a half-open breaker after a successful trial;
@@ -721,7 +723,8 @@ class IntervalCommitter:
                 empty, empty, empty
             )
             args = [
-                self.paged._pool if self.paged is not None else agg._acc,
+                self.paged._pool if self.paged is not None
+                else agg._dense_acc_locked(),
                 tuple(t.ring for t in tiers),
             ]
             if lc is not None:

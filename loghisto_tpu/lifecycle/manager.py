@@ -292,7 +292,7 @@ class LifecycleManager:
                     vcounts = np.zeros(len(vids), dtype=np.int64)
                 else:
                     acc, rings, la, vcounts = self._fold(
-                        agg._acc,
+                        agg._dense_acc_locked(),
                         tuple(t.ring for t in wheel._tiers),
                         la,
                         vpad,
@@ -411,7 +411,7 @@ class LifecycleManager:
                         jax.block_until_ready(la)
                     else:
                         acc, rings, la = self._compact(
-                            agg._acc,
+                            agg._dense_acc_locked(),
                             tuple(t.ring for t in wheel._tiers),
                             la,
                             perm,

@@ -24,6 +24,10 @@ pins the parity):
     SPARSE_KERNEL).  Off-TPU it runs in interpret mode so CI exercises
     the same code path.
 
+A third form, ``make_flat_sparse_ingest_fn``, runs the jnp tier's math
+into a flat per-interval delta that ``settle_ingest_delta`` later adds
+into the accumulator (the aggregator's single-device dense path).
+
 Padding rows use id -1, which ``sanitize_ids`` (jnp tier) or the
 explicit bounds guard (Pallas tier) drops; callers route counts >= 2^30
 to the exact host spill first, so the int32 count column cannot
@@ -202,6 +206,59 @@ def pallas_sparse_ingest(
     idx = jnp.clip(packed[:, 1], -bucket_limit, bucket_limit) + bucket_limit
     return pallas_cell_scatter(
         acc, packed[:, 0], idx, packed[:, 2], interpret=interpret
+    )
+
+
+def flat_sparse_ingest_batch(
+    delta: jnp.ndarray, packed: jnp.ndarray, bucket_limit: int,
+    num_cols: int,
+) -> jnp.ndarray:
+    """Flat tier: the same weighted scatter-add into a flat int32
+    ``[num_cols * M]`` delta of the ``[M, num_cols]`` accumulator, cell
+    (row, col) at ``col * M + row``.
+
+    A scatter into the tiled 2-D accumulator compiles on a TPU to a
+    relayout of the whole accumulator to a flat buffer and back around
+    the scatter; into a flat operand it is the scatter alone.  The
+    order is column-major because a v5e lays the accumulator out rows
+    minor (``{0,1}``), so ``settle_ingest_delta`` retiles the delta but
+    does not transpose it.  Pads (id -1) and rows outside [0, M) map to
+    index ``M * num_cols``, past the end, which ``mode="drop"`` sheds.
+    ``M * num_cols`` must be below 2^31 so every index fits int32."""
+    if packed.ndim != 2 or packed.shape[1] != 3:
+        raise ValueError(
+            f"packed must be [n, 3] (id, bucket, count); got {packed.shape}"
+        )
+    size = delta.shape[0]
+    num_rows = size // num_cols
+    rows = packed[:, 0]
+    idx = jnp.clip(packed[:, 1], -bucket_limit, bucket_limit) + bucket_limit
+    valid = (rows >= 0) & (rows < num_rows)
+    lin = jnp.where(valid, idx * num_rows + rows, size)
+    return delta.at[lin].add(packed[:, 2], mode="drop")
+
+
+def make_flat_sparse_ingest_fn(bucket_limit: int, num_cols: int):
+    """Jitted, donated-delta flat merge step: ``f(delta, packed) ->
+    delta`` with delta int32 ``[num_cols * M]`` and packed int32
+    ``[n, 3]``."""
+
+    @functools.partial(jax.jit, donate_argnums=0)
+    def sparse_ingest_flat(delta, packed):
+        return flat_sparse_ingest_batch(delta, packed, bucket_limit, num_cols)
+
+    return sparse_ingest_flat
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def settle_ingest_delta(acc: jnp.ndarray, delta: jnp.ndarray):
+    """Add a flat column-major delta (``flat_sparse_ingest_batch``) into
+    the accumulator it mirrors and return both, the delta zeroed.  The
+    one relayout of the delta an interval pays."""
+    num_rows, num_cols = acc.shape
+    return (
+        acc + delta.reshape(num_cols, num_rows).T,
+        jnp.zeros_like(delta),
     )
 
 

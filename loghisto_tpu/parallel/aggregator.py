@@ -585,12 +585,23 @@ class TPUAggregator:
         #   _lock     — host staging state (_pending_*, _native_staged);
         #               held only for list appends/drains, never across a
         #               device call.
-        #   _dev_lock — device state (_acc, _spill, _interval_ingested,
-        #               growth); held across device dispatches.
+        #   _dev_lock — device state (_acc, the flat delta, _spill,
+        #               _interval_ingested, growth); held across device
+        #               dispatches.  Re-entrant: reading `_acc` takes it
+        #               to settle the delta, and locked code reads `_acc`.
         # Never nested: every method releases one before taking the other,
         # so lock-ordering deadlocks are impossible by construction.
         self._lock = threading.Lock()
-        self._dev_lock = threading.Lock()
+        self._dev_lock = threading.RLock()
+        # flat int32 per-interval delta of the accumulator that packed
+        # merges scatter into on one device (_merge_packed_locked), and
+        # the samples it holds; _dense_acc_locked settles it.  Allocated
+        # at the first flat merge.
+        self._acc_array = None
+        self._delta = None
+        self._delta_samples = 0
+        self.flat_merges = 0  # packed-merge launches into the delta
+        self.delta_settles = 0
         self._pending_ids: list[np.ndarray] = []
         self._pending_values: list[np.ndarray] = []
         self._pending_count = 0
@@ -931,9 +942,7 @@ class TPUAggregator:
         # runtime after the density probe; the kernel tier follows the
         # capture-overridable SPARSE_KERNEL switch.  Compilation is lazy
         # (first packed merge), so raw-only aggregators never pay for it.
-        from loghisto_tpu.ops.sparse_ingest import make_sparse_ingest_fn
-
-        self._packed_ingest = make_sparse_ingest_fn(config.bucket_limit)
+        self._select_packed_merge()
         bl, prec = config.bucket_limit, config.precision
 
         # a named function, not a functools.partial: a partial's program
@@ -1009,6 +1018,63 @@ class TPUAggregator:
 
         return dense_ingest
 
+    @property
+    def _acc(self):
+        """The dense accumulator with every merged count in it: reading
+        it takes _dev_lock and settles the flat delta first."""
+        with self._dev_lock:
+            return self._dense_acc_locked()
+
+    @_acc.setter
+    def _acc(self, value) -> None:
+        self._acc_array = value
+
+    def _dense_acc_locked(self):
+        """The dense accumulator with every merged count in it (caller
+        holds _dev_lock): settles the pending flat delta first, adding
+        it in and zeroing it in one donated dispatch.  Every reader of
+        the accumulator comes through here."""
+        if self._delta_samples:
+            from loghisto_tpu.ops.sparse_ingest import settle_ingest_delta
+
+            try:
+                with self.obs_recorder.span("ingest.settle"):
+                    self._acc_array, self._delta = settle_ingest_delta(
+                        self._acc_array, self._delta
+                    )
+            except Exception:
+                self._on_device_failure_locked()
+            else:
+                self._delta_samples = 0
+                self.delta_settles += 1
+        return self._acc_array
+
+    def _select_packed_merge(self) -> None:
+        """Pick the packed merge step: into the flat per-interval delta
+        on one device with the dense store, while its int32 index fits
+        (M * B < 2^31) and the sparse kernel is the XLA scatter tier;
+        into the [M, B] accumulator otherwise (a mesh's sharded one, the
+        page pool's twin, the Pallas tier's HBM tiles)."""
+        from loghisto_tpu.ops.dispatch import resolve_sparse_kernel
+        from loghisto_tpu.ops.sparse_ingest import (
+            make_flat_sparse_ingest_fn,
+            make_sparse_ingest_fn,
+        )
+
+        bl = self.config.bucket_limit
+        self._flat = (
+            self.mesh is None
+            and self.paged is None
+            and self._acc_array.size < 1 << 31
+            and resolve_sparse_kernel("auto") == "jnp"
+        )
+        if self._flat:
+            self._packed_ingest = make_flat_sparse_ingest_fn(
+                bl, self._acc_array.shape[1]
+            )
+        else:
+            self._packed_ingest = make_sparse_ingest_fn(bl)
+
     def _grow_row_unit(self) -> int:
         """Row-count granularity growth must preserve: the mesh metric
         axis (shard divisibility) or the multirow kernel's row tile."""
@@ -1071,7 +1137,7 @@ class TPUAggregator:
                 mesh=self.mesh is not None,
             )
             ingest = self._make_dense_step_fn(new_path)
-        acc_np = np.asarray(self._acc)
+        acc_np = np.asarray(self._dense_acc_locked())
         grown = np.zeros((new_m, acc_np.shape[1]), dtype=acc_np.dtype)
         grown[:old_m] = acc_np
         if self.mesh is not None:
@@ -1086,6 +1152,9 @@ class TPUAggregator:
         )
         self.ingest_path = new_path
         self._acc = new_acc
+        self._delta = None  # settled above; the next merge sizes it anew
+        if self._flat and new_acc.size >= 1 << 31:
+            self._select_packed_merge()  # the flat index outgrew int32
         self.num_metrics = new_m
         self.stats_snapshot = None  # row space changed; handle is stale
         self.registry.grow(new_m)
@@ -1111,7 +1180,9 @@ class TPUAggregator:
             self._interval_ingested = 0
             self.stats_snapshot = None
             return
-        acc_np = np.asarray(self._finalize_acc(self._acc), dtype=np.int64)
+        acc_np = np.asarray(
+            self._finalize_acc(self._dense_acc_locked()), dtype=np.int64
+        )
         if self._spill is None:
             self._spill = acc_np
         else:
@@ -1610,7 +1681,7 @@ class TPUAggregator:
                         )
                     else:
                         self._acc = self._ingest(
-                            self._acc,
+                            self._dense_acc_locked(),
                             ids_dev[lo:lo + bs],
                             values_dev[lo:lo + bs],
                         )
@@ -1741,6 +1812,8 @@ class TPUAggregator:
             + (ring.bytes_uploaded if ring else 0),
             "samples_shipped": self._xfer_samples_shipped,
             "backpressure_flushes": self._backpressure_flushes,
+            "flat_merges": self.flat_merges,
+            "delta_settles": self.delta_settles,
         }
 
     def _preagg_record(self, ids: np.ndarray, values: np.ndarray) -> None:
@@ -1852,16 +1925,29 @@ class TPUAggregator:
             pad[:, 2] = 0
             pad[:take] = packed[off:off + take]
             try:
-                self._acc = self._packed_ingest(self._acc, pad)
+                if self._flat:
+                    # into the flat delta: no relayout of the [M, B]
+                    # accumulator per launch (ops/sparse_ingest.py)
+                    if self._delta is None:
+                        self._delta = jnp.zeros(
+                            self._acc_array.size, dtype=jnp.int32
+                        )
+                    self._delta = self._packed_ingest(self._delta, pad)
+                else:
+                    self._acc = self._packed_ingest(
+                        self._dense_acc_locked(), pad
+                    )
             except Exception:
                 self._on_device_failure_locked()
                 self._spill_add_packed_locked(packed[off:])
                 return
             # success-only reset, mirroring the raw flush loop
             self._device_down_until = 0.0
-            self._interval_ingested += int(
-                weights[off:off + take].sum(dtype=np.int64)
-            )
+            merged = int(weights[off:off + take].sum(dtype=np.int64))
+            self._interval_ingested += merged
+            if self._flat:
+                self._delta_samples += merged
+                self.flat_merges += 1
 
     def _on_device_failure_locked(self) -> None:
         """Device-failure bookkeeping (caller holds _dev_lock, and must
@@ -1876,16 +1962,26 @@ class TPUAggregator:
             "device ingest dispatch failed"
         )
         self._device_down_until = time.monotonic() + self.retry_cooldown
-        if getattr(self._acc, "is_deleted", lambda: False)():
+        acc_lost = getattr(self._acc_array, "is_deleted", lambda: False)()
+        delta_lost = self._delta is not None and self._delta.is_deleted()
+        if acc_lost or delta_lost:
+            # the accumulator holds the whole interval, the delta only
+            # what was merged since its last settle
+            lost = (
+                self._interval_ingested if acc_lost else self._delta_samples
+            )
             logging.getLogger("loghisto_tpu").error(
-                "device failure consumed the donated accumulator; %d "
+                "device failure consumed the donated %s; %d "
                 "already-ingested samples of this interval are lost",
-                self._interval_ingested,
+                "accumulator" if acc_lost else "ingest delta", lost,
             )
             with self._shed_lock:
-                self._shed_samples += self._interval_ingested
-            self._interval_ingested = 0
-            self._acc = self._fresh_acc()
+                self._shed_samples += lost
+            self._interval_ingested -= lost
+            if acc_lost:
+                self._acc = self._fresh_acc()
+            self._delta = None
+            self._delta_samples = 0
         if self.paged is not None and self.paged.pool_deleted():
             logging.getLogger("loghisto_tpu").error(
                 "device failure consumed the donated page pool; %d "
@@ -2034,7 +2130,7 @@ class TPUAggregator:
             weights_pad[:take] = weights_np[off:off + take]
             try:
                 self._acc = self._weighted_ingest(
-                    self._acc, ids_pad, bidx_pad, weights_pad
+                    self._dense_acc_locked(), ids_pad, bidx_pad, weights_pad
                 )
             except Exception:
                 self._on_device_failure_locked()
@@ -2068,7 +2164,9 @@ class TPUAggregator:
         ids = np.full(_MERGE_CHUNK, -1, dtype=np.int32)
         zeros = np.zeros(_MERGE_CHUNK, dtype=np.int32)
         with self._dev_lock:
-            self._acc = self._weighted_ingest(self._acc, ids, zeros, zeros)
+            self._acc = self._weighted_ingest(
+                self._dense_acc_locked(), ids, zeros, zeros
+            )
 
     def attach(self, ms: MetricSystem, channel_capacity: int = 8) -> None:
         """Subscribe to a MetricSystem's raw broadcast; every interval's
@@ -2186,7 +2284,7 @@ class TPUAggregator:
                     self.stats_snapshot = None
         else:
             with self._dev_lock:
-                acc = self._acc
+                acc = self._dense_acc_locked()
                 spill = self._spill
                 if reset:
                     # zeros_like preserves the NamedSharding in mesh mode

@@ -121,7 +121,7 @@ def save(
                 # ingest_path choices (multirow's lane padding is
                 # stripped)
                 acc = np.asarray(
-                    aggregator._finalize_acc(aggregator._acc)
+                    aggregator._finalize_acc(aggregator._dense_acc_locked())
                 )
                 # a spilled interval keeps part of its counts in the
                 # host int64 fold — snapshotting only the device tensor
@@ -416,9 +416,8 @@ def _restore_dense_delta(aggregator, remapped: np.ndarray) -> None:
     2^31 unseen by the spill trigger."""
     import jax.numpy as jnp
 
-    live_max = int(
-        jnp.max(aggregator._finalize_acc(aggregator._acc))
-    )
+    live = aggregator._dense_acc_locked()
+    live_max = int(jnp.max(aggregator._finalize_acc(live)))
     if (
         int(remapped.max(initial=0))
         + live_max
@@ -430,7 +429,7 @@ def _restore_dense_delta(aggregator, remapped: np.ndarray) -> None:
         else:
             aggregator._spill += remapped.astype(np.int64)
     else:
-        live_cols = aggregator._acc.shape[1]
+        live_cols = live.shape[1]
         dense = remapped.astype(np.int32)
         if live_cols != dense.shape[1]:
             # re-pad the canonical dense rows into the live
@@ -444,7 +443,7 @@ def _restore_dense_delta(aggregator, remapped: np.ndarray) -> None:
         # first: checkpoints save gathered host arrays, so a snapshot
         # taken on one mesh shape restores onto any other (or none)
         delta = jnp.asarray(dense)
-        live_sharding = getattr(aggregator._acc, "sharding", None)
+        live_sharding = getattr(live, "sharding", None)
         if (
             getattr(aggregator, "mesh", None) is not None
             and live_sharding is not None
@@ -452,7 +451,7 @@ def _restore_dense_delta(aggregator, remapped: np.ndarray) -> None:
             import jax
 
             delta = jax.device_put(delta, live_sharding)
-        aggregator._acc = aggregator._acc + delta
+        aggregator._acc = live + delta
 
 
 def _names_arr(names) -> np.ndarray:

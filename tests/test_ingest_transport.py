@@ -375,3 +375,264 @@ def test_sparse_transport_failure_spills_exactly(monkeypatch):
     out = agg.collect().metrics
     assert out["m_count"] == 1000
     agg.close()
+
+
+# ---------------------------------------------------------------------- #
+# flat per-interval delta: the packed merge's single-device dense path
+# ---------------------------------------------------------------------- #
+
+FLAT_M, FLAT_BL = 24, 64
+FLAT_B = 2 * FLAT_BL + 1
+
+
+def _flat_chunks(case, rng, n=512, chunks=3):
+    """int32 [n, 3] packed chunks exercising one edge of the merge."""
+    out = []
+    for _ in range(chunks):
+        rows = rng.integers(0, FLAT_M, n)
+        buckets = rng.integers(-FLAT_BL, FLAT_BL + 1, n)
+        counts = rng.integers(1, 1000, n)
+        if case == "pads":
+            rows[rng.random(n) < 0.5] = -1
+            counts[rows < 0] = 0
+        elif case == "duplicates":
+            pick = rng.integers(0, 5, n)
+            rows = np.array([3, 3, 7, 23, 0])[pick]
+            buckets = np.array([0, 0, -FLAT_BL, FLAT_BL, 5])[pick]
+        elif case == "clipped":
+            buckets = rng.choice(
+                [-FLAT_BL, FLAT_BL, -FLAT_BL - 1, FLAT_BL + 1,
+                 -(1 << 30), 1 << 30, -FLAT_BL - 4096], n)
+        elif case == "edges":
+            rows = rng.choice([0, FLAT_M - 1], n)
+            buckets = rng.choice([-FLAT_BL, FLAT_BL], n)
+        elif case == "out_of_range":
+            rows = rng.choice([-1, -7, FLAT_M, FLAT_M + 1, (1 << 31) - 1,
+                               FLAT_M - 1], n)
+        else:  # mixed
+            rows[::5] = -1
+            rows[1::7] = FLAT_M - 1
+            rows[2::11] = FLAT_M + 3
+            buckets[::3] = FLAT_BL + 9
+            buckets[1::13] = -FLAT_BL
+        out.append(np.stack([rows, buckets, counts], 1).astype(np.int32))
+    return out
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["pads", "duplicates", "clipped", "edges", "out_of_range", "mixed"],
+)
+def test_flat_delta_merge_and_settle_match_the_2d_scatter(case):
+    """Chunks merged into the flat delta and settled once land exactly
+    where the [M, B] scatter (sparse_ingest_batch) puts them: pads,
+    duplicate cells, clipped buckets, the last row and column, rows out
+    of range."""
+    import jax.numpy as jnp
+
+    from loghisto_tpu.ops.sparse_ingest import (
+        make_flat_sparse_ingest_fn,
+        settle_ingest_delta,
+        sparse_ingest_batch,
+    )
+
+    rng = np.random.default_rng(
+        ["pads", "duplicates", "clipped", "edges", "out_of_range",
+         "mixed"].index(case) + 41
+    )
+    start = rng.integers(0, 1 << 20, (FLAT_M, FLAT_B)).astype(np.int32)
+    ref = jnp.asarray(start)
+    merge = make_flat_sparse_ingest_fn(FLAT_BL, FLAT_B)
+    delta = jnp.zeros(FLAT_M * FLAT_B, dtype=jnp.int32)
+    for packed in _flat_chunks(case, rng):
+        ref = sparse_ingest_batch(ref, jnp.asarray(packed), FLAT_BL)
+        delta = merge(delta, jnp.asarray(packed))
+    got, zeroed = settle_ingest_delta(jnp.asarray(start), delta)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    assert not np.asarray(zeroed).any()
+    assert zeroed.shape == (FLAT_M * FLAT_B,)
+
+
+def test_flat_delta_readers_race_merges_without_loss():
+    """Readers of the accumulator (collect with reset, `_acc` read-backs)
+    race packed merges into the flat delta on the transfer worker, with
+    a short switch interval: every merged sample is collected exactly
+    once."""
+    import sys
+
+    agg = TPUAggregator(
+        num_metrics=8, config=CFG, transport="sparse", batch_size=1024,
+    )
+    agg.registry.id_for("m")
+    assert agg._flat
+    rounds, cells = 60, 300
+    collected = []
+    done = threading.Event()
+
+    def reader():
+        while not done.is_set():
+            collected.append(agg.collect(reset=True).metrics.get(
+                "m_count", 0.0))
+            np.asarray(agg._acc)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        t = threading.Thread(target=reader)
+        t.start()
+        rng = np.random.default_rng(3)
+        total = 0
+        for _ in range(rounds):
+            packed = np.stack([
+                np.zeros(cells), rng.integers(-300, 300, cells),
+                rng.integers(1, 9, cells),
+            ], 1).astype(np.int32)
+            total += int(packed[:, 2].sum())
+            agg.merge_packed(packed)
+        assert agg.wait_transfers(timeout=60)
+        done.set()
+        t.join(timeout=60)
+        assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+    final = agg.collect(reset=True).metrics.get("m_count", 0.0)
+    assert sum(collected) + final == total
+    assert agg.flat_merges == rounds
+    assert agg.delta_settles >= 1
+    agg.close()
+
+
+def test_flat_delta_conserves_through_every_reader(tmp_path):
+    """Packed merges land in the flat delta; every reader of the
+    accumulator settles it first and sees exact counts: read-back,
+    collect, the fused commit, the spill fold, checkpoint save and
+    restore, registry growth, lifecycle eviction and compaction."""
+    import datetime as dt
+
+    from loghisto_tpu.lifecycle import LifecycleConfig
+    from loghisto_tpu.metrics import RawMetricSet
+    from loghisto_tpu.system import TPUMetricSystem
+    from loghisto_tpu.utils import checkpoint
+
+    bl = 64
+    b = 2 * bl + 1
+    rng = np.random.default_rng(11)
+
+    def build():
+        return TPUMetricSystem(
+            interval=3600.0, sys_stats=False,
+            config=MetricConfig(bucket_limit=bl), num_metrics=8,
+            retention=((4, 1),), commit="fused", transport="sparse",
+            lifecycle=LifecycleConfig(check_every=10**6,
+                                      auto_compact_fragmentation=0.0),
+        )
+
+    def merge(ms, want, names):
+        agg = ms.aggregator
+        ids = np.array([agg._id_for(n) for n in names])
+        pick = rng.integers(0, len(names), 2000)
+        buckets = rng.integers(-bl - 8, bl + 9, 2000)
+        counts = rng.integers(1, 50, 2000)
+        packed = np.stack([ids[pick], buckets, counts], 1).astype(np.int32)
+        agg.merge_packed(packed, wait=True)
+        for k, name in enumerate(names):
+            row = want.setdefault(name, np.zeros(b, np.int64))
+            sel = pick == k
+            np.add.at(row, np.clip(buckets[sel], -bl, bl) + bl, counts[sel])
+        assert agg._delta_samples > 0  # pending in the delta
+
+    def exact(ms, want):
+        agg = ms.aggregator
+        with agg._dev_lock:
+            acc = np.asarray(agg._finalize_acc(agg._acc), dtype=np.int64)
+            assert agg._delta_samples == 0
+            if agg._spill is not None:
+                acc = acc + agg._spill
+        for name, row in want.items():
+            np.testing.assert_array_equal(
+                acc[agg.registry.lookup(name)], row, err_msg=name)
+        assert int(acc.sum()) == sum(int(r.sum()) for r in want.values())
+
+    def settles(ms, read):
+        before = ms.aggregator.delta_settles
+        out = read()
+        assert ms.aggregator.delta_settles > before
+        return out
+
+    ms = build()
+    agg, lc = ms.aggregator, ms.lifecycle
+    assert agg._flat
+    names = [f"api.m{i}" for i in range(6)]
+    want = {}
+    try:
+        merge(ms, want, names)
+        acc = settles(ms, lambda: np.asarray(agg._acc))  # read-back
+        assert int(acc.sum()) == sum(int(r.sum()) for r in want.values())
+        exact(ms, want)
+
+        merge(ms, want, names)
+        got = settles(ms, lambda: ms.device_metrics(reset=False).metrics)
+        for name in names:
+            assert got[f"{name}_count"] == want[name].sum()
+        exact(ms, want)
+
+        merge(ms, want, names)
+        raw = RawMetricSet(
+            time=dt.datetime(2026, 1, 1, tzinfo=dt.timezone.utc),
+            counters={}, rates={}, histograms={names[0]: {3: 7, -bl: 2}},
+            gauges={}, duration=1.0,
+        )
+        settles(ms, lambda: ms.committer.commit(raw))
+        assert ms.committer.fused_intervals == 1
+        want[names[0]][3 + bl] += 7
+        want[names[0]][0] += 2
+        exact(ms, want)
+
+        merge(ms, want, names)
+
+        def spill_fold():
+            with agg._dev_lock:
+                agg._spill_fold_locked()
+        settles(ms, spill_fold)
+        assert agg._spill is not None
+        exact(ms, want)
+
+        merge(ms, want, names)
+        path = str(tmp_path / "flat.npz")
+        settles(ms, lambda: checkpoint.save(path, aggregator=agg))
+        exact(ms, want)
+        ms2 = build()
+        try:
+            want2 = {}
+            merge(ms2, want2, names[:2])
+            settles(ms2, lambda: checkpoint.restore(path,
+                                                    aggregator=ms2.aggregator))
+            for name, row in want.items():
+                want2[name] = want2.get(name, 0) + row
+            exact(ms2, want2)
+        finally:
+            ms2.stop()
+
+        merge(ms, want, names)
+        grown = [f"api.g{i}" for i in range(4)]
+        settles(ms, lambda: [agg._id_for(n) for n in grown])
+        assert agg.num_metrics == 16
+        merge(ms, want, names + grown)
+        exact(ms, want)
+
+        merge(ms, want, names + grown)
+        victim = names[1]
+        settles(ms, lambda: lc.evict_ids([agg.registry.lookup(victim)]))
+        want.setdefault("_overflow.api", np.zeros(b, np.int64))
+        want["_overflow.api"] += want.pop(victim)
+        exact(ms, want)
+
+        live = [n for n in names + grown if n != victim]
+        merge(ms, want, live)
+        assert settles(ms, lc.compact)
+        exact(ms, want)
+        stats = agg.transport_stats()
+        assert stats["flat_merges"] >= 9
+        assert stats["delta_settles"] == agg.delta_settles >= 9
+    finally:
+        ms.stop()

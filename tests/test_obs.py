@@ -707,11 +707,14 @@ def test_device_programs_have_stable_names_in_their_layers():
     import os
     import re
 
+    from loghisto_tpu.ops.sparse_ingest import settle_ingest_delta
+
     ms = _system()
     try:
         agg, wheel, com = ms.aggregator, ms.retention, ms.committer
         names = {
             agg._packed_ingest: "ingest",
+            settle_ingest_delta: "ingest",
             agg._ingest: "ingest",
             com._fused: "commit",
             com._fused_snap: "commit",

@@ -195,3 +195,31 @@ def test_sharded_commit_merges_once_per_chunk_on_v5e_2x2(topo):
     hlo = fn.lower(*args).compile().as_text()
     all_reduces = re.findall(r"\sall-reduce(?:-start)?\(", hlo)
     assert len(all_reduces) == 1, all_reduces
+
+
+def test_flat_sparse_merge_and_settle_compile_for_v5e(one_chip):
+    """The packed merge into the flat per-interval delta at THE merge
+    width (65,536 triples): no reshape or copy of any operand as large
+    as the accumulator, and under 64 MB of temp (the scatter into the
+    [M, B] accumulator relayouts it through 327.9 MB).  The settle that
+    adds the delta in once an interval holds at most one relayout of
+    it (a row-major delta would transpose through three)."""
+    import math
+    import re
+
+    from loghisto_tpu.ops.sparse_ingest import (
+        make_flat_sparse_ingest_fn, settle_ingest_delta,
+    )
+
+    flat = _shape(one_chip, (M * B,))
+    merge = make_flat_sparse_ingest_fn(BUCKET_LIMIT, B).lower(
+        flat, _shape(one_chip, (1 << 16, 3))).compile()
+    moved = re.findall(r"= s32\[([\d,]*)\]\S* (?:reshape|copy)\(",
+                       merge.as_text())
+    dims = [math.prod(int(d) for d in m.split(",") if d) for m in moved]
+    assert all(n < M * B for n in dims), moved
+    assert merge.memory_analysis().temp_size_in_bytes < 64 << 20
+
+    settle = settle_ingest_delta.lower(
+        _shape(one_chip, (M, B)), flat).compile()
+    assert settle.memory_analysis().temp_size_in_bytes < 2 * M * B * 4
