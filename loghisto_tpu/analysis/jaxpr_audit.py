@@ -571,6 +571,25 @@ def _build_settle_ingest_delta():
     return settle_ingest_delta, (_z((M, B)), _z((M * B,)))
 
 
+def _build_flat_sparse_ingest_sharded():
+    from loghisto_tpu.ops.sparse_ingest import (
+        make_sharded_flat_sparse_ingest_fn,
+    )
+
+    fn = make_sharded_flat_sparse_ingest_fn(
+        _mesh(), BL, B, M // MESH_SHAPE[1]
+    )
+    return fn, (_z((MESH_SHAPE[0], M * B)), _z((N, 3)))
+
+
+def _build_settle_ingest_delta_sharded():
+    from loghisto_tpu.ops.sparse_ingest import make_sharded_settle_fn
+
+    return make_sharded_settle_fn(_mesh()), (
+        _z((M, B)), _z((MESH_SHAPE[0], M * B))
+    )
+
+
 def _build_paged_commit(kernel):
     from loghisto_tpu.ops.paged_store import make_paged_commit_fn
 
@@ -754,6 +773,21 @@ PROGRAMS: tuple[ProgramSpec, ...] = (
           _build_settle_ingest_delta, donated=2,
           description="flat delta added into the accumulator, returned "
                       "zeroed"),
+    _spec("sparse_ingest_flat_sharded",
+          "ops.sparse_ingest.make_sharded_flat_sparse_ingest_fn",
+          _build_flat_sparse_ingest_sharded,
+          donated=1, stream_psums=0,
+          int32_scatter_shapes=((M // MESH_SHAPE[1] * B,),),
+          forbidden_shapes=((M // MESH_SHAPE[1], B),
+                            (B, M // MESH_SHAPE[1])),
+          description="mesh flat merge: each device scatters its stream "
+                      "slice into its own partial; no collective"),
+    _spec("sharded_settle_ingest_delta",
+          "ops.sparse_ingest.make_sharded_settle_fn",
+          _build_settle_ingest_delta_sharded,
+          donated=2, stream_psums=1,
+          description="mesh partials merged by ONE stream psum, added "
+                      "into the accumulator shards, returned zeroed"),
     # -- paged storage ------------------------------------------------ #
     _spec("paged_commit_jnp", "ops.paged_store.make_paged_commit_fn",
           functools.partial(_build_paged_commit, "jnp"),

@@ -46,7 +46,7 @@ from loghisto_tpu.ops.ingest import sanitize_ids
 from loghisto_tpu.ops.paged_store import paged_scatter_batch
 from loghisto_tpu.ops.stats import dense_cdf
 from loghisto_tpu.ops.window import window_snapshot
-from loghisto_tpu.parallel.mesh import METRIC_AXIS, STREAM_AXIS
+from loghisto_tpu.parallel.mesh import METRIC_AXIS, STREAM_AXIS, stream_runs
 
 # Fixed commit launch width, matching the aggregator bridge's merge
 # chunk: one compiled executable serves every interval; a typical
@@ -812,18 +812,6 @@ def make_sharded_paged_fused_commit_snapshot_fn(
     )
 
 
-def _fresh(width: int, n: int, head: np.ndarray, pad, dtype=np.int32):
-    """A new host array of ``width`` rows: ``head`` then ``pad``.  Staged
-    uploads never reuse a host buffer: ``jax.device_put`` may alias one
-    (the CPU backend does, ``may_alias=False`` or not) or still be
-    reading it after it returns (an async H2D copy), so a buffer handed
-    to it is never written again."""
-    out = np.empty((width,) + head.shape[1:], dtype=dtype)
-    out[:n] = head
-    out[n:] = pad
-    return out
-
-
 class CellStagingRing:
     """H2D staging for interval cell arrays.
 
@@ -840,7 +828,9 @@ class CellStagingRing:
         self.width = width
         # under a mesh the cell chunk uploads stream-sharded (each
         # device receives its slice of the staged pad arrays); the
-        # sharded commit programs consume it as P(STREAM_AXIS) operands
+        # sharded commit programs consume it as P(STREAM_AXIS) operands.
+        # The cells are split into one run per slice, so every stream
+        # shard scatters a share of them, however few there are
         self.sharding = sharding
         self.uploads = 0          # lifetime stage() calls
         self.bytes_uploaded = 0   # lifetime H2D bytes issued
@@ -852,10 +842,12 @@ class CellStagingRing:
         if n > self.width:
             raise ValueError(f"chunk of {n} cells exceeds staging width "
                              f"{self.width}")
+        slices = (1 if self.sharding is None
+                  else self.sharding.mesh.shape[STREAM_AXIS])
         host = (
-            _fresh(self.width, n, ids, DROP_ID),
-            _fresh(self.width, n, idx, 0),
-            _fresh(self.width, n, weights, 0),
+            stream_runs(self.width, ids, DROP_ID, slices),
+            stream_runs(self.width, idx, 0, slices),
+            stream_runs(self.width, weights, 0, slices),
         )
         dev = (
             jax.device_put(host, self.sharding)
@@ -889,7 +881,7 @@ class PagedTripleRing:
         if n > self.width:
             raise ValueError(f"chunk of {n} triples exceeds staging "
                              f"width {self.width}")
-        buf = _fresh(self.width, n, triples, np.array([-1, 0, 0]))
+        buf = stream_runs(self.width, triples, (-1, 0, 0))
         if self.sharding is not None:
             # collective-free across real jax.distributed processes
             # (every process stages the identical translated chunk)

@@ -26,7 +26,11 @@ pins the parity):
 
 A third form, ``make_flat_sparse_ingest_fn``, runs the jnp tier's math
 into a flat per-interval delta that ``settle_ingest_delta`` later adds
-into the accumulator (the aggregator's single-device dense path).
+into the accumulator (the aggregator's single-device dense path).  On a
+("stream", "metric") mesh ``make_sharded_flat_sparse_ingest_fn`` runs it
+per device into a per-(stream, metric) partial, and
+``make_sharded_settle_fn`` merges the partials with one stream-axis
+``psum`` as it adds them in.
 
 Padding rows use id -1, which ``sanitize_ids`` (jnp tier) or the
 explicit bounds guard (Pallas tier) drops; callers route counts >= 2^30
@@ -41,11 +45,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 from loghisto_tpu.ops.backend import default_interpret
 from loghisto_tpu.ops.ingest import sanitize_ids
+from loghisto_tpu.parallel.mesh import METRIC_AXIS, STREAM_AXIS
 
 # Triples per Pallas grid step: small enough that the SMEM operand
 # blocks stay trivial, large enough to amortize grid overhead.
@@ -259,6 +266,69 @@ def settle_ingest_delta(acc: jnp.ndarray, delta: jnp.ndarray):
     return (
         acc + delta.reshape(num_cols, num_rows).T,
         jnp.zeros_like(delta),
+    )
+
+
+# The partition of a mesh's flat ingest partial: int32 ``[n_stream,
+# n_metric * num_cols * rows_per_shard]``, one ``[1, num_cols *
+# rows_per_shard]`` column-major block of its metric shard's rows per
+# (stream, metric) device.
+DELTA_SPEC = P(STREAM_AXIS, METRIC_AXIS)
+
+
+@functools.lru_cache(maxsize=None)
+def make_sharded_flat_sparse_ingest_fn(
+    mesh, bucket_limit: int, num_cols: int, rows_per_shard: int
+):
+    """The flat merge on a ("stream", "metric") mesh: ``f(partial,
+    packed) -> partial``, partial as ``DELTA_SPEC`` lays it out
+    and packed int32 ``[n, 3]`` split over the stream axis
+    (``P(STREAM_AXIS, None)``).  Each device scatters only its stream
+    slice of the triples, and of those only its metric shard's rows, into
+    its own block: no collective runs.  Rows are translated with the
+    sharded idiom (``sanitize_ids(ids - shard * rows_per_shard)``), so
+    another shard's rows fall out of range and drop."""
+    def sparse_ingest_flat_sharded(partial, packed):
+        shard = jax.lax.axis_index(METRIC_AXIS)
+        rows = sanitize_ids(packed[:, 0] - shard * rows_per_shard)
+        local = jnp.stack([rows, packed[:, 1], packed[:, 2]], axis=1)
+        return flat_sparse_ingest_batch(
+            partial[0], local, bucket_limit, num_cols
+        )[None]
+
+    return jax.jit(
+        shard_map(
+            sparse_ingest_flat_sharded, mesh=mesh,
+            in_specs=(DELTA_SPEC, P(STREAM_AXIS, None)),
+            out_specs=DELTA_SPEC,
+        ),
+        donate_argnums=0,
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def make_sharded_settle_fn(mesh):
+    """The settle on a ("stream", "metric") mesh: ``f(acc, partial) ->
+    (acc, zeroed partial)`` with acc ``[M, B]`` metric-sharded
+    (``P(METRIC_AXIS, None)``).  ONE ``psum`` over the stream axis merges
+    the stream slices' partials; each device then retiles its metric
+    shard's merged block and adds it into its accumulator shard, as
+    ``settle_ingest_delta`` does on one device."""
+    def settle_ingest_delta_sharded(acc, partial):
+        merged = jax.lax.psum(partial[0], STREAM_AXIS)
+        num_rows, num_cols = acc.shape
+        return (
+            acc + merged.reshape(num_cols, num_rows).T,
+            jnp.zeros_like(partial),
+        )
+
+    return jax.jit(
+        shard_map(
+            settle_ingest_delta_sharded, mesh=mesh,
+            in_specs=(P(METRIC_AXIS, None), DELTA_SPEC),
+            out_specs=(P(METRIC_AXIS, None), DELTA_SPEC),
+        ),
+        donate_argnums=(0, 1),
     )
 
 

@@ -46,7 +46,7 @@ from loghisto_tpu.ops.ingest import (
 )
 from loghisto_tpu.ops.dispatch import resolve_ingest_path
 from loghisto_tpu.ops.stats import dense_stats, dense_stats_np
-from loghisto_tpu.parallel.mesh import METRIC_AXIS, STREAM_AXIS
+from loghisto_tpu.parallel.mesh import METRIC_AXIS, STREAM_AXIS, stream_runs
 from loghisto_tpu.registry import MetricRegistry, RegistryFullError
 
 # Default registry-growth headroom: max_metrics = num_metrics * this when
@@ -300,7 +300,11 @@ def make_interval_distributed_step(
     Here each device folds batches into its own (stream, metric) partial
     with ZERO collectives, and the stream-axis psum runs once per
     ``collect`` — with B batches/interval the collective amortizes to
-    1/B of the per-batch design's volume.
+    1/B of the per-batch design's volume.  ``TPUAggregator``'s packed
+    (sparse transport) path on a mesh keeps partials the same way: a flat
+    per-(stream, metric) delta (``ops.sparse_ingest``), merged by one
+    stream psum per settle.  This factory serves raw-sample callers
+    (``loghisto_tpu.firehose``, ``benchmarks/mesh_scale.py``).
 
     Returns (ingest, collect, make_partial):
 
@@ -594,14 +598,18 @@ class TPUAggregator:
         self._lock = threading.Lock()
         self._dev_lock = threading.RLock()
         # flat int32 per-interval delta of the accumulator that packed
-        # merges scatter into on one device (_merge_packed_locked), and
-        # the samples it holds; _dense_acc_locked settles it.  Allocated
-        # at the first flat merge.
+        # merges scatter into (_merge_packed_locked; on a mesh one
+        # partial per (stream, metric) device), and the samples it
+        # holds; _dense_acc_locked settles it.  Allocated at the first
+        # flat merge.
         self._acc_array = None
         self._delta = None
         self._delta_samples = 0
+        self._settle = None  # the delta's settle program
         self.flat_merges = 0  # packed-merge launches into the delta
         self.delta_settles = 0
+        # bytes one device's settle all-reduce carried, over all settles
+        self.stream_allreduce_bytes = 0
         self._pending_ids: list[np.ndarray] = []
         self._pending_values: list[np.ndarray] = []
         self._pending_count = 0
@@ -1032,14 +1040,13 @@ class TPUAggregator:
     def _dense_acc_locked(self):
         """The dense accumulator with every merged count in it (caller
         holds _dev_lock): settles the pending flat delta first, adding
-        it in and zeroing it in one donated dispatch.  Every reader of
-        the accumulator comes through here."""
+        it in and zeroing it in one donated dispatch (on a mesh, the
+        dispatch that also all-reduces the stream partials).  Every
+        reader of the accumulator comes through here."""
         if self._delta_samples:
-            from loghisto_tpu.ops.sparse_ingest import settle_ingest_delta
-
             try:
                 with self.obs_recorder.span("ingest.settle"):
-                    self._acc_array, self._delta = settle_ingest_delta(
+                    self._acc_array, self._delta = self._settle(
                         self._acc_array, self._delta
                     )
             except Exception:
@@ -1047,33 +1054,80 @@ class TPUAggregator:
             else:
                 self._delta_samples = 0
                 self.delta_settles += 1
+                if self.mesh is not None:
+                    # one device's block is what its all-reduce carries
+                    self.stream_allreduce_bytes += (
+                        self._delta.nbytes // self.mesh.size
+                    )
         return self._acc_array
 
     def _select_packed_merge(self) -> None:
         """Pick the packed merge step: into the flat per-interval delta
-        on one device with the dense store, while its int32 index fits
-        (M * B < 2^31) and the sparse kernel is the XLA scatter tier;
-        into the [M, B] accumulator otherwise (a mesh's sharded one, the
-        page pool's twin, the Pallas tier's HBM tiles)."""
+        with the dense store, while its int32 index fits (M * B < 2^31
+        per metric shard) and the sparse kernel is the XLA scatter tier;
+        into the [M, B] accumulator otherwise (the page pool's twin, the
+        Pallas tier's HBM tiles).  On a mesh the delta is a
+        per-(stream, metric) partial: each device merges its stream
+        slice of every launch into its own block, and the settle merges
+        the blocks with one stream-axis all-reduce."""
         from loghisto_tpu.ops.dispatch import resolve_sparse_kernel
         from loghisto_tpu.ops.sparse_ingest import (
             make_flat_sparse_ingest_fn,
+            make_sharded_flat_sparse_ingest_fn,
+            make_sharded_settle_fn,
             make_sparse_ingest_fn,
+            settle_ingest_delta,
         )
 
         bl = self.config.bucket_limit
+        n_metric = n_stream = 1
+        if self.mesh is not None:
+            n_metric = self.mesh.shape[METRIC_AXIS]
+            n_stream = self.mesh.shape[STREAM_AXIS]
         self._flat = (
-            self.mesh is None
-            and self.paged is None
-            and self._acc_array.size < 1 << 31
+            self.paged is None
+            and self._acc_array.size // n_metric < 1 << 31
             and resolve_sparse_kernel("auto") == "jnp"
         )
-        if self._flat:
-            self._packed_ingest = make_flat_sparse_ingest_fn(
-                bl, self._acc_array.shape[1]
-            )
-        else:
+        # a mesh's flat launch gives every stream shard a full chunk
+        self._merge_width = _MERGE_CHUNK * (n_stream if self._flat else 1)
+        if not self._flat:
             self._packed_ingest = make_sparse_ingest_fn(bl)
+            return
+        rows, cols = self._acc_array.shape
+        if self.mesh is None:
+            self._packed_ingest = make_flat_sparse_ingest_fn(bl, cols)
+            self._settle = settle_ingest_delta
+        else:
+            self._packed_ingest = make_sharded_flat_sparse_ingest_fn(
+                self.mesh, bl, cols, rows // n_metric
+            )
+            self._settle = make_sharded_settle_fn(self.mesh)
+
+    def _fresh_delta(self) -> jnp.ndarray:
+        """A zero flat delta for the current accumulator: ``[M * B]`` on
+        one device; on a mesh ``[n_stream, M * B]``, one block per
+        (stream, metric) device (``DELTA_SPEC``)."""
+        size = self._acc_array.size
+        if self.mesh is None:
+            return jnp.zeros(size, dtype=jnp.int32)
+        from loghisto_tpu.ops.sparse_ingest import DELTA_SPEC
+        from loghisto_tpu.parallel.mesh import sharded_zeros
+
+        return sharded_zeros(
+            (self.mesh.shape[STREAM_AXIS], size),
+            NamedSharding(self.mesh, DELTA_SPEC),
+        )
+
+    def _merge_operand(self, cells: np.ndarray) -> np.ndarray:
+        """One packed launch's ``[_merge_width, 3]`` host operand: the
+        cells, then drop rows (id -1, dropped by sanitize_ids).  A mesh's
+        flat merge takes one ``_MERGE_CHUNK`` slice per stream shard, the
+        cells split into one contiguous run at the head of each; the
+        jitted merge uploads each slice to its stream shard's devices
+        only."""
+        return stream_runs(self._merge_width, cells, (-1, 0, 0),
+                           self._merge_width // _MERGE_CHUNK)
 
     def _grow_row_unit(self) -> int:
         """Row-count granularity growth must preserve: the mesh metric
@@ -1153,8 +1207,10 @@ class TPUAggregator:
         self.ingest_path = new_path
         self._acc = new_acc
         self._delta = None  # settled above; the next merge sizes it anew
-        if self._flat and new_acc.size >= 1 << 31:
-            self._select_packed_merge()  # the flat index outgrew int32
+        if self._flat and (self.mesh is not None or new_acc.size >= 1 << 31):
+            # a mesh's merge is built for its shard's row count; on one
+            # device the flat index may have outgrown int32
+            self._select_packed_merge()
         self.num_metrics = new_m
         self.stats_snapshot = None  # row space changed; handle is stale
         self.registry.grow(new_m)
@@ -1814,6 +1870,7 @@ class TPUAggregator:
             "backpressure_flushes": self._backpressure_flushes,
             "flat_merges": self.flat_merges,
             "delta_settles": self.delta_settles,
+            "stream_allreduce_bytes": self.stream_allreduce_bytes,
         }
 
     def _preagg_record(self, ids: np.ndarray, values: np.ndarray) -> None:
@@ -1917,21 +1974,16 @@ class TPUAggregator:
                 return
             self._device_down_until = 0.0
             return
-        for off in range(0, n, _MERGE_CHUNK):
-            take = min(_MERGE_CHUNK, n - off)
-            pad = np.empty((_MERGE_CHUNK, 3), dtype=np.int32)
-            pad[:, 0] = -1  # negative id: dropped by sanitize_ids
-            pad[:, 1] = 0
-            pad[:, 2] = 0
-            pad[:take] = packed[off:off + take]
+        width = self._merge_width
+        for off in range(0, n, width):
+            take = min(width, n - off)
             try:
+                pad = self._merge_operand(packed[off:off + take])
                 if self._flat:
                     # into the flat delta: no relayout of the [M, B]
                     # accumulator per launch (ops/sparse_ingest.py)
                     if self._delta is None:
-                        self._delta = jnp.zeros(
-                            self._acc_array.size, dtype=jnp.int32
-                        )
+                        self._delta = self._fresh_delta()
                     self._delta = self._packed_ingest(self._delta, pad)
                 else:
                     self._acc = self._packed_ingest(

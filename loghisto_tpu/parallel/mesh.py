@@ -13,6 +13,14 @@ order-free and mergeable) and the *metric* axis shards the dense
 10k+ metric configs whose dense tensor shouldn't be replicated).  Merges
 ride ``psum`` over the stream axis (ICI within a slice, DCN across
 slices); percentile extraction then runs row-parallel on the metric axis.
+
+``TPUAggregator``'s packed ingest on a mesh keeps one flat partial per
+(stream, metric) device: each launch's triples split over the stream
+axis (``triple_sharding``), each device merges its slice collective-free,
+and the settle before any read merges the partials with ONE stream psum.
+The interval commit's cells split over the stream axis the same way
+(``cell_sharding``); ``stream_runs`` lays both out, one contiguous run
+of the rows per stream slice.
 """
 
 from __future__ import annotations
@@ -70,6 +78,34 @@ def triple_sharding(mesh: Mesh) -> NamedSharding:
     like cell chunks — each device scatters its slice into a local pool
     delta and ONE psum merges them (int32 ⇒ order-independent)."""
     return NamedSharding(mesh, PartitionSpec(STREAM_AXIS, None))
+
+
+def stream_runs(width: int, head: np.ndarray, pad,
+                slices: int = 1) -> np.ndarray:
+    """A new int32 host array of ``width`` rows for an upload split over
+    ``slices`` stream shards: ``head`` cut into ``slices`` contiguous
+    runs, one at the start of each of the ``slices`` equal slices of the
+    width, every slice's tail filled with ``pad`` (a scalar, or one value
+    per column of a 2-D ``head``).  With one slice it is ``head`` then
+    ``pad``.  Staged uploads never reuse a host buffer: ``jax.device_put``
+    may alias one (the CPU backend does) or still be reading it after it
+    returns (an async H2D copy), so a buffer handed to it is never
+    written again."""
+    out = np.empty((width,) + head.shape[1:], dtype=np.int32)
+    parts = out.reshape((slices, width // slices) + head.shape[1:])
+    share = -(-len(head) // slices)
+    for s in range(slices):
+        run = head[s * share:(s + 1) * share]
+        parts[s, :len(run)] = run
+        tail = parts[s, len(run):]
+        if np.ndim(pad):
+            # a column at a time: a broadcast row fill is several times
+            # slower on the host
+            for col, value in enumerate(pad):
+                tail[:, col] = value
+        else:
+            tail[...] = pad
+    return out
 
 
 def sharded_zeros(shape, sharding=None) -> jax.Array:

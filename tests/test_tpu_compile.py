@@ -223,3 +223,43 @@ def test_flat_sparse_merge_and_settle_compile_for_v5e(one_chip):
     settle = settle_ingest_delta.lower(
         _shape(one_chip, (M, B)), flat).compile()
     assert settle.memory_analysis().temp_size_in_bytes < 2 * M * B * 4
+
+
+def test_mesh_flat_merge_and_settle_compile_for_v5e_2x2(topo):
+    """The mesh's packed merge and settle at 10k x 8193 on a 2x2
+    ("stream", "metric") mesh, a 65,536-triple slice a stream shard (the
+    aggregator's launch width): the merge runs no collective and copies or
+    relayouts no operand as large as a metric shard's rows; the settle
+    merges the stream partials with exactly one all-reduce."""
+    import math
+    import re
+
+    from jax.sharding import NamedSharding
+
+    from loghisto_tpu.ops.sparse_ingest import (
+        make_sharded_flat_sparse_ingest_fn, make_sharded_settle_fn,
+        DELTA_SPEC,
+    )
+    from loghisto_tpu.parallel.mesh import (
+        acc_sharding, make_mesh, triple_sharding,
+    )
+
+    mesh = make_mesh(stream=2, metric=2, devices=topo.devices)
+    shard = M // 2 * B
+    partial = _shape(NamedSharding(mesh, DELTA_SPEC), (2, M * B))
+    merge = make_sharded_flat_sparse_ingest_fn(
+        mesh, BUCKET_LIMIT, B, M // 2).lower(
+        partial, _shape(triple_sharding(mesh), (2 << 16, 3))).compile()
+    hlo = merge.as_text()
+    assert not re.search(
+        r"all-reduce|all-gather|collective-permute|all-to-all", hlo)
+    moved = re.findall(r"= s32\[([\d,]*)\]\S* (?:reshape|copy)\(", hlo)
+    dims = [math.prod(int(d) for d in m.split(",") if d) for m in moved]
+    assert all(n < shard for n in dims), moved
+    assert merge.memory_analysis().temp_size_in_bytes < 64 << 20
+
+    settle = make_sharded_settle_fn(mesh).lower(
+        _shape(acc_sharding(mesh), (M, B)), partial).compile()
+    all_reduces = re.findall(r"\sall-reduce(?:-start)?\(",
+                             settle.as_text())
+    assert len(all_reduces) == 1, all_reduces
