@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import collections
 import functools
+import queue
 import threading
 import time
 from typing import Dict, Mapping, Optional
@@ -64,6 +65,39 @@ _MERGE_CHUNK = 1 << 16
 # probe itself (one host compress + unique over this prefix) must stay
 # negligible next to shipping the batch.
 _PROBE_SAMPLES = 1 << 16
+
+
+class _FoldJob:
+    """One queued "fold" item's fold, run on the transfer worker's helper
+    thread ahead of the item's turn.  ``done`` is set once ``packed`` or
+    ``error`` (what the fold raised) holds the outcome."""
+
+    def __init__(self, ids: np.ndarray, values: np.ndarray, fold) -> None:
+        self.ids, self.values, self._fold = ids, values, fold
+        self.done = threading.Event()
+        self.packed: Optional[np.ndarray] = None
+        self.error: Optional[Exception] = None
+
+    def run(self) -> None:
+        try:
+            self.packed = self._fold(self.ids, self.values)
+        except Exception as e:
+            self.error = e
+        finally:
+            self.done.set()
+
+    def result(self) -> np.ndarray:
+        self.done.wait()
+        if self.error is not None:
+            raise self.error
+        return self.packed
+
+
+def _fold_helper(jobs: queue.SimpleQueue) -> None:
+    """The transfer worker's fold-ahead thread: runs each job in turn
+    until its worker, exiting, queues None."""
+    while (job := jobs.get()) is not None:
+        job.run()
 
 
 class IngestStagingRing:
@@ -635,6 +669,14 @@ class TPUAggregator:
         self._xfer_active = False  # worker is mid-item
         self._xfer_thread: Optional[threading.Thread] = None
         self._xfer_stop = False
+        # fold-ahead: the worker's helper thread folds the queue's next
+        # "fold" item while the worker merges the one before it.  The
+        # helper and its job queue are spawned with each worker; a job
+        # rides on its queue item, so the item stays queued, counted
+        # and barriered until its merge ends.  Guarded by _xfer_cv.
+        self._fold_thread: Optional[threading.Thread] = None
+        self._fold_jobs: Optional[queue.SimpleQueue] = None
+        self.folds_ahead = 0
         self._staging_ring: Optional[IngestStagingRing] = None
         self.staging_depth = 3
         # wire accounting for bytes/sample reporting (bench satellite)
@@ -1410,12 +1452,15 @@ class TPUAggregator:
                 return  # device cooling down; keep buffering
             elif (
                 not force
+                and self._xfer_queue
                 and self._xfer_queued_samples >= self.max_pending_samples
             ):
                 # transfer queue saturated (device slower than producers):
                 # leave samples in the bounded host buffer, where the
                 # oldest-first shed policy applies, instead of growing
-                # the queue without bound
+                # the queue without bound.  One item may always wait
+                # behind the one in flight, for the worker's helper to
+                # fold while the worker merges (_fold_ahead_locked).
                 self._backpressure_flushes += 1
                 return
             else:
@@ -1481,7 +1526,8 @@ class TPUAggregator:
 
     def _enqueue_xfer(self, item: tuple) -> None:
         """Append one (kind, a, b, n_samples, force) item to the transfer
-        queue, lazily (re)spawning the worker thread."""
+        queue, lazily (re)spawning the worker thread and its fold-ahead
+        helper."""
         with self._xfer_cv:
             if self._xfer_thread is None or not self._xfer_thread.is_alive():
                 if (
@@ -1497,6 +1543,14 @@ class TPUAggregator:
                         "loghisto-tpu-xfer"
                     )
                 self._xfer_stop = False
+                self._fold_jobs = queue.SimpleQueue()
+                self._fold_thread = threading.Thread(
+                    target=_fold_helper,
+                    args=(self._fold_jobs,),
+                    daemon=True,
+                    name="loghisto-tpu-fold",
+                )
+                self._fold_thread.start()
                 self._xfer_thread = threading.Thread(
                     target=self._xfer_worker,
                     daemon=True,
@@ -1505,6 +1559,8 @@ class TPUAggregator:
                 self._xfer_thread.start()
             self._xfer_queue.append(item)
             self._xfer_queued_samples += item[3]
+            if self._xfer_active:
+                self._fold_ahead_locked()
             self._xfer_cv.notify_all()
 
     def wait_transfers(self, timeout: Optional[float] = None) -> bool:
@@ -1552,11 +1608,23 @@ class TPUAggregator:
         with self._xfer_cv:
             self._xfer_stop = True
             self._xfer_cv.notify_all()
-            t = self._xfer_thread
+            t, helper = self._xfer_thread, self._fold_thread
         if t is not None:
             t.join(timeout=10.0)
+            if not t.is_alive():  # the helper stops after its worker
+                helper.join(timeout=10.0)
 
     def _xfer_worker(self) -> None:
+        try:
+            self._xfer_loop()
+        finally:
+            # the helper finishes any fold it was handed, then stops; a
+            # worker respawned in this one's place finds that fold on
+            # its queue item
+            with self._xfer_cv:
+                self._fold_jobs.put(None)
+
+    def _xfer_loop(self) -> None:
         while True:
             inj = self.fault_injector
             if inj is not None:
@@ -1575,6 +1643,7 @@ class TPUAggregator:
                     return
                 item = self._xfer_queue.popleft()
                 self._xfer_active = True
+                self._fold_ahead_locked()
             try:
                 with self.obs_recorder.span("ingest.drain"):
                     self._process_xfer_item(item)
@@ -1591,7 +1660,8 @@ class TPUAggregator:
                     self._xfer_cv.notify_all()
 
     def _process_xfer_item(self, item: tuple) -> None:
-        kind, a, b, n, force = item
+        # a "fold" item the helper folded ahead carries its job last
+        kind, a, b, n, force, *ahead = item
         if kind == "packed":
             self._xfer_uploads += 1
             self._xfer_bytes += a.nbytes
@@ -1607,9 +1677,41 @@ class TPUAggregator:
             self._requeue_raw(a, b)
             return
         if kind == "fold" or self._maybe_switch_sparse(a, b, n):
-            self._process_fold(a, b, n)
+            self._process_fold(a, b, n, *ahead)
             return
         self._process_raw(a, b, n)
+
+    def _fold_ahead_locked(self) -> None:
+        """Hand the queue head's fold to the helper, so it runs while the
+        worker works off the item it holds (caller holds _xfer_cv; the
+        worker has just taken an item, or an item arrived while it holds
+        one).  Only a "fold" head not yet handed over, and not one the
+        retry cooldown will bounce unfolded."""
+        if not self._xfer_queue:
+            return
+        head = self._xfer_queue[0]
+        kind, ids, values, _, force, *ahead = head
+        if (
+            kind != "fold" or ahead
+            or (not force and time.monotonic() < self._device_down_until)
+        ):
+            return
+        job = _FoldJob(ids, values, self._fold_packed)
+        self._xfer_queue[0] = head + (job,)
+        self._fold_jobs.put(job)
+        self.folds_ahead += 1
+
+    def _fold_packed(
+        self, ids: np.ndarray, values: np.ndarray
+    ) -> np.ndarray:
+        from loghisto_tpu import _native
+
+        with self.obs_recorder.span("ingest.fold"):
+            return _native.fold_packed(
+                ids, values,
+                bucket_limit=self.config.bucket_limit,
+                precision=self.config.precision,
+            )
 
     def _requeue_raw(self, ids: np.ndarray, values: np.ndarray) -> None:
         if not len(ids):
@@ -1672,22 +1774,21 @@ class TPUAggregator:
         return self.transport == "sparse"
 
     def _process_fold(
-        self, ids: np.ndarray, values: np.ndarray, n: int
+        self, ids: np.ndarray, values: np.ndarray, n: int,
+        job: Optional[_FoldJob] = None,
     ) -> None:
-        """Sparse transport: fold the raw batch into packed triples on
-        this worker thread (GIL-released parallel native tier, NumPy
-        fallback) and merge them via the packed scatter.  Failures past
-        this point spill exactly (cells are finished aggregates — no
-        retry queue needed)."""
-        from loghisto_tpu import _native
-
+        """Sparse transport: fold the raw batch into packed triples
+        (GIL-released parallel native tier, NumPy fallback) and merge
+        them via the packed scatter.  The fold is the helper's ``job``
+        when it ran ahead, else it runs here.  Failures past the fold
+        spill exactly (cells are finished aggregates — no retry queue
+        needed)."""
         try:
-            with self.obs_recorder.span("ingest.fold"):
-                packed = _native.fold_packed(
-                    ids, values,
-                    bucket_limit=self.config.bucket_limit,
-                    precision=self.config.precision,
-                )
+            if job is None:
+                packed = self._fold_packed(ids, values)
+            else:
+                with self.obs_recorder.span("ingest.fold_wait"):
+                    packed = job.result()
         except MemoryError:
             # can't build the fold table: ship the batch raw instead of
             # losing it (same wire contract, just more bytes)
@@ -1871,6 +1972,7 @@ class TPUAggregator:
             "flat_merges": self.flat_merges,
             "delta_settles": self.delta_settles,
             "stream_allreduce_bytes": self.stream_allreduce_bytes,
+            "folds_ahead": self.folds_ahead,
         }
 
     def _preagg_record(self, ids: np.ndarray, values: np.ndarray) -> None:

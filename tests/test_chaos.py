@@ -9,6 +9,7 @@ through identical code — the bit-identical assertion is then exact
 dict equality over every device statistic, percentiles included."""
 
 import datetime as dt
+import threading
 import time
 
 import numpy as np
@@ -268,6 +269,73 @@ def test_crashed_transfer_worker_respawns_on_next_enqueue():
     agg.flush(force=True)
     assert sup.restarts_by_name.get("loghisto-tpu-xfer") == 1
     assert agg.collect(reset=False).metrics["m_count"] == 100.0
+
+
+@pytest.mark.parametrize("action", ["raise", "wedge"])
+def test_worker_lost_mid_fold_ahead_conserves_every_sample(action,
+                                                            monkeypatch):
+    # the worker takes item 0 and hands item 1's fold to its helper,
+    # where it is held; the worker merges item 0 and is lost at its
+    # next loop top while that fold is still in flight
+    from loghisto_tpu import _native
+
+    inj = FaultInjector(wedge_timeout_s=30.0)
+    inj.plan("agg.xfer_worker", action, on_call=2)
+    sup = ThreadSupervisor()
+    agg = TPUAggregator(num_metrics=16, config=CFG, transport="sparse",
+                        batch_size=256)
+    agg.fault_injector = inj
+    agg.supervisor = sup
+    mid = agg.registry.id_for("m")
+    started, release = threading.Event(), threading.Event()
+    fold = _native.fold_packed
+
+    def held_fold(ids, values, **kw):
+        if threading.current_thread().name == "loghisto-tpu-fold":
+            started.set()
+            release.wait(30)
+        return fold(ids, values, **kw)
+
+    monkeypatch.setattr(_native, "fold_packed", held_fold)
+    with agg._xfer_cv:
+        for _ in range(2):
+            agg.record_batch(np.full(256, mid, np.int32),
+                             np.ones(256, np.float32))
+    assert started.wait(10.0)
+    worker, helper = agg._xfer_thread, agg._fold_thread
+    deadline = time.monotonic() + 5.0
+    while (worker.is_alive() if action == "raise"
+           else inj.wedged_now == 0) and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert inj.fires_at("agg.xfer_worker") == 1
+    # item 1 is still queued and counted: the barrier does not pass
+    assert not agg.wait_transfers(timeout=0.3)
+    assert agg._xfer_queued_samples == 256
+
+    release.set()
+    if action == "wedge":
+        inj.release_wedges()
+    else:
+        # the helper finishes the fold it holds, then stops with its
+        # worker
+        helper.join(timeout=10.0)
+        assert not helper.is_alive()
+    # the next enqueue respawns worker and helper together; the forced
+    # flush merges the fold left on item 1, then item 2
+    agg.record_batch(np.full(256, mid, np.int32), np.ones(256, np.float32))
+    agg.flush(force=True)
+    if action == "raise":
+        assert sup.restarts_by_name.get("loghisto-tpu-xfer") == 1
+        assert agg._xfer_thread is not worker
+        assert agg._fold_thread is not helper
+        assert agg._fold_thread.is_alive()
+    else:
+        assert agg._xfer_thread is worker and agg._fold_thread is helper
+        assert not sup.restarts_by_name
+    assert agg.collect(reset=False).metrics["m_count"] == 768.0
+    assert agg.transport_stats()["folds_ahead"] >= 1
+    assert agg._shed_samples == 0
+    agg.close()
 
 
 # -- scripted slow consumer / clock step ---------------------------------- #

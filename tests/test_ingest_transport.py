@@ -11,6 +11,7 @@ legally land one bucket over (conservation still exact; see
 test_preagg_boundary_values_conserve_counts)."""
 
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -636,3 +637,190 @@ def test_flat_delta_conserves_through_every_reader(tmp_path):
         assert stats["delta_settles"] == agg.delta_settles >= 9
     finally:
         ms.stop()
+
+
+# ---------------------------------------------------------------------- #
+# fold-ahead: the worker's helper folds the next queued "fold" item while
+# the worker merges the one before it
+# ---------------------------------------------------------------------- #
+
+FOLD_ROUTES = ["one_device", "mesh_2x2"]
+FOLD_ITEM = 2048  # samples per queued item: one record_batch, one flush
+
+
+def _fold_agg(route):
+    from loghisto_tpu.parallel.mesh import make_mesh
+
+    agg = TPUAggregator(
+        num_metrics=16, config=CFG, transport="sparse",
+        batch_size=FOLD_ITEM,
+        mesh=make_mesh(2, 2) if route == "mesh_2x2" else None,
+    )
+    for i in range(16):
+        agg.registry.id_for(f"m{i}")
+    return agg
+
+
+def _fold_item(rng, row=None):
+    """One queued item's samples: every one on ``row`` when given."""
+    ids = (
+        np.full(FOLD_ITEM, row, np.int32) if row is not None
+        else rng.integers(0, 16, FOLD_ITEM).astype(np.int32)
+    )
+    return ids, rng.lognormal(3, 1, FOLD_ITEM).astype(np.float32)
+
+
+def _slow_merges(monkeypatch, agg, seconds, log=None):
+    """Stretch every packed merge by ``seconds``; ``log`` gets
+    ("merge", row) and ("merged", row) of the merge's first cell."""
+    merge = agg._merge_packed_locked
+
+    def slow(packed):
+        row = int(packed[0, 0])
+        if log is not None:
+            log.append(("merge", row))
+        time.sleep(seconds)
+        merge(packed)
+        if log is not None:
+            log.append(("merged", row))
+
+    monkeypatch.setattr(agg, "_merge_packed_locked", slow)
+
+
+def _row_counts(agg):
+    return _drained_acc(agg).sum(axis=1)
+
+
+@pytest.mark.parametrize("route", FOLD_ROUTES)
+@pytest.mark.parametrize("arrival", ["queued", "during_merge"])
+def test_fold_ahead_starts_the_next_fold_before_the_merge_returns(
+    route, arrival, monkeypatch
+):
+    """With every merge stretched, the fold of item k+1 starts, on the
+    helper thread, before the merge of item k returns: when all four
+    items were queued before the worker took the first, and when each
+    arrives while the one before it merges.  The counts stay exact."""
+    from loghisto_tpu import _native
+
+    agg = _fold_agg(route)
+    log, folds_on = [], {}
+    fold = _native.fold_packed
+
+    def logged_fold(ids, values, **kw):
+        log.append(("fold", int(ids[0])))
+        folds_on[int(ids[0])] = threading.current_thread().name
+        return fold(ids, values, **kw)
+
+    monkeypatch.setattr(_native, "fold_packed", logged_fold)
+    _slow_merges(monkeypatch, agg, 0.2, log)
+    rng = np.random.default_rng(5)
+    items = 4
+    if arrival == "queued":
+        with agg._xfer_cv:  # all queued before the worker takes one
+            for row in range(items):
+                agg.record_batch(*_fold_item(rng, row))
+    else:
+        for row in range(items):
+            deadline = time.monotonic() + 30
+            while (row and ("merge", row - 1) not in log
+                   and time.monotonic() < deadline):
+                time.sleep(0.002)
+            agg.record_batch(*_fold_item(rng, row))
+    assert agg.wait_transfers(timeout=60)
+    for k in range(items - 1):
+        assert log.index(("fold", k + 1)) < log.index(("merged", k)), log
+        assert folds_on[k + 1] == "loghisto-tpu-fold"
+    assert agg.transport_stats()["folds_ahead"] == items - 1
+    want = np.zeros(16, np.int64)
+    want[:items] = FOLD_ITEM
+    np.testing.assert_array_equal(_row_counts(agg), want)
+    agg.close()
+
+
+@pytest.mark.parametrize("route", FOLD_ROUTES)
+@pytest.mark.parametrize("barrier", ["flush_force", "close_mid_flush",
+                                     "collect_reset"])
+def test_fold_ahead_keeps_counts_exact(route, barrier, monkeypatch):
+    """Items folded ahead are merged exactly once through every barrier:
+    a forced flush, close() while writers are still flushing, and
+    collect(reset=True) between items, with a short switch interval."""
+    import sys
+
+    agg = _fold_agg(route)
+    _slow_merges(monkeypatch, agg, 0.01)
+    rng = np.random.default_rng(["flush_force", "close_mid_flush",
+                                 "collect_reset"].index(barrier) + 61)
+    chunks = [_fold_item(rng) for _ in range(12)]
+    want = np.bincount(
+        np.concatenate([ids for ids, _ in chunks]), minlength=16)
+    collected = np.zeros(16, np.int64)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        if barrier == "flush_force":
+            with agg._xfer_cv:
+                for ids, values in chunks:
+                    agg.record_batch(ids, values)
+            agg.flush(force=True)
+            assert agg._xfer_queued_samples == 0
+        else:
+            def writer(k):
+                for ids, values in chunks[k::4]:
+                    agg.record_batch(ids, values)
+
+            ts = [threading.Thread(target=writer, args=(k,))
+                  for k in range(4)]
+            for t in ts:
+                t.start()
+            while any(t.is_alive() for t in ts):
+                if barrier == "close_mid_flush":
+                    agg.close()  # drains what was queued, then stops
+                else:
+                    out = agg.collect(reset=True).metrics
+                    collected += np.array(
+                        [out.get(f"m{i}_count", 0.0) for i in range(16)],
+                        dtype=np.int64)
+            for t in ts:
+                t.join(timeout=60)
+                assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+    np.testing.assert_array_equal(_row_counts(agg) + collected, want)
+    assert agg._buffered_samples() == 0 and agg._shed_samples == 0
+    assert agg.transport_stats()["folds_ahead"] >= 1
+    agg.close()
+
+
+@pytest.mark.parametrize("route", FOLD_ROUTES)
+def test_wait_transfers_times_out_while_a_fold_ahead_runs(
+    route, monkeypatch
+):
+    """The item folded ahead stays queued: the transfer barrier does not
+    pass while its fold is still running on the helper."""
+    from loghisto_tpu import _native
+
+    agg = _fold_agg(route)
+    started, release = threading.Event(), threading.Event()
+    fold = _native.fold_packed
+
+    def held_fold(ids, values, **kw):
+        if threading.current_thread().name == "loghisto-tpu-fold":
+            started.set()
+            release.wait(30)
+        return fold(ids, values, **kw)
+
+    monkeypatch.setattr(_native, "fold_packed", held_fold)
+    rng = np.random.default_rng(9)
+    with agg._xfer_cv:
+        for row in (0, 1):
+            agg.record_batch(*_fold_item(rng, row))
+    assert started.wait(30)
+    assert not agg.wait_transfers(timeout=0.3)
+    assert agg._xfer_queued_samples > 0
+    release.set()
+    assert agg.wait_transfers(timeout=60)
+    assert agg.transport_stats()["folds_ahead"] == 1
+    want = np.zeros(16, np.int64)
+    want[:2] = FOLD_ITEM
+    np.testing.assert_array_equal(_row_counts(agg), want)
+    agg.close()

@@ -665,11 +665,21 @@ def test_backpressure_flushes_count_flushes_a_full_queue_refused():
         for _ in range(100):
             agg.record("m", 1.0)
         agg.flush()  # enqueued: the worker takes it and wedges
+        deadline = time.monotonic() + 10.0
+        while agg._xfer_queue and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert agg._xfer_active and not agg._xfer_queue
+        for _ in range(10):
+            agg.record("m", 1.0)
+        # one item may always wait behind the one in flight
+        agg.flush()
         assert agg.transport_stats()["backpressure_flushes"] == 0
+        assert agg._buffered_samples() == 0
         for _ in range(10):
             agg.record("m", 1.0)
         # the queue still counts the wedged item's 100 samples, at least
-        # max_pending_samples: the flush leaves these 10 on the host
+        # max_pending_samples, and holds a waiting item: the flush leaves
+        # these 10 on the host
         agg.flush()
         assert agg.transport_stats()["backpressure_flushes"] == 1
         assert agg._buffered_samples() == 10
