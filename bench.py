@@ -82,7 +82,7 @@ def _resolve_ingest_step(cfg, platform: str):
     by default for this configuration (TPUAggregator(ingest_path="auto")
     resolves through the same table) — the headline measures what a user
     of the default path actually gets, not a hardwired kernel.  Override
-    with LOGHISTO_BENCH_PATH=scatter|sort|hybrid for comparisons."""
+    with LOGHISTO_BENCH_PATH=scatter|sort|pallas|fused for comparisons."""
     import os
 
     from loghisto_tpu.ops.dispatch import ingest_step_fn, resolve_ingest_path

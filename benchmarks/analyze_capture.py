@@ -85,14 +85,11 @@ def analyze(dirname: str, table: dict) -> dict[int, str]:
     return winners
 
 
-SORT_FAMILY = ("sort", "sortscan")
-
-
 def derive_thresholds(dirname: str, table: dict,
                       winners: dict[int, str]) -> dict | None:
     """One capture's winners -> the dispatch threshold table
     ops/dispatch.py loads.  Policy shape is fixed (pallas at M=1?,
-    sort-family above a crossover, scatter between); this derives the
+    sort above a crossover, scatter between); this derives the
     numbers.  Returns None when the capture can't support the policy
     (not TPU, or no multi-metric rows)."""
     if table.get("platform") != "tpu":
@@ -103,15 +100,15 @@ def derive_thresholds(dirname: str, table: dict,
         print(f"{dirname}: no multi-metric rows; no thresholds derived")
         return None
 
-    sort_wins = sorted(m for m, w in multi.items() if w in SORT_FAMILY)
-    other_wins = sorted(m for m, w in multi.items() if w not in SORT_FAMILY)
+    sort_wins = sorted(m for m, w in multi.items() if w == "sort")
+    other_wins = sorted(m for m, w in multi.items() if w != "sort")
     if sort_wins and sort_wins[-1] < max(other_wins, default=0):
         # non-monotone table with sort LOSING at the top of the measured
         # range: a threshold would dispatch sort into a region the capture
         # shows another kernel winning — disable instead of extrapolating
-        print(f"{dirname}: WARNING sort-family wins at {sort_wins} but "
+        print(f"{dirname}: WARNING sort wins at {sort_wins} but "
               f"loses above (others at {other_wins}); disabling the "
-              f"sort-family dispatch region")
+              f"sort dispatch region")
         sort_wins = []
     if sort_wins:
         lo = max([m for m in other_wins if m < sort_wins[0]] or [1])
@@ -120,11 +117,8 @@ def derive_thresholds(dirname: str, table: dict,
         # Floor of 2 keeps the value inside the loader's smm > 1 guard
         # (M=1 has its own pallas policy axis).
         sort_min = max(2, int(round((lo * sort_wins[0]) ** 0.5)))
-        # which sort formulation won at the high-cardinality rows
-        kernel = winners[sort_wins[-1]]
     else:
-        sort_min = 1 << 30  # sort-family never measured fastest
-        kernel = "sort"
+        sort_min = 1 << 30  # sort never measured fastest
 
     return {
         "source": dirname,
@@ -134,7 +128,6 @@ def derive_thresholds(dirname: str, table: dict,
         "mode": table.get("mode"),
         "winners": {str(m): w for m, w in sorted(winners.items())},
         "sort_min_metrics": sort_min,
-        "high_cardinality_kernel": kernel,
         "pallas_single_metric": winners.get(1) in ("pallas", "pallasb"),
     }
 

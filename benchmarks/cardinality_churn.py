@@ -130,7 +130,7 @@ def run(configs=None) -> dict:
         # lossless retirement: every committed sample is still on device,
         # either in a live row or folded into the overflow row
         acc = np.asarray(
-            agg._finalize_acc(agg._acc), dtype=np.int64
+            agg._acc, dtype=np.int64
         )
         if agg._spill is not None:
             acc = acc + agg._spill

@@ -1,5 +1,5 @@
-"""Compare the device ingest paths (scatter vs sort-dedup vs MXU matmul
-vs Pallas row/multirow) across metric counts — the tuning harness for
+"""Compare the device ingest paths (scatter vs sort-dedup vs Pallas
+row) across metric counts — the tuning harness for
 picking per-config fast paths on real hardware.
 
 Two measurement modes:
@@ -149,7 +149,7 @@ def main():
     print(f"{'M':>6} {'path':>10} {'samples/s':>14}")
 
     # each path runs isolated: one path's lowering failure must not lose
-    # the rest of the table (the r2_a1 capture lost scatter/matmul/sort
+    # the rest of the table (the r2_a1 capture lost scatter/sort
     # data to a single Pallas lowering rejection)
     results = {"platform": platform, "batch": n,
                "num_buckets": cfg.num_buckets,
@@ -186,20 +186,18 @@ def main():
                 results["errors"]["<aborted>"] = "device fault; table aborted"
                 raise DeviceDead from e
 
-    def measure(m, name, pure_step, jitted, acc, fn_args,
-                needs_ids=True, make_acc=None):
+    def measure(m, name, pure_step, jitted, acc, fn_args, needs_ids=True):
         if looped:
             def make_run(iters):
                 return make_looped(pure_step, m, n, iters,
                                    needs_ids=needs_ids)
 
-            if make_acc is None:
-                make_acc = (
-                    (lambda: jnp.zeros(cfg.num_buckets, dtype=jnp.int32))
-                    if not needs_ids
-                    else (lambda: jnp.zeros((m, cfg.num_buckets),
-                                            dtype=jnp.int32))
-                )
+            make_acc = (
+                (lambda: jnp.zeros(cfg.num_buckets, dtype=jnp.int32))
+                if not needs_ids
+                else (lambda: jnp.zeros((m, cfg.num_buckets),
+                                        dtype=jnp.int32))
+            )
             def run_adaptive():
                 dt, iters = bench_looped_adaptive(
                     make_run, make_acc, max_iters=loop_iters
@@ -225,10 +223,6 @@ def _run_table(args, cfg, rng, n, platform, looped, measure, results):
     import jax.numpy as jnp
 
     from loghisto_tpu.ops.ingest import ingest_batch
-    from loghisto_tpu.ops.matmul_hist import (
-        ingest_batch_matmul,
-        make_matmul_ingest_fn,
-    )
     from loghisto_tpu.ops.pallas_kernels import (
         make_pallas_row_ingest,
         pallas_histogram_row,
@@ -236,9 +230,7 @@ def _run_table(args, cfg, rng, n, platform, looped, measure, results):
     from loghisto_tpu.ops.ingest import make_ingest_fn
     from loghisto_tpu.ops.sort_ingest import (
         make_sort_ingest_fn,
-        make_sortscan_ingest_fn,
         sort_ingest_batch,
-        sortscan_ingest_batch,
     )
 
     values = rng.lognormal(8, 2, n).astype(np.float32)
@@ -257,21 +249,6 @@ def _run_table(args, cfg, rng, n, platform, looped, measure, results):
                 lambda a, i, v: sort_ingest_batch(
                     a, i, v, cfg.bucket_limit),
                 make_sort_ingest_fn(cfg.bucket_limit), acc, (ids, values))
-
-        acc = jnp.zeros((m, cfg.num_buckets), dtype=jnp.int32)
-        measure(m, "sortscan",
-                lambda a, i, v: sortscan_ingest_batch(
-                    a, i, v, cfg.bucket_limit),
-                make_sortscan_ingest_fn(cfg.bucket_limit), acc,
-                (ids, values))
-
-        if m * cfg.num_buckets <= 1 << 23:
-            acc = jnp.zeros((m, cfg.num_buckets), dtype=jnp.int32)
-            measure(m, "matmul",
-                    lambda a, i, v: ingest_batch_matmul(
-                        a, i, v, cfg.bucket_limit),
-                    make_matmul_ingest_fn(cfg.bucket_limit), acc,
-                    (ids, values))
 
         if m == 1:
             row = jnp.zeros(cfg.num_buckets, dtype=jnp.int32)
@@ -294,37 +271,6 @@ def _run_table(args, cfg, rng, n, platform, looped, measure, results):
                     jax.jit(lambda a, i, v: pallas_row_ingest_batch(
                         a, i, v, cfg.bucket_limit), donate_argnums=0),
                     acc, (ids, values))
-
-        if m >= 256:
-            from loghisto_tpu.ops.hybrid_hist import (
-                ingest_batch_hybrid,
-                make_hybrid_ingest_fn,
-            )
-
-            acc = jnp.zeros((m, cfg.num_buckets), dtype=jnp.int32)
-            measure(m, "hybrid",
-                    lambda a, i, v: ingest_batch_hybrid(
-                        a, i, v, cfg.bucket_limit),
-                    make_hybrid_ingest_fn(cfg.bucket_limit), acc,
-                    (ids, values))
-
-        if m >= 16 and platform == "tpu":
-            # metric-tiled pallas path (interpret mode is far too slow off
-            # TPU, and the pltpu lowering only targets TPU)
-            from loghisto_tpu.ops.pallas_multirow import make_multirow_ingest
-
-            try:
-                init, mingest, _ = make_multirow_ingest(
-                    m, cfg.bucket_limit, rows_tile=8
-                )
-                # the jitted ingest inlines when traced inside the loop;
-                # its accumulator is LANE-PADDED — init(), not the dense
-                # shape the other paths use
-                measure(m, "multirow", mingest, mingest, init(),
-                        (ids, values), make_acc=init)
-            except Exception as e:
-                results["errors"][f"multirow@{m}"] = repr(e)
-                print(f"{m:>6} {'multirow':>10} {'FAILED':>14}")
     return results
 
 

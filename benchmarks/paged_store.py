@@ -101,7 +101,7 @@ def _conserved_total(agg) -> int:
     if agg.paged is not None:
         _, _, counts = agg.paged.decode_cells(include_spill=True)
         return int(counts.sum())
-    total = int(np.asarray(agg._finalize_acc(agg._acc), dtype=np.int64).sum())
+    total = int(np.asarray(agg._acc, dtype=np.int64).sum())
     if agg._spill is not None:
         total += int(agg._spill.sum())
     return total

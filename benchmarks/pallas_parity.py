@@ -22,7 +22,6 @@ def main():
     from loghisto_tpu.config import MetricConfig
     from loghisto_tpu.ops.ingest import make_ingest_fn
     from loghisto_tpu.ops.pallas_kernels import SAMPLE_TILE, make_pallas_row_ingest
-    from loghisto_tpu.ops.pallas_multirow import make_multirow_ingest
 
     plat = jax.devices()[0].platform
     print(f"platform={plat} (interpret={'cpu' == plat})")
@@ -75,36 +74,6 @@ def main():
     else:
         bad = np.nonzero(ref != got)
         print(f"PARITY FAIL pallas_masked {bad[0].size} cells differ")
-        failures += 1
-
-    # --- multirow kernel vs scatter at several metric counts ---
-    for m in (16, 256, 1024):
-        ids = rng.integers(0, m, n).astype(np.int32)
-        ref = np.asarray(
-            scatter(jnp.zeros((m, cfg.num_buckets), jnp.int32), ids, values)
-        )
-        init, mingest, finalize = make_multirow_ingest(m, cfg.bucket_limit, rows_tile=8)
-        got = np.asarray(finalize(mingest(init(), ids, values)))
-        if np.array_equal(ref, got):
-            print(f"PARITY OK  multirow m={m:<5} sum={got.sum()}")
-        else:
-            bad = np.nonzero(ref != got)
-            print(f"PARITY FAIL multirow m={m} {bad[0].size} cells differ")
-            failures += 1
-
-    # --- two-step accumulation (revisit/aliasing risk, VERDICT item 2) ---
-    m = 64
-    ids = rng.integers(0, m, n).astype(np.int32)
-    ref = scatter(jnp.zeros((m, cfg.num_buckets), jnp.int32), ids, values)
-    ref = np.asarray(scatter(ref, ids[::-1].copy(), values))
-    init, mingest, finalize = make_multirow_ingest(m, cfg.bucket_limit, rows_tile=8)
-    acc = mingest(init(), ids, values)
-    acc = mingest(acc, ids[::-1].copy(), values)
-    got = np.asarray(finalize(acc))
-    if np.array_equal(ref, got):
-        print(f"PARITY OK  multirow-2step m={m} sum={got.sum()}")
-    else:
-        print("PARITY FAIL multirow-2step")
         failures += 1
 
     # --- r13 fused sample->scatter kernel vs scatter oracle ---

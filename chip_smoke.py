@@ -282,7 +282,7 @@ def run_system(w: Workload, seed: int, mesh=None, resilience=None,
             if snapshot_acc:
                 agg.flush(force=True)
                 with agg._dev_lock:
-                    accs.append(np.asarray(agg._finalize_acc(agg._acc)))
+                    accs.append(np.asarray(agg._acc))
             device_sets.append(ms.device_metrics(reset=True))
             channel_sets.append(pset)
             ref["fire"].append((ids, values))

@@ -94,7 +94,7 @@ print(f"  registry generation       : {reg.generation}")
 
 # count-exact overflow: every evicted sample is still counted, in the
 # per-prefix catch-all — nothing was lost to the churn
-acc = np.asarray(ms.aggregator._finalize_acc(ms.aggregator._acc))
+acc = np.asarray(ms.aggregator._acc)
 ovid = reg.lookup("_overflow.api")
 print("== lossless retirement ==")
 print(f"  samples ingested          : {total_samples}")

@@ -1,10 +1,10 @@
 """Automatic ingest/storage/commit path selection (VERDICT r1 item 6,
 unified capability table r17).
 
-Six bit-identical device accumulation kernels exist (scatter / sort-dedup
-scatter / scan-based sort-dedup ("sortscan") / one-hot MXU matmul /
-Pallas row / Pallas multirow, plus the hot-row hybrid); they differ
-only in speed per (num_metrics, num_buckets, platform) configuration.
+Four bit-identical dense accumulation kernels exist (scatter /
+sort-dedup scatter / Pallas row / fused sample->scatter), all reading
+and writing the same [M, B] int32 accumulator; they differ only in
+speed per (num_metrics, num_buckets, platform) configuration.
 ``TPUAggregator(ingest_path="auto")`` — the default — calls
 ``choose_ingest_path`` at construction (platform is known then; this is
 NOT a trace-time probe).
@@ -60,11 +60,6 @@ SORT_MIN_METRICS = 4096
 # ranks "pallasb" (analyze_capture.py flags the comparison).
 PALLAS_SINGLE_METRIC = True
 
-# Which sort-dedup formulation auto uses at high cardinality: "sort"
-# (jnp.unique-based) or "sortscan" (sort + reverse min-scan, 3x on CPU,
-# awaiting a hardware ranking).  Capture-overridable like the rest.
-HIGH_CARDINALITY_KERNEL = "sort"
-
 # Whether auto considers the r13 fused sample->scatter Pallas kernel
 # (ops/fused_ingest.py: codec on the VPU inside the kernel, one
 # dispatch, no HBM bucket-index array) at high metric cardinality on
@@ -105,12 +100,6 @@ FUSED_MIN_BATCH_BY_PLATFORM: Dict[str, int] = {}
 # fused_ingest.ROWS_TILE without importing jax (this module must stay
 # importable without jax — analyze_capture.py depends on that).
 FUSED_ROWS_TILE = 8
-
-# Dense one-hot matmul materializes an [N, B] one-hot per tile; the r2
-# table shows it never beating scatter on hardware at >=16 metrics, and
-# losing to the Pallas row kernel at M=1 — it remains available for
-# explicit selection but auto no longer picks it.
-MATMUL_MAX_CELLS = 1 << 21
 
 # Whether auto commits intervals through the fused single-dispatch
 # program (ops/commit.py: aggregator fold + all retention tiers in one
@@ -178,7 +167,7 @@ THRESHOLDS_SOURCE = "baked-in defaults"
 
 def _load_thresholds() -> None:
     global SORT_MIN_METRICS, PALLAS_SINGLE_METRIC, THRESHOLDS_SOURCE
-    global HIGH_CARDINALITY_KERNEL, FUSED_COMMIT
+    global FUSED_COMMIT
     global SPARSE_DENSITY_CROSSOVER, SPARSE_KERNEL
     global FUSED_INGEST, FUSED_MIN_BATCH, FUSED_MIN_BATCH_BY_PLATFORM
     global PAGED_STORAGE, PAGED_MIN_METRICS, FUSED_PAGED
@@ -197,10 +186,6 @@ def _load_thresholds() -> None:
     psm = table.get("pallas_single_metric")
     if isinstance(psm, bool):
         PALLAS_SINGLE_METRIC = psm
-        applied = True
-    hck = table.get("high_cardinality_kernel")
-    if hck in ("sort", "sortscan"):
-        HIGH_CARDINALITY_KERNEL = hck
         applied = True
     fc = table.get("fused_commit")
     if isinstance(fc, bool):
@@ -561,9 +546,8 @@ CAPABILITY_TABLE: Dict[Tuple[str, str], Tuple[CapabilityEdge, ...]] = {
 }
 
 # The single degradation order per axis — the ladder every "auto"
-# resolution walks, most-capable contender first.  (The ingest ladder's
-# sort entry is HIGH_CARDINALITY_KERNEL at resolve time; "scatter" is
-# the unconditional floor on every axis where it appears.)
+# resolution walks, most-capable contender first.  ("scatter" is the
+# unconditional floor on every axis where it appears.)
 DEGRADATION_ORDER: Dict[str, Tuple[str, ...]] = {
     "ingest": ("fused_paged", "fused", "sort", "scatter"),
     "storage": ("paged", "dense"),
@@ -709,16 +693,13 @@ def choose_ingest_path(
 ) -> str:
     """Pick the measured-fastest ingest kernel for a configuration.
 
-    The Pallas multirow kernel stays opt-in: it was never the fastest
-    in the ranking the thresholds come from, so "auto" does not select
-    it.  The Pallas row
-    kernel (winner at M=1) participates via its masked
+    The Pallas row kernel (winner at M=1) participates via its masked
     pallas_row_ingest_batch form, which has the standard (ids, values)
     contract (see PALLAS_SINGLE_METRIC note on the extrapolation).  At
     high cardinality on TPU the r13 fused sample->scatter kernel is the
     preferred pick (one dispatch, codec on-chip); resolve_ingest_path
-    degrades it to HIGH_CARDINALITY_KERNEL when
-    ``fused_ingest_incapability`` names a blocker.
+    degrades it to "sort" when ``fused_ingest_incapability`` names a
+    blocker.
     """
     if platform == "tpu" and num_metrics == 1 and PALLAS_SINGLE_METRIC:
         # the fused Pallas row kernel wins the single-metric config
@@ -726,9 +707,7 @@ def choose_ingest_path(
         # makes it contract-compatible with the other paths
         return "pallas"
     if platform == "tpu" and num_metrics >= SORT_MIN_METRICS:
-        if FUSED_INGEST:
-            return "fused"
-        return HIGH_CARDINALITY_KERNEL
+        return "fused" if FUSED_INGEST else "sort"
     return "scatter"
 
 
@@ -747,14 +726,14 @@ def resolve_ingest_path(
     default.  Auto never picks a kernel the shape invalidates (falls back
     to scatter), while an EXPLICIT choice the shape cannot support raises
     here — at selection time — instead of silently corrupting histograms
-    inside the traced kernel (the sort and matmul paths' combined int32
-    cell keys wrap negative past 2^31 cells).
+    inside the traced kernel (the sort path's combined int32 cell keys
+    wrap negative past 2^31 cells).
 
     ``guard_metrics`` is the row count to validate shapes against when it
     exceeds ``num_metrics`` — TPUAggregator passes its growth cap
     (max_metrics) so auto cannot pick a kernel that registry growth would
-    later invalidate.  ``batch_size``, when known, guards hybrid's
-    float32 hot-head exactness bound (per-batch counts < 2^24); auto
+    later invalidate.  ``batch_size``, when known, guards the Pallas row
+    kernel's float32 exactness bound (per-batch counts < 2^24); auto
     refuses to pick "pallas" when the bound is UNKNOWN (batch_size=None)
     — the precondition would otherwise surface as a trace-time raise
     inside a shard_map step (ADVICE r2).  ``mesh=True`` marks a
@@ -774,10 +753,10 @@ def resolve_ingest_path(
         ) is not None:
             # degrade to the pre-r13 high-cardinality winner, which then
             # takes its own shape validation below
-            path = HIGH_CARDINALITY_KERNEL
-        if path in ("sort", "sortscan"):
+            path = "sort"
+        if path == "sort":
             try:
-                validate_flat_cell_shape(guard, num_buckets, path)
+                validate_flat_cell_shape(guard, num_buckets)
             except ValueError:
                 path = "scatter"
         elif path == "pallas" and (
@@ -798,11 +777,11 @@ def resolve_ingest_path(
         )
         if reason is not None:
             raise ValueError(f"fused ingest unavailable: {reason}")
-    if path in ("sort", "sortscan", "matmul"):
-        validate_flat_cell_shape(guard, num_buckets, path)
-    elif path in ("hybrid", "pallas") and batch_too_big:
+    if path == "sort":
+        validate_flat_cell_shape(guard, num_buckets)
+    elif path == "pallas" and batch_too_big:
         raise ValueError(
-            f"{path} ingest batches must stay < 2^24 samples (float32 "
+            "pallas ingest batches must stay < 2^24 samples (float32 "
             f"accumulation exactness); got batch_size={batch_size}"
         )
     if path == "pallas" and num_metrics != 1:
@@ -1050,9 +1029,8 @@ def resolve_full_path(
 def ingest_step_fn(path: str):
     """The pure per-batch accumulation function for a named path, with the
     uniform ``f(acc, ids, values, bucket_limit, precision) -> acc``
-    contract (scatter / sort / sortscan / hybrid / matmul / pallas — the
-    paths whose dense accumulator layout is interchangeable; pallas
-    additionally requires acc shape [1, B]).  Used wherever a traced step
+    contract (scatter / sort / pallas / fused, all on the one [M, B]
+    accumulator layout; pallas additionally requires acc shape [1, B]).  Used wherever a traced step
     needs the dispatched kernel inline (firehose generation loop, bench
     interval loop) rather than the TPUAggregator's jitted wrappers.
     The r17 "fused_paged" contender is NOT here: its accumulator is the
@@ -1062,18 +1040,6 @@ def ingest_step_fn(path: str):
         from loghisto_tpu.ops.sort_ingest import sort_ingest_batch
 
         return sort_ingest_batch
-    if path == "sortscan":
-        from loghisto_tpu.ops.sort_ingest import sortscan_ingest_batch
-
-        return sortscan_ingest_batch
-    if path == "hybrid":
-        from loghisto_tpu.ops.hybrid_hist import ingest_batch_hybrid
-
-        return ingest_batch_hybrid
-    if path == "matmul":
-        from loghisto_tpu.ops.matmul_hist import ingest_batch_matmul
-
-        return ingest_batch_matmul
     if path == "pallas":
         from loghisto_tpu.ops.pallas_kernels import pallas_row_ingest_batch
 
@@ -1085,8 +1051,7 @@ def ingest_step_fn(path: str):
     if path != "scatter":
         raise ValueError(
             f"no pure step form for ingest_path {path!r}: expected "
-            "'scatter', 'sort', 'sortscan', 'hybrid', 'matmul', "
-            "'pallas', or 'fused'"
+            "'scatter', 'sort', 'pallas', or 'fused'"
         )
     from loghisto_tpu.ops.ingest import ingest_batch
 

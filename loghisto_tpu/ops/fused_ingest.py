@@ -1,9 +1,9 @@
 """Fused sample->scatter Pallas TPU ingest: raw values + metric ids to
 dense [M, B] int32 accumulator in ONE device dispatch.
 
-Every prior multi-metric path splits the work in two: a compress stage
-that materializes a bucket-index array in HBM, then a scatter (or
-one-hot matmul) stage that consumes it.  The circllhist observation
+The other multi-metric paths split the work in two: a compress stage
+that materializes a bucket-index array in HBM, then a scatter stage
+that consumes it.  The circllhist observation
 (PAPERS.md) is that log-linear bucket selection is pure bit/exponent
 arithmetic — VPU work that belongs in the SAME kernel as the
 accumulate, like SNIPPETS.md [2]'s histogram kernel which avoids
@@ -13,9 +13,10 @@ pipeline:
   1. (XLA preprocess, all static shapes, fused into the same jitted
      program) group samples by *metric row block* (rows_tile consecutive
      rows) with one sort, and lay the RAW values out so every
-     SAMPLE_TILE-sized tile holds samples of exactly one block — the
-     ``pallas_multirow.py`` tiling idiom, except no bucket index is ever
-     computed here: the layout carries float32 values, not bucket ids.
+     SAMPLE_TILE-sized tile holds samples of exactly one block (a
+     metric-tiled layout: each tile routes to one accumulator block),
+     and no bucket index is ever computed here: the layout carries
+     float32 values, not bucket ids.
   2. (Pallas kernel) grid over sample tiles routed by a
      scalar-prefetched ``tile_block`` map.  Each tile compresses its
      values on the VPU (``bucket_indices`` — the same codec function as
@@ -23,12 +24,13 @@ pipeline:
      one-hots in VMEM, and accumulates a [rows_tile*H, 128] matmul on
      the MXU straight into the aliased accumulator block.
 
-Compared to the multirow kernel this (a) moves the codec on-chip — the
-bucket-index array never exists in HBM — and (b) drops the lane-padded
-accumulator layout: the acc block is (rows_tile, B) with B equal to the
-array's own minor dim, which Mosaic accepts (a block dim may equal the
-array dim instead of being 8/128-divisible), so the kernel aliases the
-product's [M, B] accumulator directly and plugs into the uniform
+Compared to a metric-tiled kernel over precomputed bucket indices this
+(a) moves the codec on-chip — the bucket-index array never exists in
+HBM — and (b) needs no lane-padded accumulator layout: the acc block is
+(rows_tile, B) with B equal to the array's own minor dim, which Mosaic
+accepts (a block dim may equal the array dim instead of being
+8/128-divisible), so the kernel aliases the product's [M, B]
+accumulator directly and plugs into the uniform
 ``f(acc, ids, values, bucket_limit, precision)`` dispatch contract.
 
 Exactness contract (same as every other path): per-tile f32 one-hot
@@ -100,7 +102,7 @@ from loghisto_tpu.ops.paged_store import (
 from loghisto_tpu.ops.pallas_kernels import LANES, SAMPLE_TILE
 
 # Metric rows per accumulator block resident in VMEM.  8 matches the
-# multirow kernel (and the sublane tile), keeps the one-hot column space
+# sublane tile, keeps the one-hot column space
 # rows_tile*H narrow enough for VMEM at 8k buckets, and is what
 # TPUAggregator._grow_row_unit preserves under registry growth.
 ROWS_TILE = 8
@@ -189,7 +191,7 @@ def _kernel(tile_block_ref, rows_ref, vals_ref, acc_in_ref, acc_out_ref, *,
 
     # Consecutive tiles of one block keep the output block resident; the
     # aliased INPUT block may be re-fetched stale on revisits, so it is
-    # only read on the block's first tile (see pallas_multirow._kernel).
+    # only read on the block's first tile.
     first_visit = jnp.logical_or(
         i == 0, tile_block_ref[i] != tile_block_ref[jnp.maximum(i - 1, 0)]
     )
@@ -245,8 +247,8 @@ def fused_ingest_batch(
         num_scalar_prefetch=1,
         grid=(g,),
         in_specs=[
-            # lane-axis grid over [1, G*T] layouts (see pallas_multirow:
-            # Mosaic rejects block [1, T] on a [G, T] array)
+            # lane-axis grid over [1, G*T] layouts (Mosaic rejects
+            # block [1, T] on a [G, T] array)
             pl.BlockSpec((1, SAMPLE_TILE), lambda i, tb: (0, i)),
             pl.BlockSpec((1, SAMPLE_TILE), lambda i, tb: (0, i)),
             # acc block minor dim == array minor dim: legal without lane

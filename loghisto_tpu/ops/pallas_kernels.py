@@ -1,9 +1,9 @@
 """Pallas TPU kernel: fused compress -> one-hot -> MXU-accumulate
 histogram for a single metric row.
 
-The XLA variant (ops/matmul_hist.py) materializes the two one-hot
-matrices [N, H] and [N, 128] in HBM between fusion boundaries; this kernel
-keeps everything on-chip: each grid step loads one sample tile into VMEM,
+An XLA one-hot matmul would materialize the two one-hot matrices
+[N, H] and [N, 128] in HBM between fusion boundaries; this kernel keeps
+everything on-chip: each grid step loads one sample tile into VMEM,
 compresses it on the VPU, forms the one-hots in registers/VMEM, runs the
 [H, T] x [T, 128] matmul on the MXU into a float32 VMEM scratch
 accumulator, and only on the last step adds the scratch into the int32

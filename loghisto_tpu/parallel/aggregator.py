@@ -209,8 +209,8 @@ def local_histogram_fold(
     stream axis, and fold into the accumulator.  Must run inside
     shard_map on a ("stream", "metric") mesh.
 
-    ``ingest_path`` names a CONCRETE per-batch kernel ("scatter", "sort",
-    "hybrid", "matmul" — resolve "auto" outside the traced region): the
+    ``ingest_path`` names a CONCRETE per-batch kernel ("scatter" or
+    "sort" — resolve "auto" outside the traced region): the
     duplicate-serialization economics that drive single-chip dispatch
     apply unchanged to the per-device local fold (a Zipf stream
     concentrates each shard's in-range samples on its hot rows), so the
@@ -523,14 +523,16 @@ class TPUAggregator:
           * "auto"     — (default) pick the measured-fastest kernel for
             (num_metrics, num_buckets, platform) via ops/dispatch.py
           * "scatter"  — XLA scatter-add (works everywhere)
-          * "matmul"   — one-hot MXU matmul (small metric counts)
           * "sort"     — sort-deduplicated conflict-free scatter
             (ops/sort_ingest.py; built for TPU scatter semantics)
-          * "multirow" — metric-tiled Pallas kernel (sorted/block-padded;
-            single-device only, TPU-targeted, interpret-mode elsewhere)
-        All three are bit-identical (tests/test_fast_paths.py,
-        tests/test_pallas_multirow.py); they differ only in speed per
-        configuration — benchmarks/device_paths.py measures them.
+          * "pallas"   — the single-metric Pallas row kernel (starting
+            shape [1, B]; growth past one row swaps to the auto pick)
+          * "fused"    — the sample->scatter Pallas kernel with the codec
+            on the VPU (ops/fused_ingest.py)
+        All four read and write the same [M, B] int32 accumulator and are
+        bit-identical (tests/test_fast_paths.py,
+        tests/test_fused_ingest.py); they differ only in speed per
+        configuration.
 
         `on_registry_full` defines the name-cardinality policy when a new
         name arrives with the registry at capacity (the reference admits
@@ -739,7 +741,7 @@ class TPUAggregator:
                 "checks could wrap an int32 cell"
             )
         self.spill_threshold = int(spill_threshold)
-        if ingest_path in ("sort", "sortscan", "matmul", "hybrid", "pallas"):
+        if ingest_path in ("sort", "pallas"):
             # validate explicit choices BEFORE the accumulator allocation
             # below — the combined-key bound failing after a multi-GB
             # jnp.zeros is a worse failure mode than a raise inside the
@@ -858,12 +860,6 @@ class TPUAggregator:
         if self.storage == "paged":
             from loghisto_tpu.paging import PagedStore, PagedStoreConfig
 
-            if ingest_path == "multirow":
-                raise ValueError(
-                    "ingest_path='multirow' needs the dense lane-padded "
-                    "accumulator; paged storage keeps none (every paged "
-                    "commit rides the packed sparse-triple scatter)"
-                )
             # r18: a mesh shards the store itself — per-shard page
             # arenas, shard-local translate/scatter inside one
             # shard_map (the capability table's relaxed "mesh shape:"
@@ -887,14 +883,8 @@ class TPUAggregator:
                     "direct-to-paged fused kernel: "
                     f"{self.fused_paged_reason}"
                 )
-        elif mesh is not None:
-            self._acc = make_sharded_accumulator(
-                mesh, num_metrics, config.num_buckets
-            )
         else:
-            self._acc = jnp.zeros(
-                (num_metrics, config.num_buckets), dtype=jnp.int32
-            )
+            self._acc = self._fresh_dense_acc()
         if ingest_path == "auto":
             platform = (
                 mesh.devices.flat[0].platform
@@ -909,24 +899,11 @@ class TPUAggregator:
                 guard_metrics=self.max_metrics, batch_size=batch_size,
                 mesh=mesh is not None,
             )
-        # identity for dense-layout paths; multirow slices its lane padding
+        # the accumulator has one layout on every path; benchmark/harness.py
+        # still reads this identity
         self._finalize_acc = lambda a: a
-        # per-path zero-accumulator factory (layout differs by path)
-        self._make_acc = self._fresh_dense_acc
         if ingest_path == "scatter":
             self._ingest = make_ingest_fn(
-                config.bucket_limit, config.precision
-            )
-        elif ingest_path == "matmul":
-            from loghisto_tpu.ops.matmul_hist import make_matmul_ingest_fn
-
-            self._ingest = make_matmul_ingest_fn(
-                config.bucket_limit, config.precision
-            )
-        elif ingest_path == "hybrid":
-            from loghisto_tpu.ops.hybrid_hist import make_hybrid_ingest_fn
-
-            self._ingest = make_hybrid_ingest_fn(
                 config.bucket_limit, config.precision
             )
         elif ingest_path == "sort":
@@ -934,12 +911,6 @@ class TPUAggregator:
             from loghisto_tpu.ops.sort_ingest import make_sort_ingest_fn
 
             self._ingest = make_sort_ingest_fn(
-                config.bucket_limit, config.precision
-            )
-        elif ingest_path == "sortscan":
-            from loghisto_tpu.ops.sort_ingest import make_sortscan_ingest_fn
-
-            self._ingest = make_sortscan_ingest_fn(
                 config.bucket_limit, config.precision
             )
         elif ingest_path == "pallas":
@@ -957,33 +928,16 @@ class TPUAggregator:
             if reason is not None:
                 raise ValueError(f"ingest_path='fused': {reason}")
             self._ingest = self._make_dense_step_fn("fused")
-        elif ingest_path == "fused_paged":
+        elif ingest_path == "fused_paged" and self.fused_paged:
             # direct-to-paged fused kernel (r17): dispatches run through
             # PagedStore.ingest_raw inside _dispatch_slot_locked — the
             # donated pool is the accumulator, so there is no dense
             # f(acc, ids, values) step fn to build here
             self._ingest = None
-        elif ingest_path == "multirow":
-            if mesh is not None:
-                raise ValueError(
-                    "ingest_path='multirow' is single-device (its dense "
-                    "layout is lane-padded); use scatter with a mesh"
-                )
-            from loghisto_tpu.ops.pallas_multirow import make_multirow_ingest
-
-            init, multirow_ingest, self._finalize_acc = make_multirow_ingest(
-                num_metrics, config.bucket_limit, config.precision
-            )
-            self._ingest = multirow_ingest
-            # lane-padded accumulator layout; the weighted host-bridge
-            # ingest still works (dense buckets are the leading columns)
-            self._make_acc = init
-            self._acc = init()
         else:
             raise ValueError(
                 f"unknown ingest_path {ingest_path!r}: expected 'auto', "
-                "'scatter', 'matmul', 'sort', 'sortscan', 'hybrid', "
-                "'fused', or 'multirow'"
+                "'scatter', 'sort', 'pallas', or 'fused'"
             )
         self.ingest_path = ingest_path
         self._weighted_ingest = make_weighted_ingest_fn(config.bucket_limit)
@@ -1173,11 +1127,9 @@ class TPUAggregator:
 
     def _grow_row_unit(self) -> int:
         """Row-count granularity growth must preserve: the mesh metric
-        axis (shard divisibility) or the multirow kernel's row tile."""
+        axis (shard divisibility) or the fused kernel's row tile."""
         if self.mesh is not None:
             return self.mesh.shape[METRIC_AXIS]
-        if self.ingest_path == "multirow":
-            return 8  # make_multirow_ingest's rows_tile default
         if self.ingest_path == "fused":
             return 8  # fused_ingest.ROWS_TILE: M must stay tile-divisible
         return 1
@@ -1185,8 +1137,8 @@ class TPUAggregator:
     def _grow_locked(self, target: Optional[int] = None) -> bool:
         """Grow the metric-row space in place (caller holds _dev_lock): pad
         the accumulator (and spill) with zero rows, re-shard in mesh mode,
-        rebuild the shape-specialized multirow kernel (caller holds
-        _dev_lock — growth mutates device state).  Returns False when
+        swap the single-row pallas kernel for the grown shape's (caller
+        holds _dev_lock — growth mutates device state).  Returns False when
         no growth is possible (max_metrics reached, or the divisibility
         unit leaves no room).  All fallible work happens BEFORE any state
         is committed, so a failed grow leaves the aggregator untouched.
@@ -1208,17 +1160,8 @@ class TPUAggregator:
             self.registry.grow(new_m)
             return True
         # -- fallible section: build everything in locals first --
-        make_acc, ingest, finalize = (
-            self._make_acc, self._ingest, self._finalize_acc
-        )
-        new_path = self.ingest_path
-        if self.ingest_path == "multirow":
-            from loghisto_tpu.ops.pallas_multirow import make_multirow_ingest
-
-            make_acc, ingest, finalize = make_multirow_ingest(
-                new_m, self.config.bucket_limit, self.config.precision
-            )
-        elif self.ingest_path == "pallas":
+        ingest, new_path = self._ingest, self.ingest_path
+        if self.ingest_path == "pallas":
             # the single-row kernel cannot cover more rows; swap to the
             # auto-dispatched dense-family kernel for the grown shape
             # (same [*, B] layout, so the data moves unchanged)
@@ -1243,9 +1186,7 @@ class TPUAggregator:
         else:
             new_acc = jnp.asarray(grown)
         # -- commit --
-        self._make_acc, self._ingest, self._finalize_acc = (
-            make_acc, ingest, finalize
-        )
+        self._ingest = ingest
         self.ingest_path = new_path
         self._acc = new_acc
         self._delta = None  # settled above; the next merge sizes it anew
@@ -1278,14 +1219,12 @@ class TPUAggregator:
             self._interval_ingested = 0
             self.stats_snapshot = None
             return
-        acc_np = np.asarray(
-            self._finalize_acc(self._dense_acc_locked()), dtype=np.int64
-        )
+        acc_np = np.asarray(self._dense_acc_locked(), dtype=np.int64)
         if self._spill is None:
             self._spill = acc_np
         else:
             self._spill += acc_np
-        self._acc = self._fresh_acc()
+        self._acc = self._fresh_dense_acc()
         self._spilled_samples += self._interval_ingested
         self._interval_ingested = 0
         self.stats_snapshot = None  # acc folded out; handle is stale
@@ -1337,12 +1276,6 @@ class TPUAggregator:
         return jnp.zeros(
             (self.num_metrics, self.config.num_buckets), dtype=jnp.int32
         )
-
-    def _fresh_acc(self) -> jnp.ndarray:
-        """Zero accumulator in THIS ingest path's layout (the multirow
-        path is lane-padded; rebuilding the wrong shape after a device
-        failure would permanently break ingestion)."""
-        return self._make_acc()
 
     def _buffered_samples(self) -> int:
         """Samples currently buffered on host awaiting a device attempt
@@ -2133,7 +2066,7 @@ class TPUAggregator:
                 self._shed_samples += lost
             self._interval_ingested -= lost
             if acc_lost:
-                self._acc = self._fresh_acc()
+                self._acc = self._fresh_dense_acc()
             self._delta = None
             self._delta_samples = 0
         if self.paged is not None and self.paged.pool_deleted():
@@ -2453,9 +2386,7 @@ class TPUAggregator:
             if spill is not None:
                 # overflow-spill interval: counts exceed int32 on device,
                 # so the whole extraction runs in exact int64 on host
-                combined = spill + np.asarray(
-                    self._finalize_acc(acc), dtype=np.int64
-                )
+                combined = spill + np.asarray(acc, dtype=np.int64)
                 stats = dense_stats_np(
                     combined,
                     np.asarray(ps, dtype=np.float64),
@@ -2463,10 +2394,7 @@ class TPUAggregator:
                     self.config.precision,
                 )
             else:
-                stats = self._stats_fn(
-                    self._finalize_acc(acc),
-                    np.asarray(ps, dtype=np.float32),
-                )
+                stats = self._stats_fn(acc, np.asarray(ps, dtype=np.float32))
         counts = np.asarray(stats["counts"])
         sums = np.asarray(stats["sums"])
         pcts = np.asarray(stats["percentiles"])

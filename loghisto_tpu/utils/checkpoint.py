@@ -117,12 +117,9 @@ def save(
                     aggregator.paged.codec_names()
                 )
             else:
-                # canonical dense layout: snapshots stay portable across
-                # ingest_path choices (multirow's lane padding is
-                # stripped)
-                acc = np.asarray(
-                    aggregator._finalize_acc(aggregator._dense_acc_locked())
-                )
+                # canonical dense layout: every ingest_path shares it, so
+                # snapshots stay portable across them
+                acc = np.asarray(aggregator._dense_acc_locked())
                 # a spilled interval keeps part of its counts in the
                 # host int64 fold — snapshotting only the device tensor
                 # would silently lose them; the combined snapshot is
@@ -417,7 +414,7 @@ def _restore_dense_delta(aggregator, remapped: np.ndarray) -> None:
     import jax.numpy as jnp
 
     live = aggregator._dense_acc_locked()
-    live_max = int(jnp.max(aggregator._finalize_acc(live)))
+    live_max = int(jnp.max(live))
     if (
         int(remapped.max(initial=0))
         + live_max
@@ -429,20 +426,10 @@ def _restore_dense_delta(aggregator, remapped: np.ndarray) -> None:
         else:
             aggregator._spill += remapped.astype(np.int64)
     else:
-        live_cols = live.shape[1]
-        dense = remapped.astype(np.int32)
-        if live_cols != dense.shape[1]:
-            # re-pad the canonical dense rows into the live
-            # (lane-padded) layout
-            padded = np.zeros(
-                (aggregator.num_metrics, live_cols), dtype=np.int32
-            )
-            padded[:, :dense.shape[1]] = dense
-            dense = padded
         # re-shard the host rows onto the live accumulator's layout
         # first: checkpoints save gathered host arrays, so a snapshot
         # taken on one mesh shape restores onto any other (or none)
-        delta = jnp.asarray(dense)
+        delta = jnp.asarray(remapped.astype(np.int32))
         live_sharding = getattr(live, "sharding", None)
         if (
             getattr(aggregator, "mesh", None) is not None

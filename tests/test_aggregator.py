@@ -148,7 +148,7 @@ def test_registry_full():
         strict.record("c", 1.0)
 
 
-@pytest.mark.parametrize("path", ["scatter", "matmul", "hybrid", "multirow"])
+@pytest.mark.parametrize("path", ["scatter", "sort", "fused"])
 def test_ingest_paths_agree(path):
     agg = TPUAggregator(num_metrics=8, config=CFG, ingest_path=path)
     rng = np.random.default_rng(7)
@@ -173,25 +173,41 @@ def test_ingest_path_validation():
 
     from loghisto_tpu.parallel.mesh import make_mesh
 
-    with pytest.raises(ValueError):
-        TPUAggregator(num_metrics=8, config=CFG, ingest_path="warp-drive")
-    with pytest.raises(ValueError):
+    # exactly five values construct (pallas from a single-metric shape)
+    for path in ("auto", "scatter", "sort", "fused"):
+        TPUAggregator(num_metrics=8, config=CFG, ingest_path=path).close()
+    TPUAggregator(num_metrics=1, config=CFG, ingest_path="pallas").close()
+    # every other value is refused, with the five named
+    expected = "'auto', 'scatter', 'sort', 'pallas', or 'fused'"
+    for path in ("warp-drive", "matmul", "hybrid", "multirow", "fused_paged"):
+        with pytest.raises(ValueError, match=expected):
+            TPUAggregator(num_metrics=8, config=CFG, ingest_path=path)
+    with pytest.raises(ValueError, match=expected):
         TPUAggregator(
             num_metrics=8, config=CFG, ingest_path="multirow",
             mesh=make_mesh(stream=4, metric=2, devices=jax.devices()[:8]),
         )
 
 
-def test_multirow_path_checkpoint_roundtrip(tmp_path):
+@pytest.mark.parametrize("path", ["sort", "fused", "pallas"])
+def test_ingest_path_checkpoint_roundtrip(tmp_path, path):
     from loghisto_tpu.utils import checkpoint
 
-    agg = TPUAggregator(num_metrics=8, config=CFG, ingest_path="multirow")
+    m = 1 if path == "pallas" else 8
+    agg = TPUAggregator(num_metrics=m, config=CFG, ingest_path=path)
     agg.record("m", 5.0)
-    path = str(tmp_path / "m.npz")
-    checkpoint.save(path, aggregator=agg)
-    fresh = TPUAggregator(num_metrics=8, config=CFG, ingest_path="multirow")
-    checkpoint.restore(path, aggregator=fresh)
-    assert fresh.collect().metrics["m_count"] == 1
+    agg.record_batch(
+        np.zeros(3000, np.int32), np.full(3000, 7.0, np.float32)
+    )
+    file = str(tmp_path / "m.npz")
+    checkpoint.save(file, aggregator=agg)
+    fresh = TPUAggregator(num_metrics=m, config=CFG, ingest_path=path)
+    checkpoint.restore(file, aggregator=fresh)
+    # restored counts merge with what the restored aggregator ingests
+    fresh.record("m", 5.0)
+    assert fresh.collect().metrics["m_count"] == 3002
+    agg.close()
+    fresh.close()
 
 
 def test_mesh_mode_matches_single_device():
@@ -281,7 +297,7 @@ def test_preagg_transport_bit_parity_with_raw():
             agg.registry.id_for(f"m{name_id}")
         agg.record_batch(ids, values)
         agg.flush(force=True)
-        outs[transport] = np.asarray(agg._finalize_acc(agg._acc))
+        outs[transport] = np.asarray(agg._acc)
     np.testing.assert_array_equal(outs["raw"], outs["preagg"])
 
 
@@ -310,7 +326,7 @@ def test_preagg_transport_exact_beyond_int16_ids():
         )
         agg.record_batch(ids, values)
         agg.flush(force=True)
-        outs[transport] = np.asarray(agg._finalize_acc(agg._acc))
+        outs[transport] = np.asarray(agg._acc)
     np.testing.assert_array_equal(outs["raw"], outs["preagg"])
     # every sample landed (nothing silently dropped by id truncation)
     assert int(outs["preagg"].sum()) == n
@@ -339,7 +355,7 @@ def test_preagg_boundary_values_conserve_counts():
         agg.record_batch(ids, values)
         agg.flush(force=True)
         outs[transport] = np.asarray(
-            agg._finalize_acc(agg._acc), dtype=np.int64
+            agg._acc, dtype=np.int64
         )
     a, b = outs["raw"], outs["preagg"]
     # per-metric totals exact — no sample lost or duplicated by tier

@@ -108,20 +108,24 @@ def test_go_compat_roundtrip_survives_restart(tmp_path):
 def test_checkpoint_portable_across_ingest_paths(tmp_path):
     from loghisto_tpu.parallel.aggregator import TPUAggregator
 
-    src = TPUAggregator(num_metrics=8, config=CFG, ingest_path="multirow")
+    src = TPUAggregator(num_metrics=8, config=CFG, ingest_path="sort")
     src.record("m", 5.0)
     path = str(tmp_path / "x.npz")
     checkpoint.save(path, aggregator=src)
-    # restore into a scatter-path aggregator (different acc layout)
-    dst = TPUAggregator(num_metrics=8, config=CFG, ingest_path="scatter")
+    # restore into an aggregator running another kernel
+    dst = TPUAggregator(num_metrics=8, config=CFG, ingest_path="fused")
     checkpoint.restore(path, aggregator=dst)
     assert dst.collect().metrics["m_count"] == 1
 
 
-def test_multirow_device_failure_rebuilds_right_layout():
+@pytest.mark.parametrize("path", ["sort", "fused", "pallas"])
+def test_device_failure_rebuilds_accumulator(path):
     from loghisto_tpu.parallel.aggregator import TPUAggregator
 
-    agg = TPUAggregator(num_metrics=8, config=CFG, ingest_path="multirow")
+    agg = TPUAggregator(
+        num_metrics=1 if path == "pallas" else 8, config=CFG,
+        ingest_path=path,
+    )
     agg.retry_cooldown = 0.0
     agg.registry.id_for("m")
     real = agg._ingest
@@ -139,9 +143,12 @@ def test_multirow_device_failure_rebuilds_right_layout():
     agg.record_batch(
         np.zeros(10, dtype=np.int32), np.full(10, 5.0, dtype=np.float32)
     )
-    agg.flush()  # fails; if the acc were deleted it must rebuild PADDED
+    agg.flush()  # fails; if the acc was lost it is rebuilt [M, B]
     out = agg.collect().metrics
     assert out["m_count"] == 10
+    assert calls[0] == 2  # the failed dispatch, then the retry
+    assert agg.ingest_path == path
+    assert agg._acc.shape == (agg.num_metrics, CFG.num_buckets)
 
 
 def test_atomic_write_leaves_no_tmp(tmp_path):
@@ -281,7 +288,7 @@ def test_lifecycle_roundtrip_generation_and_overflow(tmp_path):
     assert lc2.evictions == 1 and lc2.compactions == 0
     assert reg2.lookup("api.b") is None  # the hole did not resurrect
 
-    acc2 = np.asarray(agg2._finalize_acc(agg2._acc))
+    acc2 = np.asarray(agg2._acc)
     ovid = reg2.lookup("_overflow.api")
     assert ovid is not None and int(acc2[ovid].sum()) == 3
     # total conservation across save/restore: 5+3+2+1 samples

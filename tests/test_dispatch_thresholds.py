@@ -15,7 +15,6 @@ def restore_dispatch_globals():
     saved = (
         dispatch.SORT_MIN_METRICS,
         dispatch.PALLAS_SINGLE_METRIC,
-        dispatch.HIGH_CARDINALITY_KERNEL,
         dispatch.SPARSE_DENSITY_CROSSOVER,
         dispatch.SPARSE_KERNEL,
         dispatch.FUSED_INGEST,
@@ -31,7 +30,6 @@ def restore_dispatch_globals():
     (
         dispatch.SORT_MIN_METRICS,
         dispatch.PALLAS_SINGLE_METRIC,
-        dispatch.HIGH_CARDINALITY_KERNEL,
         dispatch.SPARSE_DENSITY_CROSSOVER,
         dispatch.SPARSE_KERNEL,
         dispatch.FUSED_INGEST,
@@ -51,10 +49,9 @@ def test_thresholds_file_overrides_baked_constants(
     table = {
         "source": "TPU_CAPTURE_test",
         "sort_min_metrics": 512,
-        "high_cardinality_kernel": "sortscan",
         "pallas_single_metric": False,
         # a capture that ranks the fused kernel slower pins it off — the
-        # sortscan assertions below depend on that (otherwise choose
+        # sort assertions below depend on that (otherwise choose
         # returns "fused" at >= sort_min_metrics on TPU)
         "fused_ingest": False,
     }
@@ -67,13 +64,13 @@ def test_thresholds_file_overrides_baked_constants(
     assert dispatch.THRESHOLDS_SOURCE == "TPU_CAPTURE_test"
     # the policy immediately reflects the overrides
     assert dispatch.choose_ingest_path(1, 8193, "tpu") == "scatter"
-    assert dispatch.choose_ingest_path(600, 8193, "tpu") == "sortscan"
+    assert dispatch.choose_ingest_path(600, 8193, "tpu") == "sort"
     assert dispatch.choose_ingest_path(256, 8193, "tpu") == "scatter"
-    # auto resolve validates the overridden sortscan like any sort-family
-    # pick (falls back to scatter past the int32 cell-key wrap)
+    # auto resolve validates the sort pick below the overridden crossover
+    # (falls back to scatter past the int32 cell-key wrap)
     assert dispatch.resolve_ingest_path(
         "auto", 600, 8193, "tpu"
-    ) == "sortscan"
+    ) == "sort"
     assert dispatch.resolve_ingest_path(
         "auto", 300_000, 8193, "tpu"
     ) == "scatter"
@@ -82,8 +79,7 @@ def test_thresholds_file_overrides_baked_constants(
 def test_malformed_or_missing_thresholds_file_is_ignored(
     tmp_path, restore_dispatch_globals
 ):
-    before = (dispatch.SORT_MIN_METRICS, dispatch.PALLAS_SINGLE_METRIC,
-              dispatch.HIGH_CARDINALITY_KERNEL)
+    before = (dispatch.SORT_MIN_METRICS, dispatch.PALLAS_SINGLE_METRIC)
     dispatch.THRESHOLDS_FILE = str(tmp_path / "missing.json")
     dispatch._load_thresholds()
     bad = tmp_path / "bad.json"
@@ -94,12 +90,11 @@ def test_malformed_or_missing_thresholds_file_is_ignored(
     wrong = tmp_path / "wrong.json"
     wrong.write_text(json.dumps({
         "sort_min_metrics": "many", "pallas_single_metric": "yes",
-        "high_cardinality_kernel": "quantum",
     }))
     dispatch.THRESHOLDS_FILE = str(wrong)
     dispatch._load_thresholds()
-    assert (dispatch.SORT_MIN_METRICS, dispatch.PALLAS_SINGLE_METRIC,
-            dispatch.HIGH_CARDINALITY_KERNEL) == before
+    assert (dispatch.SORT_MIN_METRICS,
+            dispatch.PALLAS_SINGLE_METRIC) == before
 
 
 def test_transport_crossover_overrides(tmp_path, restore_dispatch_globals):
@@ -192,7 +187,6 @@ def test_derive_thresholds_from_r2_shaped_table():
         10_000: ["sort", "scatter"],
     })
     assert t["pallas_single_metric"] is True
-    assert t["high_cardinality_kernel"] == "sort"
     # geometric midpoint of the 256..10000 bracket
     assert 256 < t["sort_min_metrics"] < 10_000
     assert t["sort_min_metrics"] == round((256 * 10_000) ** 0.5)
@@ -214,11 +208,6 @@ def test_derive_thresholds_non_monotone_disables_sort():
         10_000: ["scatter", "sort"],
     })
     assert t["sort_min_metrics"] >= 1 << 30
-
-
-def test_derive_thresholds_sortscan_upgrade():
-    t = _derive({16: ["scatter"], 10_000: ["sortscan", "sort"]})
-    assert t["high_cardinality_kernel"] == "sortscan"
 
 
 def test_derive_thresholds_non_tpu_refused():

@@ -1,5 +1,7 @@
-"""Fast-path ingest parity: one-hot MXU matmul histogram and the fused
-Pallas row kernel must agree exactly with the scatter path."""
+"""Fast-path ingest parity: every dense kernel the dispatcher can pick
+(sort-dedup, the Pallas row kernel, the fused sample->scatter kernel)
+must agree exactly with the scatter path on the one [M, B] int32
+accumulator layout."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -7,7 +9,7 @@ import pytest
 
 from loghisto_tpu.config import MetricConfig
 from loghisto_tpu.ops.ingest import ingest_batch
-from loghisto_tpu.ops.matmul_hist import ingest_batch_matmul
+from loghisto_tpu.ops.dispatch import ingest_step_fn
 from loghisto_tpu.ops.pallas_kernels import (
     SAMPLE_TILE,
     make_pallas_row_ingest,
@@ -20,36 +22,6 @@ CFG = MetricConfig(bucket_limit=512)
 def _scatter_reference(ids, values, m):
     acc = jnp.zeros((m, CFG.num_buckets), dtype=jnp.int32)
     return np.asarray(ingest_batch(acc, ids, values, CFG.bucket_limit))
-
-
-def test_matmul_hist_matches_scatter():
-    rng = np.random.default_rng(0)
-    m, n = 4, 8192
-    ids = rng.integers(0, m, n).astype(np.int32)
-    values = rng.lognormal(2, 1.5, n).astype(np.float32)
-    values[::7] *= -1  # negatives too
-    acc = jnp.zeros((m, CFG.num_buckets), dtype=jnp.int32)
-    got = np.asarray(
-        ingest_batch_matmul(acc, ids, values, CFG.bucket_limit)
-    )
-    np.testing.assert_array_equal(got, _scatter_reference(ids, values, m))
-
-
-def test_matmul_hist_drops_bad_ids():
-    ids = np.array([0, -1, 99], dtype=np.int32)
-    values = np.ones(3, dtype=np.float32)
-    acc = jnp.zeros((2, CFG.num_buckets), dtype=jnp.int32)
-    got = np.asarray(ingest_batch_matmul(acc, ids, values, CFG.bucket_limit))
-    assert got.sum() == 1
-
-
-def test_matmul_hist_accumulates():
-    ids = np.zeros(16, dtype=np.int32)
-    values = np.full(16, 5.0, dtype=np.float32)
-    acc = jnp.zeros((1, CFG.num_buckets), dtype=jnp.int32)
-    acc = ingest_batch_matmul(acc, ids, values, CFG.bucket_limit)
-    acc = ingest_batch_matmul(acc, ids, values, CFG.bucket_limit)
-    assert int(np.asarray(acc).sum()) == 32
 
 
 def test_pallas_row_matches_scatter():
@@ -117,85 +89,6 @@ def test_sort_ingest_matches_scatter():
         sort_fn(jnp.zeros((m, cfg.num_buckets), jnp.int32), ids, values)
     )
     np.testing.assert_array_equal(got, ref)
-
-
-def test_sortscan_matches_scatter_adversarial():
-    """The scan-based dedup (one sort + one reverse min-scan + one
-    conflict-free scatter) must be bit-identical to scatter on the same
-    adversarial batch the sort path is tested with: invalid ids, NaN,
-    zero, negatives, duplicates."""
-    from loghisto_tpu.ops.ingest import make_ingest_fn
-    from loghisto_tpu.ops.sort_ingest import make_sortscan_ingest_fn
-
-    cfg = MetricConfig(bucket_limit=256)
-    rng = np.random.default_rng(9)
-    n, m = 1 << 14, 37
-    ids = rng.integers(-2, m + 3, n).astype(np.int32)
-    values = rng.lognormal(3, 2, n).astype(np.float32)
-    values[:64] = np.nan
-    values[64:128] = 0.0
-    values[128:256] *= -1
-    scatter = make_ingest_fn(cfg.bucket_limit)
-    scan_fn = make_sortscan_ingest_fn(cfg.bucket_limit)
-    ref = np.asarray(
-        scatter(jnp.zeros((m, cfg.num_buckets), jnp.int32), ids, values)
-    )
-    got = np.asarray(
-        scan_fn(jnp.zeros((m, cfg.num_buckets), jnp.int32), ids, values)
-    )
-    np.testing.assert_array_equal(got, ref)
-
-
-def test_sortscan_single_cell_and_all_invalid():
-    from loghisto_tpu.ops.sort_ingest import make_sortscan_ingest_fn
-
-    cfg = MetricConfig(bucket_limit=64)
-    scan_fn = make_sortscan_ingest_fn(cfg.bucket_limit)
-    # every sample in one cell: one segment spanning the whole batch
-    acc = scan_fn(
-        jnp.zeros((8, cfg.num_buckets), jnp.int32),
-        np.zeros(4096, dtype=np.int32),
-        np.full(4096, 2.5, dtype=np.float32),
-    )
-    acc = np.asarray(acc)
-    assert acc.sum() == 4096 and (acc > 0).sum() == 1
-    # every sample invalid: nothing lands, nothing crashes
-    acc2 = scan_fn(
-        jnp.zeros((8, cfg.num_buckets), jnp.int32),
-        np.full(512, -1, dtype=np.int32),
-        np.ones(512, dtype=np.float32),
-    )
-    assert np.asarray(acc2).sum() == 0
-
-
-def test_sortscan_via_aggregator_and_firehose_parity():
-    from loghisto_tpu.firehose import make_firehose_step
-    from loghisto_tpu.parallel.aggregator import TPUAggregator
-
-    agg = TPUAggregator(
-        num_metrics=8, config=MetricConfig(bucket_limit=64),
-        ingest_path="sortscan", batch_size=512,
-    )
-    rng = np.random.default_rng(4)
-    for i in range(8):
-        agg.registry.id_for(f"m{i}")
-    ids = rng.integers(0, 8, 4096).astype(np.int32)
-    vals = rng.lognormal(1, 1, 4096).astype(np.float32)
-    agg.record_batch(ids, vals)
-    out = agg.collect().metrics
-    assert sum(out[f"m{i}_count"] for i in range(8)) == 4096
-
-    import jax
-
-    cfg = MetricConfig(bucket_limit=512)
-    accs = {}
-    for path in ("scatter", "sortscan"):
-        step = make_firehose_step(64, 2048, cfg, ingest_path=path)
-        acc, _ = step(
-            jnp.zeros((64, cfg.num_buckets), jnp.int32), jax.random.key(7)
-        )
-        accs[path] = np.asarray(acc)
-    np.testing.assert_array_equal(accs["scatter"], accs["sortscan"])
 
 
 def test_pallas_row_batch_matches_scatter_with_invalid_ids():
@@ -307,44 +200,119 @@ def test_sort_ingest_shape_validated_at_construction():
         )
 
 
-def test_hybrid_hist_matches_scatter():
-    """Bit-parity for the hot-head+cold-tail hybrid, incl. edge ids,
-    NaN, negatives, and non-tile-multiple batches."""
-    import numpy as np
+# -- every dispatchable kernel on the uniform step contract ---------------- #
 
-    from loghisto_tpu.ops.hybrid_hist import ingest_batch_hybrid
-    from loghisto_tpu.ops.ingest import ingest_batch
 
+def _rows(path, m=8):
+    """Accumulator rows for a case: the Pallas row kernel is
+    single-metric; the fused kernel needs whole 8-row tiles."""
+    return 1 if path == "pallas" else m
+
+
+@pytest.mark.parametrize("path", ["scatter", "sort", "fused", "pallas"])
+def test_step_single_cell_and_all_invalid(path):
+    cfg = MetricConfig(bucket_limit=64)
+    step = ingest_step_fn(path)
+    zeros = jnp.zeros((_rows(path), cfg.num_buckets), jnp.int32)
+    # every sample in one cell: one duplicate run spanning the whole batch
+    acc = np.asarray(step(
+        zeros, np.zeros(4096, np.int32), np.full(4096, 2.5, np.float32),
+        cfg.bucket_limit,
+    ))
+    assert acc[0].sum() == 4096 and (acc > 0).sum() == 1
+    # every sample invalid: nothing lands, nothing crashes
+    acc2 = step(
+        zeros, np.full(512, -1, np.int32), np.ones(512, np.float32),
+        cfg.bucket_limit,
+    )
+    assert np.asarray(acc2).sum() == 0
+
+
+@pytest.mark.parametrize("path", ["sort", "fused", "pallas"])
+def test_step_drops_bad_ids(path):
+    m = _rows(path)
+    i32 = np.iinfo(np.int32)
+    ids = np.array([0, -1, m, 99, i32.min, i32.max], dtype=np.int32)
+    values = np.ones(len(ids), dtype=np.float32)
+    acc = np.asarray(ingest_step_fn(path)(
+        jnp.zeros((m, CFG.num_buckets), jnp.int32), ids, values,
+        CFG.bucket_limit,
+    ))
+    assert acc.sum() == 1 and acc[0].sum() == 1
+
+
+@pytest.mark.parametrize("path", ["sort", "fused", "pallas"])
+def test_step_accumulates_onto_existing_counts(path):
+    """A second batch lands on the counts the first left, bit-identical
+    to two scatter steps; the second batch is ragged (not a whole number
+    of sample tiles)."""
+    rng = np.random.default_rng(11)
+    m, n = _rows(path), 3 * SAMPLE_TILE
+    ragged = n - SAMPLE_TILE // 3
+    ids = rng.integers(0, m, n).astype(np.int32)
+    values = rng.lognormal(2, 2, n).astype(np.float32)
+    values[::9] *= -1
+    step = ingest_step_fn(path)
+    got = want = jnp.zeros((m, CFG.num_buckets), jnp.int32)
+    for k in (n, ragged):
+        got = step(got, ids[:k], values[:k], CFG.bucket_limit)
+        want = ingest_batch(want, ids[:k], values[:k], CFG.bucket_limit)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert int(np.asarray(got).sum()) == n + ragged
+
+
+@pytest.mark.parametrize("path", ["sort", "fused"])
+def test_step_zipf_hot_rows_with_edge_ids_match_scatter(path):
+    """A Zipf batch whose head rows take most samples, spliced with every
+    droppable id and zero / NaN / negative values, lands bit-identical
+    to scatter."""
     rng = np.random.default_rng(4)
-    m, limit = 512, 512
-    b = 2 * limit + 1
-    raw = rng.zipf(1.3, 30_000)
-    ids = ((raw - 1) % m).astype(np.int32)
-    ids[:8] = [-1, 2**29, m, m - 1, 0, 127, 128, 129]
-    vals = np.concatenate([
-        rng.lognormal(3, 2, 29_997).astype(np.float32),
-        np.array([0.0, np.nan, -7.5], dtype=np.float32),
-    ])
-    want = ingest_batch(jnp.zeros((m, b), jnp.int32), ids, vals, limit)
-    got = ingest_batch_hybrid(jnp.zeros((m, b), jnp.int32), ids, vals,
-                              limit)
-    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
-    # accumulate again with a ragged (non-tile-multiple) slice
-    want = ingest_batch(want, ids[:5001], vals[:5001], limit)
-    got = ingest_batch_hybrid(got, ids[:5001], vals[:5001], limit)
-    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+    m, n = 64, 6000
+    ids = ((rng.zipf(1.3, n) - 1) % m).astype(np.int32)
+    ids[:8] = [-1, 2**29, m, m - 1, 0, 7, 8, 9]
+    values = rng.lognormal(3, 2, n).astype(np.float32)
+    values[-3:] = [0.0, np.nan, -7.5]
+    zeros = jnp.zeros((m, CFG.num_buckets), jnp.int32)
+    want = ingest_batch(zeros, ids, values, CFG.bucket_limit)
+    got = ingest_step_fn(path)(zeros, ids, values, CFG.bucket_limit)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_hybrid_rejects_oversized_batch():
-    import numpy as np
-    import pytest as _pytest
+def test_pallas_row_batch_rejects_oversized_batch():
+    """The float32 scratch is exact below 2^24 samples per call; a batch
+    at the bound is refused while tracing, before anything runs."""
+    import jax
 
-    from loghisto_tpu.ops.hybrid_hist import ingest_batch_hybrid
+    from loghisto_tpu.ops.pallas_kernels import pallas_row_ingest_batch
 
-    with _pytest.raises(ValueError, match="2\\^24"):
-        ingest_batch_hybrid(
-            jnp.zeros((4, 1025), jnp.int32),
-            jnp.zeros((1 << 24,), jnp.int32),
-            jnp.zeros((1 << 24,), jnp.float32),
-            512,
+    with pytest.raises(ValueError, match="2\\^24"):
+        jax.eval_shape(
+            lambda a, i, v: pallas_row_ingest_batch(a, i, v, 512),
+            jax.ShapeDtypeStruct((1, 1025), jnp.int32),
+            jax.ShapeDtypeStruct((1 << 24,), jnp.int32),
+            jax.ShapeDtypeStruct((1 << 24,), jnp.float32),
         )
+
+
+def test_sort_path_keeps_its_kernel_through_growth():
+    """Registry growth re-pads the [M, B] accumulator under the sort
+    kernel without a kernel swap; the combined cell key was validated
+    against the growth cap at construction."""
+    from loghisto_tpu.parallel.aggregator import TPUAggregator
+
+    agg = TPUAggregator(
+        num_metrics=4, config=MetricConfig(bucket_limit=64),
+        ingest_path="sort", batch_size=512, max_metrics=16,
+    )
+    for i in range(12):
+        agg.record(f"m{i}", 1.5 + i)  # a new name grows the rows
+        agg.record_batch(
+            np.full(99, agg.registry.id_for(f"m{i}"), np.int32),
+            np.full(99, 1.5 + i, np.float32),
+        )
+    assert agg.num_metrics == 16
+    assert agg.ingest_path == "sort"
+    assert agg._acc.shape == (16, 129)
+    out = agg.collect().metrics
+    assert [out[f"m{i}_count"] for i in range(12)] == [100] * 12
+    agg.close()

@@ -530,7 +530,7 @@ def _drained_acc(agg):
     agg.wait_transfers()
     agg.flush(force=True)
     with agg._dev_lock:
-        acc = np.asarray(agg._finalize_acc(agg._acc), dtype=np.int64)
+        acc = np.asarray(agg._acc, dtype=np.int64)
         if agg._spill is not None:
             acc = acc + agg._spill
     return acc

@@ -41,13 +41,31 @@ def test_firehose_step_path_parity():
 
     cfg = MetricConfig(bucket_limit=512)
     accs = {}
-    for path in ("scatter", "sort", "hybrid"):
+    for path in ("scatter", "sort", "fused"):
         step = make_firehose_step(64, 2048, cfg, ingest_path=path)
         acc = jnp.zeros((64, cfg.num_buckets), dtype=jnp.int32)
         acc, _ = step(acc, jax.random.key(7))
         accs[path] = np.asarray(acc)
     np.testing.assert_array_equal(accs["scatter"], accs["sort"])
-    np.testing.assert_array_equal(accs["scatter"], accs["hybrid"])
+    np.testing.assert_array_equal(accs["scatter"], accs["fused"])
+
+
+def test_firehose_step_single_metric_pallas_matches_scatter():
+    """The single-metric deployment's row kernel inside the generation
+    loop: same key stream -> the accumulator scatter builds."""
+    import jax
+    import jax.numpy as jnp
+
+    cfg = MetricConfig(bucket_limit=512)
+    accs = {}
+    for path in ("scatter", "pallas"):
+        step = make_firehose_step(1, 4096, cfg, ingest_path=path)
+        acc = jnp.zeros((1, cfg.num_buckets), dtype=jnp.int32)
+        for _ in range(2):
+            acc, key = step(acc, jax.random.key(3))
+        accs[path] = np.asarray(acc)
+    np.testing.assert_array_equal(accs["scatter"], accs["pallas"])
+    assert accs["pallas"].sum() == 2 * 4096
 
 
 def test_run_firehose_end_to_end():

@@ -261,14 +261,13 @@ class _MeshStub:
 @pytest.fixture
 def baked_fused_thresholds():
     saved = (dispatch.FUSED_INGEST, dispatch.FUSED_MIN_BATCH,
-             dispatch.SORT_MIN_METRICS, dispatch.HIGH_CARDINALITY_KERNEL)
+             dispatch.SORT_MIN_METRICS)
     dispatch.FUSED_INGEST = True
     dispatch.FUSED_MIN_BATCH = 1 << 17
     dispatch.SORT_MIN_METRICS = 4096
-    dispatch.HIGH_CARDINALITY_KERNEL = "sort"
     yield
     (dispatch.FUSED_INGEST, dispatch.FUSED_MIN_BATCH,
-     dispatch.SORT_MIN_METRICS, dispatch.HIGH_CARDINALITY_KERNEL) = saved
+     dispatch.SORT_MIN_METRICS) = saved
 
 
 def test_declined_reasons_name_the_blocker(baked_fused_thresholds):

@@ -322,7 +322,7 @@ def test_aggregator_fused_paged_conserves_and_matches_dense():
         dense.flush(force=True)
         with dense._dev_lock:
             ref = np.asarray(
-                dense._finalize_acc(dense._acc), dtype=np.int64
+                dense._acc, dtype=np.int64
             )
     finally:
         dense.close()

@@ -28,7 +28,7 @@ def _drained_acc(agg):
     """Force-flush and return the dense accumulator (+ spill) as int64."""
     agg.flush(force=True)
     with agg._dev_lock:
-        acc = np.asarray(agg._finalize_acc(agg._acc), dtype=np.int64)
+        acc = np.asarray(agg._acc, dtype=np.int64)
         if agg._spill is not None:
             acc = acc + agg._spill
     return acc
@@ -545,7 +545,7 @@ def test_flat_delta_conserves_through_every_reader(tmp_path):
     def exact(ms, want):
         agg = ms.aggregator
         with agg._dev_lock:
-            acc = np.asarray(agg._finalize_acc(agg._acc), dtype=np.int64)
+            acc = np.asarray(agg._acc, dtype=np.int64)
             assert agg._delta_samples == 0
             if agg._spill is not None:
                 acc = acc + agg._spill

@@ -497,7 +497,7 @@ def test_label_budget_eviction_folds_count_exact_overflow():
     assert reg.lookup("http.latency") is not None  # flat row exempt
     ovid = reg.lookup("_overflow.http")
     assert ovid is not None and lc.evicted_series > 0
-    acc = np.asarray(agg._finalize_acc(agg._acc))
+    acc = np.asarray(agg._acc)
     assert int(acc[ovid].sum()) == lc.overflowed_samples
     assert int(acc.sum()) == total  # nothing lost, nothing doubled
 

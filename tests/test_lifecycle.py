@@ -242,7 +242,7 @@ def test_ttl_eviction_folds_count_exact_overflow():
 
     # count-exact: the overflow row holds EXACTLY the evicted device
     # samples, and live rows + overflow == every sample ever ingested
-    acc = np.asarray(agg._finalize_acc(agg._acc))
+    acc = np.asarray(agg._acc)
     assert int(acc[ovid].sum()) == lc.overflowed_samples
     assert int(acc.sum()) == total
 
@@ -440,7 +440,7 @@ def test_threaded_churn_register_evict_query():
     assert lc.evicted_series > 0
     # lossless under the race: committed samples all remain (live rows +
     # overflow rows), none duplicated or lost by fold/compact
-    acc = np.asarray(agg._finalize_acc(agg._acc))
+    acc = np.asarray(agg._acc)
     assert int(acc.sum()) == 20 * (4 * 2 + 1)
 
 

@@ -57,11 +57,11 @@ def test_distributed_step_matches_single_device(mesh_shape):
     )
 
 
-@pytest.mark.parametrize("path", ["sort", "hybrid"])
+@pytest.mark.parametrize("path", ["sort"])
 def test_distributed_step_dispatched_kernels_match_scatter(path):
     """The dispatched local-fold kernels are bit-identical to scatter
     inside shard_map — the mesh analog of the single-chip path parity
-    (sort/hybrid beat scatter on duplicate-heavy shards on TPU)."""
+    (sort beats scatter on duplicate-heavy shards on TPU)."""
     mesh = make_mesh(stream=4, metric=2)
     m, n = 16, 4096
     rng = np.random.default_rng(7)

@@ -33,17 +33,14 @@ def baked_thresholds():
     from loghisto_tpu.ops import dispatch
 
     saved = (dispatch.SORT_MIN_METRICS, dispatch.PALLAS_SINGLE_METRIC,
-             dispatch.HIGH_CARDINALITY_KERNEL, dispatch.FUSED_INGEST,
-             dispatch.FUSED_MIN_BATCH)
+             dispatch.FUSED_INGEST, dispatch.FUSED_MIN_BATCH)
     dispatch.SORT_MIN_METRICS = 4096
     dispatch.PALLAS_SINGLE_METRIC = True
-    dispatch.HIGH_CARDINALITY_KERNEL = "sort"
     dispatch.FUSED_INGEST = True
     dispatch.FUSED_MIN_BATCH = 1 << 17
     yield
     (dispatch.SORT_MIN_METRICS, dispatch.PALLAS_SINGLE_METRIC,
-     dispatch.HIGH_CARDINALITY_KERNEL, dispatch.FUSED_INGEST,
-     dispatch.FUSED_MIN_BATCH) = saved
+     dispatch.FUSED_INGEST, dispatch.FUSED_MIN_BATCH) = saved
 
 
 def test_choose_ingest_path_table(baked_thresholds):
@@ -52,8 +49,8 @@ def test_choose_ingest_path_table(baked_thresholds):
     assert choose_ingest_path(1, 8193, "tpu") == "pallas"
     assert choose_ingest_path(128, 8193, "tpu") == "scatter"
     # r13: the fused sample->scatter kernel is the high-cardinality pick
-    # on TPU; resolve degrades it to HIGH_CARDINALITY_KERNEL when
-    # fused_ingest_incapability names a blocker
+    # on TPU; resolve degrades it to sort when fused_ingest_incapability
+    # names a blocker
     assert choose_ingest_path(10_000, 8193, "tpu") == "fused"
     assert choose_ingest_path(1, 8193, "cpu") == "scatter"
     assert choose_ingest_path(10_000, 8193, "cpu") == "scatter"
@@ -75,23 +72,21 @@ def test_resolve_ingest_path_guards_sort_shape(baked_thresholds):
     # silently corrupted accumulator inside the traced kernel
     with pytest.raises(ValueError):
         resolve_ingest_path("sort", 300_000, 8193, "tpu")
-    # matmul's flat int32 cell index has the same wrap bound
-    with pytest.raises(ValueError):
-        resolve_ingest_path("matmul", 300_000, 8193, "tpu")
-    assert resolve_ingest_path("hybrid", 300_000, 8193, "tpu") == "hybrid"
+    # the wrap bound is sort's alone: scatter indexes (row, bucket)
+    assert resolve_ingest_path("scatter", 300_000, 8193, "tpu") == "scatter"
     # the aggregator guards against its GROWTH cap, not just num_metrics
     with pytest.raises(ValueError):
         resolve_ingest_path(
             "sort", 10_000, 8193, "tpu", guard_metrics=300_000
         )
-    # hybrid's float32 hot-head exactness needs per-batch n < 2^24
-    with pytest.raises(ValueError):
+    # the pallas row kernel's float32 exactness needs per-batch n < 2^24
+    with pytest.raises(ValueError, match="2\\^24"):
         resolve_ingest_path(
-            "hybrid", 100, 8193, "tpu", batch_size=1 << 24
+            "pallas", 1, 8193, "tpu", batch_size=1 << 24
         )
     assert resolve_ingest_path(
-        "hybrid", 100, 8193, "tpu", batch_size=1 << 20
-    ) == "hybrid"
+        "pallas", 1, 8193, "tpu", batch_size=1 << 20
+    ) == "pallas"
     # pallas: auto picks it at M=1 only when the growth cap pins M=1 AND
     # the batch bound is KNOWN to satisfy the float32-exactness
     # precondition (ADVICE r2: an unknown bound would otherwise defer the
@@ -118,11 +113,13 @@ def test_resolve_ingest_path_guards_sort_shape(baked_thresholds):
         resolve_ingest_path("pallas", 16, 8193, "tpu")
 
 
-def test_aggregator_rejects_hybrid_oversized_batch_at_construction():
-    with pytest.raises(ValueError):
+def test_aggregator_rejects_pallas_oversized_batch_at_construction():
+    # refused before the accumulator is allocated, not as a trace-time
+    # raise inside the first flush
+    with pytest.raises(ValueError, match="2\\^24"):
         TPUAggregator(
-            num_metrics=4, config=CFG, batch_size=1 << 24,
-            ingest_path="hybrid",
+            num_metrics=1, config=CFG, batch_size=1 << 24,
+            ingest_path="pallas",
         )
 
 
@@ -284,15 +281,16 @@ def test_registry_grow_is_monotonic():
     assert r.capacity == 8
 
 
-def test_multirow_growth_respects_row_tile():
-    # max_metrics=20 is off the rows_tile=8 grid: growth must stop at 16
-    # (rounded down), never corrupt the kernel with a 20-row rebuild
+def test_fused_growth_respects_row_tile():
+    # max_metrics=20 is off the fused kernel's 8-row tile: growth must
+    # stop at 16 (rounded down), never hand the kernel a 20-row block
     agg = TPUAggregator(
-        num_metrics=8, config=CFG, ingest_path="multirow", max_metrics=20
+        num_metrics=8, config=CFG, ingest_path="fused", max_metrics=20
     )
     for i in range(20):
         agg.record(f"m{i}", 1.0)
     assert agg.num_metrics == 16
+    assert agg.ingest_path == "fused"
     out = agg.collect().metrics
     assert sum(
         1 for k in out

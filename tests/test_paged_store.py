@@ -426,8 +426,6 @@ def test_storage_auto_degrades_below_crossover_with_reason():
 
 
 def test_paged_refuses_multirow_and_nonsparse_transports():
-    with pytest.raises(ValueError, match="multirow"):
-        _mk_agg("paged", ingest_path="multirow")
     with pytest.raises(ValueError, match="transport"):
         _mk_agg("paged", transport="raw")
     with pytest.raises(ValueError, match="transport"):

@@ -68,7 +68,7 @@ def _compile(fn, *args):
 def _kernel_cases():
     from loghisto_tpu.ops import (
         anomaly, fused_ingest, lifecycle, paged_store, pallas_kernels,
-        pallas_multirow, sparse_ingest, window,
+        sparse_ingest, window,
     )
 
     bl = BUCKET_LIMIT
@@ -77,11 +77,6 @@ def _kernel_cases():
             lambda a, i, v: fused_ingest.fused_ingest_batch(
                 a, i, v, bl, interpret=False),
             [(M, B), (N,), ((N,), "float32")],
-        ),
-        "multirow": (
-            lambda a, i, v: pallas_multirow.make_multirow_ingest(
-                M, bl, interpret=False)[1](a, i, v),
-            [(M, 65 * 128), (N,), ((N,), "float32")],
         ),
         "window_merge": (
             lambda r, m: window.window_merge_pallas(r, m, interpret=False),
@@ -117,7 +112,7 @@ def _kernel_cases():
 
 @pytest.mark.parametrize(
     "name",
-    ["fused_ingest", "multirow", "window_merge", "row_ingest",
+    ["fused_ingest", "window_merge", "row_ingest",
      "sparse_ingest", "paged_scatter", "compact_rows", "divergence"],
 )
 def test_pallas_kernel_compiles_for_v5e(one_chip, name):
@@ -129,6 +124,30 @@ def test_pallas_kernel_compiles_for_v5e(one_chip, name):
     ]
     compiled = _compile(fn, *args)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("path", ["scatter", "sort"])
+def test_xla_ingest_step_compiles_for_v5e(one_chip, path):
+    """The raw-transport ingest steps that are plain XLA (no Pallas
+    call), at the deployment's widths, donating the [M, B] accumulator
+    in place: the dense raw path the aggregator runs before its density
+    probe moves it to the sparse transport."""
+    import jax
+
+    from loghisto_tpu.ops.dispatch import ingest_step_fn
+
+    step = ingest_step_fn(path)
+    compiled = jax.jit(
+        lambda a, i, v: step(a, i, v, BUCKET_LIMIT), donate_argnums=0
+    ).lower(
+        _shape(one_chip, (M, B)), _shape(one_chip, (N,)),
+        _shape(one_chip, (N,), "float32"),
+    ).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    # the accumulator's buffer (tile-padded on the chip) is updated in
+    # place, not copied
+    alias = compiled.memory_analysis().alias_size_in_bytes
+    assert alias >= M * B * 4, alias
 
 
 def test_fused_commit_program_compiles_for_v5e(one_chip):
