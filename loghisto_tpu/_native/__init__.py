@@ -181,6 +181,8 @@ def _load():
             ctypes.c_int64, ctypes.c_int, ctypes.c_int, ctypes.c_int,
             ctypes.POINTER(ctypes.POINTER(ctypes.c_int32)),
         ]
+        lib.lh_fold_last_grows.restype = ctypes.c_int64
+        lib.lh_fold_last_grows.argtypes = []
         lib.lh_cells_drain_packed_multi.restype = ctypes.c_int64
         lib.lh_cells_drain_packed_multi.argtypes = [
             ctypes.POINTER(ctypes.c_void_p), ctypes.c_int, ctypes.c_int,
@@ -466,8 +468,11 @@ def fold_packed_native(
     ids: np.ndarray, values: np.ndarray, bucket_limit: int,
     precision: int = 100, num_threads: int | None = None,
 ) -> np.ndarray:
-    """Parallel native fold (lh_fold_packed): T thread-local hash tables
-    over disjoint batch slices, GIL released for the whole call."""
+    """Parallel native fold (lh_fold_packed), GIL released for the whole
+    call.  With more than one thread it partitions cells by key hash, so
+    each distinct (id, bucket) cell comes back as one row (more only past
+    PACKED_COUNT_CAP); its tables, buffers and threads belong to the
+    calling thread and are kept for that thread's next fold."""
     lib = _load()
     if lib is None:
         raise RuntimeError(f"native library unavailable: {_build_error}")
@@ -493,6 +498,14 @@ def fold_packed_native(
     finally:
         lib.lh_packed_free(out_ptr)
     return packed
+
+
+def fold_table_grows() -> int:
+    """Hash-table grows during the calling thread's newest native fold
+    (0 where the library is unavailable).  Near 0 once that thread's
+    kept tables have grown to its items' cells."""
+    lib = _load()
+    return 0 if lib is None else int(lib.lh_fold_last_grows())
 
 
 def fold_packed(

@@ -10,11 +10,15 @@
 //
 // Plain C ABI on purpose: loaded via ctypes (no pybind11 in the image).
 
+#include <unistd.h>
+
 #include <algorithm>
 #include <atomic>
 #include <cmath>
+#include <condition_variable>
 #include <cstdint>
 #include <cstring>
+#include <functional>
 #include <memory>
 #include <mutex>
 #include <new>
@@ -51,6 +55,20 @@ inline int16_t compress_one(double value, int precision) {
   if (mag > kBucketLimit) mag = kBucketLimit;
   int16_t i = static_cast<int16_t>(mag);
   return value < 0 ? static_cast<int16_t>(-i) : i;
+}
+
+// A float sample's codec bucket, clamped to +-bucket_limit.
+inline int32_t clamped_bucket(float value, int precision, int bucket_limit) {
+  int32_t b = compress_one(static_cast<double>(value), precision);
+  if (b < -bucket_limit) b = -bucket_limit;
+  if (b > bucket_limit) b = bucket_limit;
+  return b;
+}
+
+// The (id, bucket) cell as one table key; 0 never occurs for id >= 0.
+inline uint64_t cell_key(int32_t id, int32_t b) {
+  return (static_cast<uint64_t>(static_cast<uint32_t>(id)) << 16) |
+         static_cast<uint16_t>(b + 32768);
 }
 
 }  // namespace
@@ -164,6 +182,7 @@ struct CellStore {
   std::vector<CellSlot> table;
   uint64_t mask;
   int64_t used = 0;
+  int64_t grows = 0;  // successful grow() calls over the table's life
 
   explicit CellStore(uint64_t cap) : table(cap, CellSlot{0, 0}), mask(cap - 1) {}
 
@@ -185,6 +204,7 @@ struct CellStore {
     }
     table.swap(fresh);
     mask = new_mask;
+    ++grows;
     return true;
   }
 
@@ -246,12 +266,8 @@ int64_t lh_cells_add(void* store, const int32_t* ids, const float* values,
   for (int64_t i = 0; i < n; ++i) {
     int32_t id = ids[i];
     if (id < 0) continue;
-    int32_t b = compress_one(static_cast<double>(values[i]), precision);
-    if (b < -bucket_limit) b = -bucket_limit;
-    if (b > bucket_limit) b = bucket_limit;
-    uint64_t key =
-        (static_cast<uint64_t>(static_cast<uint32_t>(id)) << 16) |
-        static_cast<uint16_t>(b + 32768);
+    uint64_t key = cell_key(id, clamped_bucket(values[i], precision,
+                                               bucket_limit));
     if (!cs->add_one(key, 1)) return i;
   }
   return n;
@@ -317,15 +333,18 @@ int64_t lh_cells_drain_packed(void* store, int32_t* out) {
 
 }  // extern "C"
 
-// -- pipelined sparse-delta transport (PR 6) -------------------------------
+// -- the host fold of transport="sparse" ------------------------------------
 //
-// The fold below is the host half of transport="sparse": one GIL-released
-// call turns a raw (ids, values) batch into packed int32 [n, 3]
-// (id, codec_bucket, count) triples — the r5 wire format — using T
-// thread-local CellStores over disjoint batch slices.  Thread-local
-// tables need no locks; duplicate (id, bucket) cells across slices cost
-// only wire rows (the device merge is additive), the same bounded-
-// duplication trade the sharded record-time store already makes.
+// One GIL-released call turns a raw (ids, values) batch into packed int32
+// [n, 3] (id, codec_bucket, count) triples, the wire format of the packed
+// merge.  With T > 1 threads the fold partitions cells by key hash: each
+// thread codes one contiguous slice of the batch and scatters its keys by
+// partition, then each thread folds one partition's keys, from every
+// slice, into its own table.  So each distinct (id, bucket) cell is
+// counted by one thread and emitted as one row (more only past the 2^30-1
+// count cap).  The tables, the key buffers and the threads live in a
+// context of the calling thread that outlasts the call, so tables grown
+// for one item are warm for the next.
 
 namespace {
 
@@ -335,7 +354,7 @@ int64_t packed_rows_needed(const CellStore& cs, int64_t cap) {
   int64_t rows = 0;
   for (const CellSlot& s : cs.table) {
     if (s.key == 0) continue;
-    rows += (s.count + cap - 1) / cap;
+    rows += s.count <= cap ? 1 : (s.count + cap - 1) / cap;
   }
   return rows;
 }
@@ -362,84 +381,312 @@ int64_t emit_packed_split(CellStore& cs, int64_t cap, int32_t* out) {
   return m;
 }
 
+// The partition of a key among t: the high half of a second
+// multiplicative hash, scaled to [0, t).  Its bits are independent of
+// the slot hash in CellStore::add_one, so one partition's keys still
+// spread over all of its table's slots.
+inline int partition_of(uint64_t key, int t) {
+  uint64_t h = (key * 0xD6E8FEB86659FD93ull) >> 32;
+  return static_cast<int>((h * static_cast<uint64_t>(t)) >> 32);
+}
+
+// Each slice's per-partition counters live on its thread's stack (shared
+// counters would bounce one cache line between the threads).
+constexpr int kMaxFoldThreads = 64;
+
+// The threads of one calling thread's folds.  run(t, fn) calls fn(0) on
+// the caller and fn(1) .. fn(t-1) on kept threads, started the first time
+// a width asks for them, and returns once every call has returned.  Only
+// the owning thread calls run, so two runs never overlap.
+class FoldPool {
+ public:
+  FoldPool() = default;
+  FoldPool(const FoldPool&) = delete;
+  FoldPool& operator=(const FoldPool&) = delete;
+
+  ~FoldPool() {
+    {
+      std::lock_guard<std::mutex> lock(mu_);
+      stop_ = true;
+    }
+    start_.notify_all();
+    for (std::thread& th : threads_) th.join();
+  }
+
+  // False, before anything ran, when a thread could not be started.
+  bool run(int t, const std::function<void(int)>& fn) {
+    while (static_cast<int>(threads_.size()) < t - 1) {
+      try {
+        threads_.emplace_back(&FoldPool::serve, this,
+                              static_cast<int>(threads_.size()) + 1, gen_);
+      } catch (...) {
+        return false;
+      }
+    }
+    if (t > 1) {
+      {
+        std::lock_guard<std::mutex> lock(mu_);
+        job_ = &fn;
+        width_ = t;
+        pending_ = t - 1;
+        ++gen_;
+      }
+      start_.notify_all();
+    }
+    fn(0);
+    std::unique_lock<std::mutex> lock(mu_);
+    done_.wait(lock, [this] { return pending_ == 0; });
+    return true;
+  }
+
+ private:
+  void serve(int index, uint64_t seen) {
+    std::unique_lock<std::mutex> lock(mu_);
+    while (true) {
+      start_.wait(lock, [&] { return stop_ || gen_ != seen; });
+      if (stop_) return;
+      seen = gen_;
+      if (index >= width_) continue;
+      const std::function<void(int)>* fn = job_;
+      lock.unlock();
+      (*fn)(index);
+      lock.lock();
+      if (--pending_ == 0) done_.notify_one();
+    }
+  }
+
+  std::mutex mu_;
+  std::condition_variable start_, done_;
+  std::vector<std::thread> threads_;
+  const std::function<void(int)>* job_ = nullptr;
+  uint64_t gen_ = 0;  // written by the owner under mu_
+  int width_ = 0;
+  int pending_ = 0;
+  bool stop_ = false;
+};
+
+// What one calling thread's folds keep between calls.  Its memory is
+// bounded by the largest item that thread has folded: 10 bytes a sample
+// (a codec bucket and a key), and one table a partition that never
+// shrinks, of 16-byte slots, at most the power of two above 10/7 of the
+// most distinct cells that partition has held.
+struct FoldContext {
+  pid_t pid = getpid();
+  FoldPool pool;
+  std::vector<std::unique_ptr<CellStore>> tables;  // one a partition
+  std::unique_ptr<int16_t[]> buckets;  // each sample's clamped bucket
+  std::unique_ptr<uint64_t[]> keys;    // the keys, grouped by partition
+  int64_t capacity = 0;                // samples the two buffers hold
+  std::vector<int64_t> starts;  // [slice * t + partition] scatter offsets
+  std::vector<int64_t> bounds;  // partition p's keys: [bounds[p], bounds[p+1])
+  std::vector<int64_t> rows;    // partition p's rows, then its emit offset
+
+  // Sizes everything for n samples over t partitions; false when a
+  // buffer could not be allocated (a table or vector may throw instead).
+  bool reserve(int64_t n, int t) {
+    if (n > capacity) {
+      // free the old buffers first: old and new never coexist
+      buckets.reset();
+      keys.reset();
+      capacity = 0;
+      buckets.reset(new (std::nothrow) int16_t[n]);
+      keys.reset(new (std::nothrow) uint64_t[n]);
+      if (!buckets || !keys) {
+        buckets.reset();
+        keys.reset();
+        return false;
+      }
+      capacity = n;
+    }
+    while (static_cast<int>(tables.size()) < t)
+      tables.emplace_back(new CellStore(1 << 14));
+    starts.assign(static_cast<size_t>(t) * t, 0);
+    bounds.assign(t + 1, 0);
+    rows.assign(t, 0);
+    return true;
+  }
+
+  int64_t grows() const {
+    int64_t g = 0;
+    for (const auto& cs : tables) g += cs->grows;
+    return g;
+  }
+
+  // Empties every table after a fold that failed partway.
+  void clear_tables() {
+    for (auto& cs : tables) {
+      std::fill(cs->table.begin(), cs->table.end(), CellSlot{0, 0});
+      cs->used = 0;
+    }
+  }
+};
+
+// One context per calling thread: the transfer worker, its fold-ahead
+// helper and a federation emitter each fold with their own, so no two
+// concurrent folds share one.  It is freed when its thread exits.  A
+// forked child inherits the pointer but not the threads, so it leaves
+// its parent's context alone and builds its own.
+struct FoldContextSlot {
+  FoldContext* ctx = nullptr;
+
+  ~FoldContextSlot() {
+    if (ctx != nullptr && ctx->pid == getpid()) delete ctx;
+  }
+
+  FoldContext& get() {
+    if (ctx != nullptr && ctx->pid != getpid()) ctx = nullptr;
+    if (ctx == nullptr) ctx = new FoldContext();
+    return *ctx;
+  }
+};
+
+thread_local FoldContextSlot t_fold_context;
+// Table grows during the calling thread's newest lh_fold_packed.
+thread_local int64_t t_fold_grows = 0;
+
+// The one-thread fold: one fresh table for the whole batch.
+int64_t fold_one_table(const int32_t* ids, const float* values, int64_t n,
+                       int precision, int bucket_limit, int32_t** out) {
+  const int64_t cap = LH_PACKED_COUNT_CAP;
+  std::unique_ptr<CellStore> cs(new CellStore(1 << 14));
+  for (int64_t i = 0; i < n; ++i) {
+    int32_t id = ids[i];
+    if (id < 0) continue;
+    uint64_t key = cell_key(id, clamped_bucket(values[i], precision,
+                                               bucket_limit));
+    if (!cs->add_one(key, 1)) {
+      t_fold_grows = cs->grows;
+      return -1;
+    }
+  }
+  t_fold_grows = cs->grows;
+  int64_t total = packed_rows_needed(*cs, cap);
+  int32_t* buf = new (std::nothrow) int32_t[3 * std::max<int64_t>(total, 1)];
+  if (!buf) return -1;
+  emit_packed_split(*cs, cap, buf);
+  *out = buf;
+  return total;
+}
+
+// The key-partitioned fold over t threads, in the calling thread's
+// context.  Leaves its tables empty, whether it succeeds or fails.
+int64_t fold_partitioned(const int32_t* ids, const float* values, int64_t n,
+                         int precision, int bucket_limit, int t,
+                         int32_t** out) {
+  const int64_t cap = LH_PACKED_COUNT_CAP;
+  FoldContext& c = t_fold_context.get();
+  if (!c.reserve(n, t)) return -1;
+  const int64_t grows_before = c.grows();
+  // 1. each slice: code its samples, count its keys by partition
+  auto code = [&](int s) {
+    int64_t counts[kMaxFoldThreads] = {};
+    for (int64_t i = n * s / t, hi = n * (s + 1) / t; i < hi; ++i) {
+      int32_t id = ids[i];
+      if (id < 0) continue;
+      int32_t b = clamped_bucket(values[i], precision, bucket_limit);
+      c.buckets[i] = static_cast<int16_t>(b);
+      ++counts[partition_of(cell_key(id, b), t)];
+    }
+    std::copy(counts, counts + t, &c.starts[static_cast<size_t>(s) * t]);
+  };
+  if (!c.pool.run(t, code)) return -1;
+  // partition p holds slice 0's keys of p, then slice 1's, ...
+  int64_t at = 0;
+  for (int p = 0; p < t; ++p) {
+    c.bounds[p] = at;
+    for (int s = 0; s < t; ++s) {
+      int64_t& start = c.starts[static_cast<size_t>(s) * t + p];
+      int64_t count = start;
+      start = at;
+      at += count;
+    }
+  }
+  c.bounds[t] = at;
+  // 2. each slice: scatter its keys to their partitions
+  auto scatter = [&](int s) {
+    int64_t pos[kMaxFoldThreads];
+    std::copy(&c.starts[static_cast<size_t>(s) * t],
+              &c.starts[static_cast<size_t>(s) * t] + t, pos);
+    for (int64_t i = n * s / t, hi = n * (s + 1) / t; i < hi; ++i) {
+      int32_t id = ids[i];
+      if (id < 0) continue;
+      uint64_t key = cell_key(id, c.buckets[i]);
+      c.keys[pos[partition_of(key, t)]++] = key;
+    }
+  };
+  // 3. each partition: fold its keys into its table, count its rows
+  std::atomic<bool> failed{false};
+  auto fold = [&](int p) {
+    CellStore& cs = *c.tables[p];
+    for (int64_t k = c.bounds[p]; k < c.bounds[p + 1]; ++k) {
+      if (!cs.add_one(c.keys[k], 1)) {
+        failed.store(true);
+        return;
+      }
+    }
+    c.rows[p] = packed_rows_needed(cs, cap);
+  };
+  bool ran = c.pool.run(t, scatter) && c.pool.run(t, fold);
+  t_fold_grows = c.grows() - grows_before;
+  int32_t* buf = nullptr;
+  if (ran && !failed.load()) {
+    int64_t total = 0;
+    for (int p = 0; p < t; ++p) {
+      int64_t r = c.rows[p];
+      c.rows[p] = total;
+      total += r;
+    }
+    buf = new (std::nothrow) int32_t[3 * std::max<int64_t>(total, 1)];
+    // 4. each partition: emit its rows at its offset, emptying its table
+    auto emit = [&](int p) {
+      emit_packed_split(*c.tables[p], cap, buf + 3 * c.rows[p]);
+    };
+    if (buf && c.pool.run(t, emit)) {
+      *out = buf;
+      return total;
+    }
+  }
+  delete[] buf;
+  c.clear_tables();
+  return -1;
+}
+
 }  // namespace
 
 extern "C" {
 
 void lh_packed_free(int32_t* p) { delete[] p; }
 
-// Fold a raw batch into packed triples with `num_threads` parallel
-// thread-local tables.  *out receives a buffer allocated here (release
-// with lh_packed_free).  Returns the row count, or -1 when an
-// allocation failed (nothing is leaked; the caller falls back to the
-// NumPy tier or raw transport).
+// Fold a raw batch into packed triples with up to `num_threads` threads
+// (one below ~64k samples a thread, where the partition pass costs more
+// than it saves).  *out receives a buffer allocated here (release with
+// lh_packed_free).  Returns the row count, or -1 when an allocation
+// failed (nothing is leaked and the kept tables are left empty; the
+// caller falls back to the NumPy tier or raw transport).
 int64_t lh_fold_packed(const int32_t* ids, const float* values, int64_t n,
                        int precision, int bucket_limit, int num_threads,
                        int32_t** out) {
-  const int64_t cap = LH_PACKED_COUNT_CAP;
   if (num_threads < 1) num_threads = 1;
-  // below ~64k samples/thread the spawn+merge overhead beats the win
+  if (num_threads > kMaxFoldThreads) num_threads = kMaxFoldThreads;
   int64_t max_t = n / 65536 + 1;
   if (num_threads > max_t) num_threads = static_cast<int>(max_t);
-  std::vector<std::unique_ptr<CellStore>> stores;
-  std::atomic<bool> failed{false};
+  t_fold_grows = 0;
   try {
-    for (int t = 0; t < num_threads; ++t)
-      stores.emplace_back(new CellStore(1 << 14));
+    if (num_threads == 1)
+      return fold_one_table(ids, values, n, precision, bucket_limit, out);
+    return fold_partitioned(ids, values, n, precision, bucket_limit,
+                            num_threads, out);
   } catch (...) {
+    // an allocation threw partway: no exception crosses the C ABI, and
+    // the next call must not find this one's cells in the kept tables
+    if (t_fold_context.ctx != nullptr) t_fold_context.ctx->clear_tables();
     return -1;
   }
-  auto fold_slice = [&](int t) {
-    int64_t lo = n * t / num_threads;
-    int64_t hi = n * (t + 1) / num_threads;
-    CellStore& cs = *stores[t];
-    for (int64_t i = lo; i < hi; ++i) {
-      int32_t id = ids[i];
-      if (id < 0) continue;
-      int32_t b = compress_one(static_cast<double>(values[i]), precision);
-      if (b < -bucket_limit) b = -bucket_limit;
-      if (b > bucket_limit) b = bucket_limit;
-      uint64_t key =
-          (static_cast<uint64_t>(static_cast<uint32_t>(id)) << 16) |
-          static_cast<uint16_t>(b + 32768);
-      if (!cs.add_one(key, 1)) {
-        failed.store(true);
-        return;
-      }
-    }
-  };
-  if (num_threads == 1) {
-    fold_slice(0);
-  } else {
-    std::vector<std::thread> threads;
-    threads.reserve(num_threads);
-    for (int t = 0; t < num_threads; ++t)
-      threads.emplace_back(fold_slice, t);
-    for (auto& th : threads) th.join();
-  }
-  if (failed.load()) return -1;
-  int64_t total = 0;
-  std::vector<int64_t> offsets(num_threads);
-  for (int t = 0; t < num_threads; ++t) {
-    offsets[t] = total;
-    total += packed_rows_needed(*stores[t], cap);
-  }
-  int32_t* buf = new (std::nothrow) int32_t[3 * std::max<int64_t>(total, 1)];
-  if (!buf) return -1;
-  if (num_threads == 1) {
-    emit_packed_split(*stores[0], cap, buf);
-  } else {
-    std::vector<std::thread> threads;
-    threads.reserve(num_threads);
-    for (int t = 0; t < num_threads; ++t)
-      threads.emplace_back([&, t] {
-        emit_packed_split(*stores[t], cap, buf + 3 * offsets[t]);
-      });
-    for (auto& th : threads) th.join();
-  }
-  *out = buf;
-  return total;
 }
+
+// Table grows during the calling thread's newest lh_fold_packed: near 0
+// once that thread's kept tables have grown to its items' cells.
+int64_t lh_fold_last_grows() { return t_fold_grows; }
 
 // Parallel drain of `num_stores` detached CellStore handles into one
 // packed buffer (allocated here; release with lh_packed_free) — the

@@ -69,28 +69,29 @@ _PROBE_SAMPLES = 1 << 16
 
 class _FoldJob:
     """One queued "fold" item's fold, run on the transfer worker's helper
-    thread ahead of the item's turn.  ``done`` is set once ``packed`` or
-    ``error`` (what the fold raised) holds the outcome."""
+    thread ahead of the item's turn.  ``done`` is set once ``folded``
+    (what the fold returned) or ``error`` (what it raised) holds the
+    outcome."""
 
     def __init__(self, ids: np.ndarray, values: np.ndarray, fold) -> None:
         self.ids, self.values, self._fold = ids, values, fold
         self.done = threading.Event()
-        self.packed: Optional[np.ndarray] = None
+        self.folded = None
         self.error: Optional[Exception] = None
 
     def run(self) -> None:
         try:
-            self.packed = self._fold(self.ids, self.values)
+            self.folded = self._fold(self.ids, self.values)
         except Exception as e:
             self.error = e
         finally:
             self.done.set()
 
-    def result(self) -> np.ndarray:
+    def result(self):
         self.done.wait()
         if self.error is not None:
             raise self.error
-        return self.packed
+        return self.folded
 
 
 def _fold_helper(jobs: queue.SimpleQueue) -> None:
@@ -679,6 +680,10 @@ class TPUAggregator:
         self._fold_thread: Optional[threading.Thread] = None
         self._fold_jobs: Optional[queue.SimpleQueue] = None
         self.folds_ahead = 0
+        # rows the sparse fold shipped, and the hash-table grows inside
+        # those folds (written by the worker alone, in _process_fold)
+        self.fold_rows = 0
+        self.fold_table_grows = 0
         self._staging_ring: Optional[IngestStagingRing] = None
         self.staging_depth = 3
         # wire accounting for bytes/sample reporting (bench satellite)
@@ -1636,15 +1641,18 @@ class TPUAggregator:
 
     def _fold_packed(
         self, ids: np.ndarray, values: np.ndarray
-    ) -> np.ndarray:
+    ) -> tuple[np.ndarray, int]:
+        """The packed triples of one item, and the table grows the fold
+        made (read on the thread that folded: the count is per thread)."""
         from loghisto_tpu import _native
 
         with self.obs_recorder.span("ingest.fold"):
-            return _native.fold_packed(
+            packed = _native.fold_packed(
                 ids, values,
                 bucket_limit=self.config.bucket_limit,
                 precision=self.config.precision,
             )
+        return packed, _native.fold_table_grows()
 
     def _requeue_raw(self, ids: np.ndarray, values: np.ndarray) -> None:
         if not len(ids):
@@ -1718,15 +1726,17 @@ class TPUAggregator:
         needed)."""
         try:
             if job is None:
-                packed = self._fold_packed(ids, values)
+                packed, grows = self._fold_packed(ids, values)
             else:
                 with self.obs_recorder.span("ingest.fold_wait"):
-                    packed = job.result()
+                    packed, grows = job.result()
         except MemoryError:
             # can't build the fold table: ship the batch raw instead of
             # losing it (same wire contract, just more bytes)
             self._process_raw(ids, values, n)
             return
+        self.fold_rows += len(packed)
+        self.fold_table_grows += grows
         self._xfer_uploads += 1
         self._xfer_bytes += packed.nbytes
         self._xfer_samples_shipped += n
@@ -1906,6 +1916,8 @@ class TPUAggregator:
             "delta_settles": self.delta_settles,
             "stream_allreduce_bytes": self.stream_allreduce_bytes,
             "folds_ahead": self.folds_ahead,
+            "fold_rows": self.fold_rows,
+            "fold_table_grows": self.fold_table_grows,
         }
 
     def _preagg_record(self, ids: np.ndarray, values: np.ndarray) -> None:

@@ -824,3 +824,115 @@ def test_wait_transfers_times_out_while_a_fold_ahead_runs(
     want[:2] = FOLD_ITEM
     np.testing.assert_array_equal(_row_counts(agg), want)
     agg.close()
+
+
+# ---------------------------------------------------------------------- #
+# the key-partitioned native fold: one row per distinct cell, and a
+# context of the calling thread kept across folds
+# ---------------------------------------------------------------------- #
+
+
+def _fold_input(seed, n, rows=4096):
+    """Zipf ids over ``rows`` with some negative (dropped) ids, and
+    values of both signs, many beyond ``CFG.bucket_limit``, with a few
+    non-finite ones, so the clamp and the codec's edges are exercised."""
+    rng = np.random.default_rng(seed)
+    ids = ((rng.zipf(1.3, n) - 1) % rows).astype(np.int32)
+    ids[rng.random(n) < 0.01] = -1
+    values = rng.lognormal(4, 2, n) * rng.choice([-1.0, 1.0], n)
+    values[rng.integers(0, n, 16)] = np.inf
+    values[rng.integers(0, n, 16)] = np.nan
+    return ids, values.astype(np.float32)
+
+
+def _assert_same_cells(native, numpy_tier):
+    # one row per distinct cell on both tiers: equal lengths mean the
+    # native fold emitted no cell twice
+    assert len(native) == len(numpy_tier)
+    np.testing.assert_array_equal(
+        native[np.lexsort(native.T[::-1])],
+        numpy_tier[np.lexsort(numpy_tier.T[::-1])],
+    )
+
+
+def test_partitioned_native_fold_matches_numpy_row_for_row():
+    """At 2^20 samples the default thread count partitions the fold; it
+    returns exactly the NumPy tier's rows: one per distinct cell."""
+    from loghisto_tpu import _native
+
+    if not _native.available():
+        pytest.skip(f"native library unavailable: {_native.build_error()}")
+    ids, values = _fold_input(7, 1 << 20)
+    via_numpy = _native.fold_packed_numpy(ids, values, CFG.bucket_limit)
+    via_native = _native.fold_packed_native(ids, values, CFG.bucket_limit)
+    _assert_same_cells(via_native, via_numpy)
+    buckets = via_numpy[:, 1]
+    assert buckets.min() == -CFG.bucket_limit
+    assert buckets.max() == CFG.bucket_limit
+    assert int(via_native[:, 2].sum()) == int((ids >= 0).sum())
+
+
+def test_native_fold_context_stays_exact_across_items_and_threads():
+    """The tables kept between folds carry nothing over: a large item, a
+    one-thread one, a few-thread one with far more rows, and a large one
+    again each fold exactly; and two threads folding different items at
+    once, each with its own context, stay exact every time."""
+    from loghisto_tpu import _native
+
+    if not _native.available():
+        pytest.skip(f"native library unavailable: {_native.build_error()}")
+    items = [
+        _fold_input(11, 1 << 20),
+        _fold_input(12, 5_000),
+        _fold_input(13, 3 * (1 << 16) + 5, rows=1 << 20),
+        _fold_input(14, 1 << 20),
+    ]
+    want = [_native.fold_packed_numpy(ids, values, CFG.bucket_limit)
+            for ids, values in items]
+    for (ids, values), cells in zip(items, want):
+        _assert_same_cells(
+            _native.fold_packed_native(ids, values, CFG.bucket_limit), cells)
+
+    errors = []
+
+    def fold_in_turn(order):
+        try:
+            for _ in range(3):
+                for k in order:
+                    got = _native.fold_packed_native(
+                        *items[k], CFG.bucket_limit)
+                    _assert_same_cells(got, want[k])
+        except AssertionError as e:
+            errors.append(e)
+
+    threads = [threading.Thread(target=fold_in_turn, args=(order,))
+               for order in ([0, 2, 1], [3, 1, 2])]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+        assert not t.is_alive()
+    assert not errors, errors[0]
+
+
+@pytest.mark.parametrize("samples", [FOLD_ITEM, 1 << 18])
+def test_transport_stats_count_the_fold_rows(samples):
+    """``fold_rows`` counts the rows the sparse fold shipped: one
+    record_batch folds into exactly its distinct cells.  The fold's table
+    grows are counted beside it."""
+    from loghisto_tpu import _native
+
+    agg = TPUAggregator(
+        num_metrics=16, config=CFG, transport="sparse", batch_size=samples,
+    )
+    rng = np.random.default_rng(17)
+    ids = rng.integers(0, 16, samples).astype(np.int32)
+    values = rng.lognormal(3, 1, samples).astype(np.float32)
+    agg.record_batch(ids, values)
+    assert agg.wait_transfers(timeout=60)
+    stats = agg.transport_stats()
+    assert stats["samples_shipped"] == samples
+    assert stats["fold_rows"] == len(
+        _native.fold_packed_numpy(ids, values, CFG.bucket_limit))
+    assert stats["fold_table_grows"] >= 0
+    agg.close()
